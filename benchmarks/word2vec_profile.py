@@ -1,8 +1,7 @@
 #!/usr/bin/env python
 """Word2Vec SGNS device profile: is the epoch scan scatter-bound?
 
-VERDICT round-2 next-step #8 / SURVEY section 7 (round-1 item 9b): the
-planned Pallas scatter-add kernel for sparse embedding rows should be
+SURVEY section 7 (item 9b): the planned Pallas scatter-add kernel for sparse embedding rows should be
 built ONLY if the profile shows the `.at[].add()` scatters dominating the
 step; otherwise record the ruling-out. This script measures, on the real
 chip, an attribution breakdown of one SGNS minibatch step
@@ -28,25 +27,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# imported at process start so round_guard.START_TS captures THIS
-# process's birth time — the stale-round guards compare it to the
-# watcher's .bench_round_start marker. round_guard (not bench!) so this
-# profiler stops inheriting bench's import-time env mutations (ADVICE r5).
-import round_guard as _round_guard
-
-# fast-abort guard: a zombie watcher from a previous round retries this
-# profile 3x per re-arm with a 1800s timeout each — it must die HERE, at
-# process start, not after burning 30 min of the 1-core host per attempt.
-# The catch is the spawner-identity signal (BENCH_WATCH_ROUND env vs the
-# current marker mtime): a fresh child's own birth time is always newer
-# than the marker, so only the inherited identity can expose a zombie
-# spawner. (The write-time guard below still covers a round boundary
-# that happens mid-profile.)
-if _round_guard.round_is_stale():
-    print("round marker is newer than this process; stale-round w2v "
-          "profile aborting at startup", file=sys.stderr)
-    raise SystemExit(3)
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -55,8 +35,7 @@ from deeplearning4j_tpu.nlp import word2vec as w2v
 
 
 def _force(x):
-    leaf = jax.tree_util.tree_leaves(x)[0]
-    np.asarray(leaf.reshape(-1)[:1])
+    jax.block_until_ready(x)
 
 
 def _bench(fn, args, steps=40):
@@ -126,17 +105,7 @@ def main(vocab=50_000, dim=128, batch=2048, k=5):
             f"NOT scatter-bound ({res['scatter_fraction']:.0%} of the "
             "step): the pallas scatter-add kernel is ruled out by "
             "measurement; gathers+math dominate and already ride XLA")
-    # stale-round guard (same second-line defense as bench._persist_partial):
-    # a profile child that survived a round-boundary plain kill must not
-    # re-create the NEW round's W2V_PROFILE.json from old-round code — the
-    # watcher's [ ! -f ] gate would then skip profiling and declare the
-    # capture complete on a stale artifact
-    if _round_guard.round_is_stale():
-        print("round marker is newer than this process; refusing to write "
-              "stale W2V_PROFILE.json", file=sys.stderr)
-        raise SystemExit(3)
-    # atomic write: a timeout kill mid-dump must not leave a truncated
-    # artifact that the watcher's existence check would count as success
+    # atomic write: a kill mid-dump must not leave a truncated artifact
     with open("W2V_PROFILE.json.tmp", "w") as f:
         json.dump(res, f, indent=1)
     os.replace("W2V_PROFILE.json.tmp", "W2V_PROFILE.json")

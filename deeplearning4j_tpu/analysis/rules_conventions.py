@@ -26,7 +26,7 @@ import re
 from typing import List, Optional, Set
 
 from deeplearning4j_tpu.analysis.engine import Finding, ParsedFile, Rule
-from deeplearning4j_tpu.analysis.rules_tunnel import call_name, dotted_name
+from deeplearning4j_tpu.analysis.rules_jit import call_name, dotted_name
 
 # ---------------------------------------------------------------------------
 # ledger registration

@@ -171,8 +171,8 @@ def _skipgram_epoch(syn0, syn1, syn1neg, P, C, M, table, cens, cxs,
     drawing device-side moves only the key); alphas: [NB] per-batch LR.
     sgns_kernel (static, resolved by the caller through
     ops/pallas_sgns.sgns_kernel_enabled) swaps _neg_body for the fused
-    Pallas gather-dot-scatter step; sgns_interpret rides along for the
-    CPU test substrate."""
+    Pallas gather-dot-scatter step; sgns_interpret is for tests, which
+    pass it themselves."""
 
     def body(carry, inp):
         syn0, syn1, syn1neg = carry
@@ -506,7 +506,6 @@ class Word2Vec:
                 sgns_on = use_neg and pallas_sgns.sgns_kernel_enabled(
                     B, self.negative + 1, syn0.shape[1]
                 )
-                sgns_interp = sgns_on and pallas_sgns.sgns_interpret()
                 nb = max(1, -(-n_ex // B))
                 alphas = np.array(
                     [self._alpha(phase, bi, n_phases, nb) for bi in range(nb)],
@@ -535,7 +534,6 @@ class Word2Vec:
                         use_neg=use_neg,
                         negative_k=self.negative,
                         sgns_kernel=sgns_on,
-                        sgns_interpret=sgns_interp,
                     )
 
         lt.syn0 = np.asarray(syn0)

@@ -667,7 +667,7 @@ class ComputationGraph:
 
     def _fit_batches_fallback(self, features, labels):
         """Per-step drain under the fusion policy (dispatch.fusion_enabled:
-        the XLA:CPU scan-of-conv ~15x pessimization, BENCH_NOTES round-6);
+        XLA:CPU compiles scan-of-conv far slower than the per-step program);
         recorded in dispatch_stats.fused_fallbacks, DL4J_TPU_FUSE=force
         overrides. Same contract as MultiLayerNetwork's fallback."""
         from deeplearning4j_tpu.optimize.listeners import (

@@ -35,7 +35,7 @@ import weakref
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 # serving latency / span duration ladder (seconds): sub-ms to 10s covers
-# a cache-hit CPU dispatch through a tunnel-window XLA compile
+# a cache-hit CPU dispatch through a cold XLA compile
 DEFAULT_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
                    0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
 

@@ -1,6 +1,6 @@
 """Measured-win gate for pallas kernels (the CLAUDE.md rent rule made
-mechanical — VERDICT round-2 weak #8 asked for exactly this: default-on
-decided by the committed on-chip artifact, not just VMEM fit).
+mechanical: default-on decided by the committed on-chip artifact, not just
+VMEM fit).
 
 PALLAS_BENCH.json (repo root) is written by the on-chip benches
 (benchmarks/pallas_lstm_bench.py, bench.py ring/flash legs). A kernel may
@@ -47,9 +47,8 @@ def reload() -> None:
 def measured_win(group: str, name: str, *, min_speedup: float = 1.0,
                  default: bool = False) -> bool:
     """True when PALLAS_BENCH.json records `group.name.speedup` >=
-    min_speedup on a real chip. `default` is the answer when no row exists
-    (fresh clone / chip never reachable): new kernels ship default-OFF
-    until the artifact proves them."""
+    min_speedup on a real chip. `default` is the answer when no row
+    exists: new kernels ship default-OFF until the artifact proves them."""
     if envknob.raw("DL4J_TPU_PALLAS_FORCE") == "1":
         return True
     row = _load().get(group, {}).get(name)
@@ -80,19 +79,3 @@ def record_win(group: str, name: str, row: dict) -> None:
     """Merge one bench result into PALLAS_BENCH.json, preserving unrelated
     groups/rows."""
     _merge(lambda data: data.setdefault(group, {}).__setitem__(name, row))
-
-
-def record_verdict(group: str, text: str) -> None:
-    """Record a per-kernel-group verdict under the artifact's ``verdicts``
-    dict. Replaces the legacy single top-level ``verdict`` (which
-    round-boundary archiving would overwrite with whichever kernel bench
-    ran last) — each group keeps its own default-on note."""
-    _merge(lambda data: data.setdefault("verdicts", {}).__setitem__(
-        group, text))
-
-
-def merge_top_level(updates: dict) -> None:
-    """Merge top-level keys (the legacy round-1/2 schema: backend / cases /
-    verdict) into the artifact without touching kernel groups. Kept for
-    archived-artifact tooling; live benches write group rows + verdicts."""
-    _merge(lambda data: data.update(updates))

@@ -32,8 +32,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from deeplearning4j_tpu.parallel.mesh import shard_map
 
 from deeplearning4j_tpu.ops import rng as rng_mod
 from deeplearning4j_tpu.parallel.mesh import DATA_AXIS, device_mesh

@@ -20,57 +20,14 @@ PIPELINE_AXIS = "pipe"
 SEQUENCE_AXIS = "seq"
 EXPERT_AXIS = "expert"
 
-# --------------------------------------------------------------------------
-# shard_map compatibility: newer jax exports it at top level with a
-# `check_vma` flag; this environment's jax (0.4.x) has it under
-# jax.experimental with the older `check_rep` spelling. Every parallel
-# module imports THIS symbol so the whole stack tracks one shim.
-# --------------------------------------------------------------------------
-try:
-    from jax import shard_map as _jax_shard_map  # jax >= 0.6
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover - exercised on the 0.4.x image
-    from jax.experimental.shard_map import shard_map as _jax_shard_map
-
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """jax.shard_map with the replication-check flag translated to whatever
-    this jax version calls it (check_vma in new jax, check_rep before)."""
-    if check_vma is not None:
-        kwargs[_CHECK_KW] = check_vma
-    return _jax_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
-
 
 def virtual_cpu_devices(n: int = 8) -> None:
     """Force a virtual n-device CPU platform BEFORE first backend use —
     the standalone-script version of the tests/conftest.py discipline
-    (Spark local[N] role, BaseSparkTest.java:90).
-
-    jax >= 0.5 spells it ``jax_num_cpu_devices``; this environment's
-    0.4.x only honors the XLA_FLAGS host-platform flag, which the CPU
-    client reads at backend creation — so it must land in the env before
-    the first device query. Any inherited count flag is REPLACED (a
-    leftover =2 from a multihost worker env would otherwise silently win
-    and break every 8-device mesh). The `-m examples` smoke tier exists
-    precisely because examples carried a bare ``jax_num_cpu_devices``
-    update that this image's jax rejects at line one."""
-    import os
-
+    (Spark local[N] role, BaseSparkTest.java:90), for the demos that are
+    about a mesh and have no chips to build one from."""
     jax.config.update("jax_platforms", "cpu")
-    # strip any inherited count flag FIRST, on both branches: even where
-    # jax_num_cpu_devices exists, a leftover XLA_FLAGS count could still
-    # win at CPU-client creation (conftest applies the same discipline)
-    flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
-             if "xla_force_host_platform_device_count" not in f]
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        flags.append(f"--xla_force_host_platform_device_count={n}")
-    os.environ["XLA_FLAGS"] = " ".join(flags)
+    jax.config.update("jax_num_cpu_devices", n)
 
 
 def device_mesh(

@@ -30,9 +30,8 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from deeplearning4j_tpu.parallel.mesh import shard_map
 
 from deeplearning4j_tpu.parallel.mesh import PIPELINE_AXIS
 
@@ -69,13 +68,7 @@ def _pipeline_body(params: Any, x: jax.Array, *, stage_fn: StageFn,
 
     outputs = jnp.zeros_like(x)
     recv = jnp.zeros_like(x[0])
-    # the aux accumulator is carried RANK-1 ([1]) through the scan and the
-    # shard_map boundary: this environment's jax (0.4.x experimental
-    # shard_map) mis-specs RANK-0 float residuals when transposing the
-    # body for the backward pipeline (_SpecError on float32[]); a length-1
-    # vector round-trips the transpose fine and pipeline_apply squeezes it
-    # back to the documented scalar
-    aux0 = jnp.zeros((1,), jnp.float32)
+    aux0 = jnp.zeros((), jnp.float32)
     # ring hop: stage s -> s+1 (last stage's send is dropped into stage 0's
     # recv buffer, where it is ignored — stage 0 reads from x instead)
     perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
@@ -89,7 +82,6 @@ def _pipeline_body(params: Any, x: jax.Array, *, stage_fn: StageFn,
                         recv)
         if with_aux:
             y, aux = stage_fn(my_params, inp)
-            aux = jnp.reshape(aux, (1,))  # rank-1 through the transpose
         else:
             y, aux = stage_fn(my_params, inp), aux0
         valid = (t - stage >= 0) & (t - stage < n_micro)
@@ -113,7 +105,7 @@ def _pipeline_body(params: Any, x: jax.Array, *, stage_fn: StageFn,
     )
     if not with_aux:
         return out
-    aux_total = lax.psum(aux_sum, axis)  # every stage's own layers; [1]
+    aux_total = lax.psum(aux_sum, axis)  # every stage's own layers
     if data_axis is not None:
         aux_total = lax.pmean(aux_total, data_axis)
     return out, aux_total
@@ -169,14 +161,12 @@ def pipeline_apply(params: Any, x: jax.Array, mesh: Mesh, *,
                 axis=axis, with_aux=with_aux, data_axis=data_axis),
         mesh=mesh,
         in_specs=(param_specs, x_spec),
-        # aux crosses the boundary as [1] (rank-0 float outputs/residuals
-        # break the 0.4.x shard_map transpose — see _pipeline_body)
-        out_specs=(x_spec, P(None)) if with_aux else x_spec,
+        out_specs=(x_spec, P()) if with_aux else x_spec,
         check_vma=False,
     )
     if with_aux:
         out, aux = fn(params, xm)
-        return out.reshape((b,) + out.shape[2:]), aux[0]
+        return out.reshape((b,) + out.shape[2:]), aux
     out = fn(params, xm)
     return out.reshape((b,) + out.shape[2:])
 

@@ -35,9 +35,8 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from deeplearning4j_tpu.parallel.mesh import shard_map
 
 from deeplearning4j_tpu.parallel.mesh import MODEL_AXIS
 

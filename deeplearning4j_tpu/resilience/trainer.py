@@ -21,8 +21,8 @@ here a lost PROCESS resumes the exact step stream):
     training (the resilience analogue of the repo's distributed==serial
     convention; tests/test_resilience.py proves it for MLN, CG, and the
     DP trainer);
-  * transient-fault retry with exponential backoff (a flaky device /
-    tunnel hiccup re-runs the step; chaos.TransientDeviceError injects
+  * transient-fault retry with exponential backoff (a flaky device
+    re-runs the step; chaos.TransientDeviceError injects
     it deterministically in tests).
 
 With no manager and no chaos config this class is a plain fit loop —

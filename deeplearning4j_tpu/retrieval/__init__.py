@@ -3,7 +3,7 @@ device-resident ANN search with online, generation-swapped index
 updates. The serving half the reference's scaleout-nlp module never
 grew — its InMemoryLookupTable answers wordsNearest with a host-side
 full scan; here the arena lives on device and top-k is one batched
-matmul (the MXU-friendly shape, BENCH_NOTES.md)."""
+matmul (the MXU-friendly shape)."""
 
 from deeplearning4j_tpu.retrieval.embed import (
     BertEmbedding,

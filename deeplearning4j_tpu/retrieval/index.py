@@ -3,8 +3,8 @@
 The reference answers ``wordsNearest`` with a host-side full scan
 (BasicModelUtils.java wordsNearest — an O(vocab) numpy pass per query);
 this module is the TPU-native serving form: batched top-k over a
-device-resident arena, the MXU-friendly matmul shape the chip likes
-(~119 TFLOPS bf16 at 8192^3, BENCH_NOTES.md).
+device-resident arena, the MXU-friendly matmul shape (its rate on the
+attached chip: not measured).
 
 Two index families over ONE immutable published snapshot layout
 (:class:`IndexSnapshot`, produced by ``retrieval/store.VectorStore``

@@ -135,8 +135,9 @@ def _tick_for(cfg: TransformerConfig, k: int = 1):
 
     k=1 keeps the original direct body (reshaped to [S, 1] so the host
     unpack is uniform); k>1 wraps the same body in lax.scan carrying
-    (cache, tok, pos, keys) — one dispatch amortizes the ~5ms fixed
-    overhead (BENCH_NOTES) over k tokens. Scheduling stays per-token:
+    (cache, tok, pos, keys) — one dispatch amortizes the fixed
+    per-dispatch overhead (not measured on the attached chip) over k
+    tokens. Scheduling stays per-token:
     the WORKER decides k each iteration (adaptive drop to 1), the
     program just executes it."""
     key = (cfg, int(k))

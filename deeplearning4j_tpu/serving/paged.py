@@ -142,7 +142,6 @@ def paged_decode_step(params, arena, tok, pos, tables,
     bt = arena["k"].shape[2]
     if attention is None:
         attention = attention_path(cfg, bt)
-    interp = attention == "kernel" and pallas_paged.paged_interpret()
     t_total = tables.shape[1] * bt                    # == cfg.max_len
     h = (params["embed"][tok] + params["pos"][pos])[:, None, :].astype(cdt)
     scale = 1.0 / float(np.sqrt(hd))
@@ -162,8 +161,7 @@ def paged_decode_step(params, arena, tok, pos, tables,
         cv = cv.at[wb, off].set(v1.astype(cv.dtype))
         if attention == "kernel":
             att = pallas_paged.paged_attention(
-                q, ck, cv, tables, pos,
-                interpret=interp).reshape(s, 1, cfg.d_model)
+                q, ck, cv, tables, pos).reshape(s, 1, cfg.d_model)
         else:
             kg = ck[tables].reshape(s, t_total, cfg.n_heads, hd)
             vg = cv[tables].reshape(s, t_total, cfg.n_heads, hd)
@@ -195,14 +193,13 @@ _PAGED_ADMIT_CACHE: Dict[tuple, object] = {}
 
 
 def _paged_tick_for(cfg: TransformerConfig, block_tokens: int, k: int = 1):
-    # the attention path (and its interpret flag) is resolved HERE, not
-    # inside the trace: a knob flip after the first tick must rebuild the
-    # jitted program, so the resolved path rides the cache key. k (tokens
+    # the attention path is resolved HERE, not inside the trace: a knob
+    # flip after the first tick must rebuild the jitted program, so the
+    # resolved path rides the cache key. k (tokens
     # per tick, ISSUE 16) rides it the same way: the adaptive worker only
     # ever asks for k=1 and k=tick_k, so at most two programs per path.
     path = attention_path(cfg, block_tokens)
-    key = (cfg, block_tokens, path,
-           path == "kernel" and pallas_paged.paged_interpret(), int(k))
+    key = (cfg, block_tokens, path, int(k))
     fn = _PAGED_TICK_CACHE.get(key)
     if fn is not None:
         return fn
@@ -603,6 +600,7 @@ class PagedDecoder:
 
     supports_streaming = True  # engine.generate_stream dispatches on this
     mesh_devices = 1  # serving-mesh width; MeshPagedDecoder overrides
+    _arena_sharding = None  # default placement; MeshPagedDecoder overrides
 
     def _reset_arena(self) -> None:
         """Fresh zeroed arena + allocator + prefix cache. Construction
@@ -618,8 +616,10 @@ class PagedDecoder:
         self.stats.set_kv_blocks(0, self.n_blocks)
 
     def _zero_arena(self):
-        """Fresh zeroed k/v buffers (factored so the mesh subclass can
-        place them sharded). Two distinct buffers: k and v donate
+        """Fresh zeroed k/v buffers, allocated directly under
+        ``_arena_sharding`` (the mesh subclass head-shards them: each
+        device only ever holds its own slice, which is what the
+        auto-sizer priced). Two distinct buffers: k and v donate
         separately and must not alias each other; the scatter in
         paged_decode_step casts k/v onto ck.dtype, so a bf16 arena under
         an f32 model just works."""
@@ -627,8 +627,10 @@ class PagedDecoder:
         hd = cfg.d_model // cfg.n_heads
         shape = (cfg.n_layers, self.n_blocks + 1, self.block_tokens,
                  cfg.n_heads, hd)
-        return {"k": jnp.zeros(shape, self.kv_dtype),
-                "v": jnp.zeros(shape, self.kv_dtype)}
+        return {"k": jnp.zeros(shape, self.kv_dtype,
+                               device=self._arena_sharding),
+                "v": jnp.zeros(shape, self.kv_dtype,
+                               device=self._arena_sharding)}
 
     # -- capacity ---------------------------------------------------------
     def kv_capacity(self) -> Dict[str, object]:

@@ -6,8 +6,8 @@ management — a fixed Spark worker set per job, provisioned by hand
 that decides WHERE a model runs). This module is the decision half the
 reference never grew: price every model's resident HBM with the
 repo's AOT accounting (ops/memory — params + paged-KV arena + ANN
-arenas, closed-form, tunnel-free) and first-fit-decreasing pack them
-against each replica's ``DL4J_TPU_HBM_GB`` budget.
+arenas, closed-form over shapes) and first-fit-decreasing pack them
+against each replica's HBM budget (ops/memory.hbm_budget_gb).
 
 Everything here is a PURE FUNCTION of its inputs — deterministic sort
 keys, no RNG, no wall clock — so a placement computed twice from the

@@ -1063,7 +1063,7 @@ class FleetRouter:
         """Per-replica table. ``hbm=True`` (the GET /replicas shape,
         ISSUE 20 satellite) also scrapes each READY replica's
         engine-side AOT HBM accounting (engine.hbm_report — params +
-        KV arena + ANN arenas vs DL4J_TPU_HBM_GB, tunnel-free); kept
+        KV arena + ANN arenas vs the HBM budget, no device read); kept
         off the health() path, which must stay scrape-free."""
         out = {rep.rid: rep.describe() for rep in self._snapshot()}
         if hbm:

@@ -2,8 +2,8 @@
 
 Speculative decoding (Leviathan et al. 2023, "Fast Inference from
 Transformers via Speculative Decoding") attacks the same cost the
-multi-token tick does — the ~5ms fixed per-dispatch overhead
-(BENCH_NOTES.md) that dominates single-stream decode — from the other
+multi-token tick does — the fixed per-dispatch overhead (not measured on
+the attached chip) that can dominate single-stream decode — from the other
 side: instead of scanning k GUARANTEED-sequential target steps, a cheap
 DRAFT model proposes k tokens autoregressively and the full-precision
 target scores all k+1 positions in ONE batched dispatch. Greedy
@@ -75,7 +75,6 @@ from deeplearning4j_tpu.models.transformer import TransformerConfig
 from deeplearning4j_tpu.obs import trace as obs_trace
 from deeplearning4j_tpu.ops import dispatch
 from deeplearning4j_tpu.ops import env as envknob
-from deeplearning4j_tpu.ops import pallas_paged
 from deeplearning4j_tpu.serving import decode
 from deeplearning4j_tpu.serving.paged import (
     PagedDecoder,
@@ -97,11 +96,9 @@ def _verify_for(cfg: TransformerConfig, block_tokens: int, k: int):
     step j is byte-equal to what a plain greedy tick would have sampled
     after committing the first j proposals (the acceptance-exactness
     contract). Keyed like paged._paged_tick_for: the resolved attention
-    path (and interpret flag) rides the cache key so a knob flip
-    rebuilds the program."""
+    path rides the cache key so a knob flip rebuilds the program."""
     path = attention_path(cfg, block_tokens)
-    key = (cfg, block_tokens, path,
-           path == "kernel" and pallas_paged.paged_interpret(), int(k))
+    key = (cfg, block_tokens, path, int(k))
     fn = _VERIFY_CACHE.get(key)
     if fn is not None:
         return fn

@@ -9,7 +9,7 @@ per-request latency (the max-wait window) for dispatch amortization, and
 only these numbers show whether the trade is paying.
 
 Latencies are kept in a fixed-size ring (last ``window`` observations) so
-the percentiles track the RECENT regime — a tunnel hiccup an hour ago must
+the percentiles track the RECENT regime — a device hiccup an hour ago must
 not pollute this minute's p99 forever — and memory stays bounded under
 heavy traffic.
 """

@@ -55,21 +55,6 @@ def worker() -> None:
           f"{info['local_device_count']} local / "
           f"{info['global_device_count']} global devices", flush=True)
 
-    # capability probe (same filter as tests/multihost_worker.py): some
-    # jaxlib builds cannot run multi-process computations on the CPU
-    # backend — exit cleanly there instead of crashing the stock example;
-    # any OTHER collective failure stays loud
-    try:
-        from jax.experimental import multihost_utils
-
-        multihost_utils.sync_global_devices("example_probe")
-    except Exception as e:  # noqa: BLE001 — filtered to the capability case
-        if "Multiprocess computations" not in str(e):
-            raise
-        print(f"[proc {info['process_index']}] MH_SKIP multiprocess CPU "
-              f"collectives unavailable in this jaxlib: {e}", flush=True)
-        return
-
     def build():
         conf = (
             NeuralNetConfiguration.builder()

@@ -31,10 +31,6 @@ os.environ.setdefault(
     "DL4J_TPU_OBS_JOURNAL",
     os.path.join(tempfile.mkdtemp(prefix="obs_example_"), "journal.jsonl"))
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np  # noqa: E402
 
 from deeplearning4j_tpu import obs  # noqa: E402

@@ -25,10 +25,7 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
+import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 from deeplearning4j_tpu.datasets.iterator import ListDataSetIterator  # noqa: E402

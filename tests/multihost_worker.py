@@ -18,26 +18,14 @@ Each worker:
 import os
 import sys
 
-# the pytest parent forces an 8-device host platform via XLA_FLAGS; this
-# worker wants 2 local devices per process (2 procs x 2 = 4 global).
-# Replace (not just strip) the flag BEFORE jax import: this environment's
-# jax (0.4.x) has no jax_num_cpu_devices config, so XLA_FLAGS — read at
-# CPU-client creation — is the only device-count mechanism (same fallback
-# as tests/conftest.py).
-flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
-         if "xla_force_host_platform_device_count" not in f]
-flags.append("--xla_force_host_platform_device_count=2")
-os.environ["XLA_FLAGS"] = " ".join(flags)
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
 
+# 2 local devices per process (2 procs x 2 = 4 global); the config wins
+# over the parent's 8-device setting
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 2)
-except AttributeError:
-    pass  # 0.4.x: the XLA_FLAGS fallback above provides the 2 devices
+jax.config.update("jax_num_cpu_devices", 2)
 jax.config.update("jax_enable_x64", True)
 
 import numpy as np  # noqa: E402
@@ -80,26 +68,6 @@ def main() -> None:
         assert "17" in str(e), e
     else:
         raise AssertionError("uneven global batch must raise")
-
-    # capability probe: this jaxlib generation (0.4.x) cannot RUN
-    # multi-process computations on the CPU backend at all (Gloo-backed
-    # cross-host CPU collectives landed later) — the cluster forms and
-    # process_info is correct, but the first collective raises. Report the
-    # missing capability explicitly so the parent test can SKIP instead of
-    # failing on an environment limit no code change here can lift.
-    try:
-        from jax.experimental import multihost_utils
-
-        multihost_utils.sync_global_devices("mh_probe")
-    except Exception as e:  # noqa: BLE001 — filtered to the capability case
-        # ONLY the known capability gap becomes a skip ("Multiprocess
-        # computations aren't implemented on the CPU backend"); any other
-        # collective failure is a real regression and must stay loud.
-        if "Multiprocess computations" not in str(e):
-            raise
-        print(f"MH_SKIP multiprocess CPU collectives unavailable: {e}",
-              flush=True)
-        return
 
     rng = np.random.RandomState(0)
     X = rng.randn(16, 8)

@@ -36,11 +36,9 @@ from deeplearning4j_tpu.analysis.rules_threads import (
     HostSyncUnderLock,
     ThreadSharedState,
 )
-from deeplearning4j_tpu.analysis.rules_tunnel import (
-    BlockUntilReadyFence,
+from deeplearning4j_tpu.analysis.rules_jit import (
     DonationThroughDispatch,
     NondeterminismInJit,
-    TunnelDeviceProbe,
 )
 from deeplearning4j_tpu.ops.env import KNOBS
 
@@ -57,80 +55,6 @@ def _lint(tmp_path, source, rule_cls,
     found = [f for f in rule_cls().check(pf)
              if not pf.is_suppressed(f.rule, f.line)]
     return found, pf
-
-
-# ---------------------------------------------------------------------------
-# tunnel-device-probe
-# ---------------------------------------------------------------------------
-
-
-def test_device_probe_at_import_time_fires(tmp_path):
-    found, _ = _lint(tmp_path, """\
-        import jax
-        N = len(jax.devices())
-        """, TunnelDeviceProbe)
-    assert len(found) == 1
-    assert found[0].rule == "tunnel-device-probe"
-    assert found[0].line == 2
-
-
-def test_device_probe_guarded_by_platform_pin_is_clean(tmp_path):
-    found, _ = _lint(tmp_path, """\
-        import jax
-        jax.config.update("jax_platforms", "cpu")
-        N = len(jax.devices())
-        """, TunnelDeviceProbe)
-    assert found == []
-
-
-def test_device_probe_in_constructor_fires(tmp_path):
-    found, _ = _lint(tmp_path, """\
-        import jax
-
-        class Master:
-            def __init__(self):
-                self.n = jax.device_count()
-        """, TunnelDeviceProbe)
-    assert len(found) == 1
-    assert "constructor" in found[0].message
-
-
-def test_device_probe_in_default_arg_fires(tmp_path):
-    found, _ = _lint(tmp_path, """\
-        import jax
-
-        def fit(n=len(jax.devices())):
-            return n
-        """, TunnelDeviceProbe)
-    assert len(found) == 1
-
-
-def test_device_probe_inside_plain_function_is_clean(tmp_path):
-    # deferred-to-first-use is exactly the sanctioned pattern
-    found, _ = _lint(tmp_path, """\
-        import jax
-
-        def n_devices():
-            return len(jax.devices())
-        """, TunnelDeviceProbe)
-    assert found == []
-
-
-# ---------------------------------------------------------------------------
-# block-until-ready-fence
-# ---------------------------------------------------------------------------
-
-
-def test_block_until_ready_warns_and_suppression_is_honored(tmp_path):
-    found, pf = _lint(tmp_path, """\
-        import jax
-        jax.block_until_ready(x)
-        jax.block_until_ready(y)  # graftlint: disable=block-until-ready-fence -- virtual CPU mesh, never the tunnel
-        """, BlockUntilReadyFence)
-    assert len(found) == 1
-    assert found[0].line == 2
-    assert found[0].severity == "warning"
-    assert pf.bad_suppressions == []
 
 
 # ---------------------------------------------------------------------------
@@ -524,10 +448,10 @@ def test_pallas_rent_suppression_is_honored(tmp_path):
 def test_standalone_suppression_covers_next_code_line(tmp_path):
     found, pf = _lint(tmp_path, """\
         import jax
-        # graftlint: disable=tunnel-device-probe -- fixture: guard proven elsewhere
+        # graftlint: disable=donation-through-dispatch -- fixture: single-owner buffer
 
-        N = len(jax.devices())
-        """, TunnelDeviceProbe)
+        step = jax.jit(f, donate_argnums=(0,))
+        """, DonationThroughDispatch)
     assert found == []
     assert pf.bad_suppressions == []
 
@@ -535,8 +459,8 @@ def test_standalone_suppression_covers_next_code_line(tmp_path):
 def test_suppression_without_justification_is_itself_a_finding(tmp_path):
     _, pf = _lint(tmp_path, """\
         import jax
-        N = len(jax.devices())  # graftlint: disable=tunnel-device-probe
-        """, TunnelDeviceProbe)
+        step = jax.jit(f, donate_argnums=(0,))  # graftlint: disable=donation-through-dispatch
+        """, DonationThroughDispatch)
     assert len(pf.bad_suppressions) == 1
     assert pf.bad_suppressions[0].rule == "bad-suppression"
     assert "justification" in pf.bad_suppressions[0].message
@@ -545,18 +469,18 @@ def test_suppression_without_justification_is_itself_a_finding(tmp_path):
 def test_suppression_of_unknown_rule_is_a_finding(tmp_path):
     _, pf = _lint(tmp_path, """\
         x = 1  # graftlint: disable=no-such-rule -- because
-        """, TunnelDeviceProbe)
+        """, DonationThroughDispatch)
     assert len(pf.bad_suppressions) == 1
     assert "unknown rule" in pf.bad_suppressions[0].message
 
 
 def test_disable_file_covers_every_line(tmp_path):
     found, pf = _lint(tmp_path, """\
-        # graftlint: disable-file=block-until-ready-fence -- fixture: whole file exempt
+        # graftlint: disable-file=donation-through-dispatch -- fixture: whole file exempt
         import jax
-        jax.block_until_ready(x)
-        jax.block_until_ready(y)
-        """, BlockUntilReadyFence)
+        a = jax.jit(f, donate_argnums=(0,))
+        b = jax.jit(g, donate_argnums=(1,))
+        """, DonationThroughDispatch)
     assert found == []
     assert pf.bad_suppressions == []
 
@@ -582,13 +506,13 @@ def test_default_targets_exist():
 
 def test_cli_exit_codes(tmp_path):
     dirty = tmp_path / "dirty.py"
-    dirty.write_text("import jax\nN = len(jax.devices())\n")
+    dirty.write_text("import jax\nf = jax.jit(g, donate_argnums=(0,))\n")
     env = dict(os.environ, PYTHONPATH=REPO)
     r = subprocess.run(
         [sys.executable, "-m", "deeplearning4j_tpu.analysis", "--json",
          str(dirty)], capture_output=True, text=True, env=env, cwd=REPO)
     assert r.returncode == 1, r.stdout + r.stderr
-    assert "tunnel-device-probe" in r.stdout
+    assert "donation-through-dispatch" in r.stdout
     clean = tmp_path / "clean.py"
     clean.write_text("x = 1\n")
     r = subprocess.run(
@@ -600,6 +524,11 @@ def test_cli_exit_codes(tmp_path):
 def test_rule_registry_is_well_formed():
     names = rule_names()
     assert "bad-suppression" in names
+    # the device-probe and completion-fence rules are gone (ISSUE 21):
+    # block_until_ready IS the completion fence, and probing a device is
+    # only wrong in a parent that then starts a child needing the chip
+    assert not any("probe" in n or "fence" in n for n in names)
+    assert "chip_smoke.py" in DEFAULT_TARGETS
     for rule in engine.all_rules():
         assert rule.name and rule.doc
         assert rule.severity in engine.SEVERITIES

@@ -137,8 +137,7 @@ def test_gru_gradients():
 
 def test_mha_gradients():
     """Central-difference check for the MultiHeadAttention layer's dense
-    path (VERDICT r5 ask #6 — the gradcheck backbone stops at GRU while
-    the beyond-reference layers go unchecked). The attention softmax
+    path. The attention softmax
     upcast is at-least-f32 (ops/dtypes.softmax_dtype), so the whole check
     runs in true f64 like the MLP/CNN/LSTM checks."""
     from deeplearning4j_tpu.nn.conf.layers import MultiHeadAttention

@@ -167,8 +167,8 @@ class TestGraphUnderMaster:
 
 class TestResNet50AveragingMode:
     def test_resnet50_averaging_round(self):
-        """The flagship CNN in averaging-compatibility mode (VERDICT round-2
-        missing #1): one full averaging round on the 8-worker mesh, params
+        """The flagship CNN in averaging-compatibility mode: one full
+        averaging round on the 8-worker mesh, params
         move, BN running stats averaged."""
         from deeplearning4j_tpu.models.resnet import build_resnet50
 

@@ -1,4 +1,4 @@
-"""Distributed NLP training tests (VERDICT round-1 missing #4).
+"""Distributed NLP training tests.
 
 Mirrors the reference dl4j-spark-nlp surface: TextPipeline partitioned vocab
 build (spark/text/functions/TextPipeline.java) and data-parallel

@@ -19,9 +19,7 @@ Contracts under test:
     correlation events;
   * instrumented seams emit the expected spans (dispatch trace-vs-cache-
     hit, serve.request -> serve.batch -> dispatch parenting with the
-    request id threading through the batcher, etl waits, ckpt phases);
-  * bench: the obs_overhead leg is registered in scripts/bench_state.py
-    EXPECTED (the watcher's completeness contract).
+    request id threading through the batcher, etl waits, ckpt phases).
 
 Reference provenance: the listener/UI plane these tests grow from is
 deeplearning4j-core/.../optimize/api/IterationListener.java and
@@ -568,19 +566,3 @@ def test_stats_listeners_share_uniform_renderer():
     assert "traces=1" in out and "donated_steps=" in out
     out = rl.render(rl.snapshots[-1])
     assert "retries=2" in out and "backoff_seconds=0.500" in out
-
-
-def test_obs_overhead_leg_registered():
-    """ISSUE 7: the obs_overhead leg is in the expected set — both the
-    live parse of bench.py's run() calls and the EXPECTED fallback — so
-    the watcher's completeness check demands the overhead evidence row
-    every round."""
-    import re
-
-    from scripts.bench_state import EXPECTED, expected_legs
-
-    src = open(os.path.join(REPO, "bench.py")).read()
-    legs_direct = re.findall(r'^\s*run\("([a-z0-9_]+)"', src, re.M)
-    assert "obs_overhead" in legs_direct
-    assert "obs_overhead" in EXPECTED
-    assert "obs_overhead" in expected_legs()

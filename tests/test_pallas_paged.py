@@ -5,8 +5,8 @@ The rent the kernel pays before it may ever go default-on
 the PR 11 block arena; no reference twin, provenance in the module
 docstring):
 
-  * value contract — ``paged_attention`` (interpret mode on this CPU
-    substrate) matches the serving gather path's masked softmax
+  * value contract — ``paged_attention`` (interpret mode, requested
+    here by argument) matches the serving gather path's masked softmax
     attention to f32 rounding, including trash-block invisibility
     (poisoned block 0 cannot move the output);
   * tick contract — ``paged_decode_step(attention='kernel')`` ==
@@ -38,6 +38,20 @@ def tiny_lm(**over):
               max_len=32, use_flash=False)
     kw.update(over)
     return TransformerLM(TransformerConfig(**kw))
+
+
+@pytest.fixture
+def interpreted_kernel(monkeypatch):
+    """Interpret mode is this test's request, not the program's choice:
+    the serving tick calls ``pallas_paged.paged_attention`` compiled, so
+    the CPU tests substitute the interpreted kernel themselves."""
+    import functools
+
+    from deeplearning4j_tpu.ops import pallas_paged
+
+    monkeypatch.setattr(
+        pallas_paged, "paged_attention",
+        functools.partial(pallas_paged.paged_attention, interpret=True))
 
 
 def _arena_case(seed=0, s=4, h=2, hd=16, bt=4, m=4):
@@ -127,7 +141,7 @@ class TestPagedAttentionKernel:
 
 
 class TestPagedDecodeStep:
-    def test_kernel_tick_equals_gather_tick(self):
+    def test_kernel_tick_equals_gather_tick(self, interpreted_kernel):
         from deeplearning4j_tpu.serving.paged import paged_decode_step
 
         lm = tiny_lm()
@@ -186,7 +200,8 @@ class TestForcedKernelTranscripts:
         assert attention_path(lm.cfg, 8) == want
         return scenario(PagedDecoder)
 
-    def test_prefix_sharing_transcripts_identical(self, monkeypatch):
+    def test_prefix_sharing_transcripts_identical(self, monkeypatch,
+                                                  interpreted_kernel):
         """The tests/test_serving_paged.py prefix-sharing scenario —
         shared read tables, trash-pointed write tables, a third
         co-resident — replayed with the kernel forced: greedy
@@ -213,7 +228,8 @@ class TestForcedKernelTranscripts:
         for b, f in zip(base, forced):
             np.testing.assert_array_equal(b, f)
 
-    def test_preemption_transcripts_identical(self, monkeypatch):
+    def test_preemption_transcripts_identical(self, monkeypatch,
+                                              interpreted_kernel):
         """The block-starvation scenario (7 blocks cannot hold three
         23/24-token sequences): preemption + recompute-from-window must
         fire under BOTH paths and the transcripts must agree byte-wise
@@ -272,7 +288,21 @@ class TestPagedGate:
         monkeypatch.delenv("DL4J_TPU_PALLAS_PAGED", raising=False)
         assert attention_path(tiny_lm().cfg, 8) == "gather"
 
-    def test_interpret_on_cpu(self):
-        from deeplearning4j_tpu.ops.pallas_paged import paged_interpret
+    def test_forced_kernel_is_compiled_not_interpreted(self, monkeypatch):
+        """force on a CPU backend must FAIL (Mosaic does not compile
+        there), never quietly run interpreted: the program may not
+        choose interpret mode for itself."""
+        from deeplearning4j_tpu.serving.paged import paged_decode_step
 
-        assert paged_interpret()
+        monkeypatch.setenv("DL4J_TPU_PALLAS_PAGED", "force")
+        lm = tiny_lm()
+        cfg = lm.cfg
+        hd = cfg.d_model // cfg.n_heads
+        shape = (cfg.n_layers, 5, 8, cfg.n_heads, hd)
+        arena = {"k": jnp.zeros(shape, cfg.compute_dtype),
+                 "v": jnp.zeros(shape, cfg.compute_dtype)}
+        with pytest.raises(ValueError, match="Only interpret mode"):
+            jax.block_until_ready(paged_decode_step(
+                lm.params, arena, jnp.zeros((1,), jnp.int32),
+                jnp.zeros((1,), jnp.int32),
+                jnp.zeros((1, cfg.max_len // 8), jnp.int32), cfg))

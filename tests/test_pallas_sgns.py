@@ -2,8 +2,8 @@
 
 The rent ops/pallas_sgns.py pays before it may ever go default-on:
 
-  * f64 gradcheck — ``sgns_fused_step`` (interpret mode on this CPU
-    substrate) matches nlp/word2vec._neg_body to 1e-8 in float64 on a
+  * f64 gradcheck — ``sgns_fused_step`` (interpret mode, requested
+    here by argument) matches nlp/word2vec._neg_body to 1e-8 in float64 on a
     batch with DELIBERATE row collisions (repeated context rows and
     repeated target rows), pinning the two-phase stale-gather /
     sequential-RMW design to XLA's exact ``.at[].add()`` semantics;
@@ -146,8 +146,3 @@ class TestSgnsGate:
 
         monkeypatch.delenv("DL4J_TPU_PALLAS_SGNS", raising=False)
         assert not sgns_kernel_enabled(128, 6, 100)
-
-    def test_interpret_on_cpu(self):
-        from deeplearning4j_tpu.ops.pallas_sgns import sgns_interpret
-
-        assert sgns_interpret()

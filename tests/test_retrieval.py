@@ -183,7 +183,7 @@ class TestAdapters:
         net = tiny_net()
         ad = resolve_adapter(net, input_shape=(8,))
         # dim known BEFORE any __call__ (jax.eval_shape — the /models
-        # tunnel-free contract)
+        # no-dispatch contract)
         assert ad.dim == 12
 
     def test_unsupported_model_raises(self):

@@ -461,16 +461,3 @@ class TestRouterLedger:
             assert body["router"]["proxied_ok"] >= 1
         finally:
             fleet.stop()
-
-    def test_serving_fleet_leg_registered(self):
-        """bench.py defines the serving_fleet leg, bench_state expects
-        it, and it is pinned CPU-only (router accounting + failover are
-        host-side machinery, not a chip benchmark)."""
-        from scripts.bench_state import EXPECTED
-
-        assert "serving_fleet" in EXPECTED
-        src = open(os.path.join(REPO, "bench.py")).read()
-        legs = set(re.findall(r'^\s*run\("([a-z0-9_]+)"', src, re.M))
-        assert "serving_fleet" in legs
-        cpu_only = re.search(r"_CPU_ONLY_LEGS\s*=\s*\{([^}]*)\}", src)
-        assert cpu_only and "serving_fleet" in cpu_only.group(1)

@@ -1,6 +1,7 @@
 """OS-process serving-fleet replicas (ISSUE 12, full tier): the
 production shape of serving/fleet.run_replica — real processes started
-via ``python -m deeplearning4j_tpu.serving.fleet --cpu``, joining the
+via ``python -m deeplearning4j_tpu.serving.fleet`` under
+``JAX_PLATFORMS=cpu``, joining the
 membership board from separate PIDs, answering traffic through the
 router, SIGTERM -> engine drain -> deregister GOODBYE, SIGKILL -> board
 expiry. The in-process contracts live in tests/test_serving_fleet.py
@@ -35,9 +36,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _spawn_replica(fleet_dir, rid, model_path, heartbeat_s=0.5):
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + ":" + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.Popen(
         [sys.executable, "-m", "deeplearning4j_tpu.serving.fleet",
-         "--cpu", "--fleet-dir", str(fleet_dir), "--replica-id", rid,
+         "--fleet-dir", str(fleet_dir), "--replica-id", rid,
          "--model-path", str(model_path),
          "--heartbeat-s", str(heartbeat_s)],
         env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT)

@@ -464,16 +464,3 @@ class TestPlumbing:
         assert snap["prefix_hits"] == 1 and snap["prefix_lookups"] == 2
         assert snap["preemptions"] == 1
         assert snap["shed_by_class"] == {"bulk": 1}
-
-    def test_serving_decode_leg_registered(self):
-        """bench.py defines the serving_decode leg, bench_state expects
-        it, and it is pinned CPU-only (the leg is a scheduler benchmark,
-        not a chip benchmark)."""
-        from scripts.bench_state import EXPECTED
-
-        assert "serving_decode" in EXPECTED
-        src = open(os.path.join(REPO, "bench.py")).read()
-        legs = set(re.findall(r'^\s*run\("([a-z0-9_]+)"', src, re.M))
-        assert "serving_decode" in legs
-        cpu_only = re.search(r"_CPU_ONLY_LEGS\s*=\s*\{([^}]*)\}", src)
-        assert cpu_only and "serving_decode" in cpu_only.group(1)

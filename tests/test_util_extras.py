@@ -1,4 +1,4 @@
-"""Tests for util extras (VERDICT round-1 coverage rows 31/36):
+"""Tests for util extras:
 MovingWindowMatrix, DiskBasedQueue, moving-window text context,
 inverted index."""
 
