@@ -1028,9 +1028,9 @@ def run_batched(plane_on):
 
 run_batched(False); run_batched(True)  # warm thread pools
 
-# interleaved off/on pairs, median-of-ratios (the serving_throughput /
-# obs_overhead methodology: single A-then-B swings with load on this
-# shared 1-core host)
+# interleaved off/on pairs, median-of-ratios (the serving_throughput
+# methodology: single A-then-B swings with load on this shared 1-core
+# host)
 pairs = []
 for _ in range(3):
     off = run_batched(False)
@@ -2798,133 +2798,6 @@ def bench_retrieval(rows=65536, queries=64):
 
 
 # ---------------------------------------------------------------------------
-# obs_overhead: per-step cost of the observability plane (ISSUE 7 —
-# deeplearning4j_tpu/obs/). CPU-measurable by design: spans/journal/
-# registry are HOST-side events only (never a device sync), so the
-# overhead they add to a step is host work on every backend.
-# ---------------------------------------------------------------------------
-
-_OBS_SCRIPT = r"""
-import json, os, sys, tempfile, time, urllib.request
-
-steps = int(sys.argv[1])
-os.environ["DL4J_TPU_OBS"] = "0"
-os.environ["DL4J_TPU_OBS_JOURNAL"] = os.path.join(
-    tempfile.mkdtemp(prefix="obs_bench_"), "journal.jsonl")
-import jax
-jax.config.update("jax_platforms", "cpu")
-import numpy as np
-
-from deeplearning4j_tpu.nn.conf import (DenseLayer, NeuralNetConfiguration,
-                                        OutputLayer)
-from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
-from deeplearning4j_tpu import obs
-
-F, C, batch = 128, 10, 128
-rng = np.random.default_rng(0)
-x = rng.standard_normal((batch, F)).astype(np.float32)
-y = np.eye(C, dtype=np.float32)[rng.integers(0, C, batch)]
-
-
-def build():
-    conf = (NeuralNetConfiguration.builder().seed(7).learning_rate(0.01)
-            .updater("adam").list()
-            .layer(0, DenseLayer(n_in=F, n_out=256, activation="relu"))
-            .layer(1, DenseLayer(n_in=256, n_out=128, activation="relu"))
-            .layer(2, OutputLayer(n_in=128, n_out=C, activation="softmax",
-                                  loss_function="mcxent"))
-            .build())
-    return MultiLayerNetwork(conf).init()
-
-
-def timed(net):
-    # warm: compile + first dispatches outside the timed window (the
-    # overhead question is about the steady state, not the retrace)
-    for _ in range(5):
-        net.fit(x, y)
-    np.asarray(net._score_dev)
-    t0 = time.perf_counter()
-    for _ in range(steps):
-        net.fit(x, y)
-    np.asarray(net._score_dev)  # data-dependent completion fence
-    return (time.perf_counter() - t0) / steps
-
-
-# interleaved off/on pairs on FRESH nets, median-of-ratios (the
-# input_pipeline methodology: single A-then-B swings with load). The env
-# flips between halves of a pair — obs_enabled() reads it per span.
-pairs = []
-net_on = None
-for _ in range(5):
-    os.environ["DL4J_TPU_OBS"] = "0"
-    t_off = timed(build())
-    os.environ["DL4J_TPU_OBS"] = "1"
-    # keep the last obs-on net ALIVE through the scrape below: the
-    # registry holds ledger owners weakly, so a dead net's ledgers are
-    # pruned and the families evidence would always read empty
-    net_on = build()
-    t_on = timed(net_on)
-    pairs.append((t_off, t_on))
-os.environ["DL4J_TPU_OBS"] = "1"
-ratios = sorted((on / off, off, on) for off, on in pairs)
-ratio, t_off, t_on = ratios[len(ratios) // 2]
-
-# evidence the plane actually ran: spans in the ring, a scrapeable
-# exporter, a non-empty journal
-span_count = len(obs.tracer().spans("dispatch.train_step"))
-exp = obs.MetricsExporter().start()
-with urllib.request.urlopen(exp.url + "/metrics", timeout=10) as r:
-    page = r.read().decode()
-exp.stop()
-jpath = obs.default_journal().flush(fsync=True)
-# flush() returns None when the journal path is unwritable — fail the
-# leg with the REAL cause, not a TypeError out of load(None)
-assert jpath, "journal flush failed (journal path unwritable?)"
-journal_events = len(obs.FlightRecorder.load(jpath))
-
-print(json.dumps({
-    "backend": jax.default_backend(),
-    "device": str(jax.devices()[0]),
-    "data": "synthetic",
-    "steps": steps, "batch": batch,
-    "step_ms_obs_off": round(t_off * 1e3, 4),
-    "step_ms_obs_on": round(t_on * 1e3, 4),
-    "overhead_pct": round((ratio - 1.0) * 100.0, 2),
-    "overhead_reps_pct": [round((r[0] - 1.0) * 100.0, 2) for r in ratios],
-    "spans_recorded": span_count,
-    "prometheus_sample_lines": sum(
-        1 for line in page.splitlines() if line and not line.startswith("#")),
-    "ledger_families_in_scrape": sorted({
-        line.split("{")[0].split(" ")[0].split("_")[1]
-        for line in page.splitlines()
-        if line.startswith("dl4j_") and not line.startswith("dl4j_span")}),
-    "journal_events": journal_events,
-    "stat": "median of 5 interleaved off/on pair ratios, fresh net per "
-            "half, steady-state steps only (5-step warmup excluded)",
-    "note": "spans are host-side events only (no device sync added); "
-            "CPU row — host-side span cost is a LARGER fraction of a "
-            "fast CPU step than of a real TPU step, so this bounds the "
-            "on-chip overhead from above",
-}))
-"""
-
-
-def bench_obs_overhead(steps=150):
-    """Observability leg (deeplearning4j_tpu/obs/): per-step wall cost of
-    DL4J_TPU_OBS=1 (span tracer + journal + registry histograms) vs the
-    default-off baseline on the MLP hot path, plus proof the plane ran
-    (span counts, a live Prometheus scrape, journal events).
-    Subprocess-isolated; CPU-only by design — spans are host-side, so
-    the CPU number upper-bounds the on-chip fraction (acceptance bar:
-    < 5% step-time delta)."""
-    parsed, err = _run_subprocess_json(
-        [sys.executable, "-c", _OBS_SCRIPT, str(steps)], 900)
-    if parsed is None:
-        return {"error": err}
-    return parsed
-
-
-# ---------------------------------------------------------------------------
 # CPU-for-CPU baseline: OUR framework on jax-CPU vs the torch-CPU rows
 # (a baseline ratio that needs no chip)
 # ---------------------------------------------------------------------------
@@ -3443,8 +3316,6 @@ def _legs(quick: bool) -> dict:
                                         reps=8 if quick else 20)),
         "retrieval": (bench_retrieval,
                       dict(rows=32768 if quick else 65536, queries=64)),
-        "obs_overhead": (bench_obs_overhead,
-                         dict(steps=50 if quick else 150)),
         "reference_cpu_lenet5_torch": (bench_torch_lenet_cpu,
                                        dict(steps=3 if quick else 8)),
         "lenet5_cpu": (bench_lenet_cpu, dict(quick=quick)),

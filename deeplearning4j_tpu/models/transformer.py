@@ -281,14 +281,16 @@ def _dense_block_f32(bp, h, n_heads: int, attend=None, ffn=None,
     c = lambda a: a.astype(cdt)
     if attend is None:
         attend = lambda q, k, v: _attention(q, k, v, n_heads)
-    x = _ln(h, c(bp["ln1_g"]), c(bp["ln1_b"]))
-    q, k, v = x @ c(bp["Wq"]), x @ c(bp["Wk"]), x @ c(bp["Wv"])
-    h = h + attend(q, k, v) @ c(bp["Wo"])
-    x = _ln(h, c(bp["ln2_g"]), c(bp["ln2_b"]))
-    if ffn is not None:
-        return h + ffn(x)
-    return (h + jax.nn.gelu(x @ c(bp["W1"]) + c(bp["b1"])) @ c(bp["W2"])
-            + c(bp["b2"]))
+    with jax.named_scope("block.attn"):
+        x = _ln(h, c(bp["ln1_g"]), c(bp["ln1_b"]))
+        q, k, v = x @ c(bp["Wq"]), x @ c(bp["Wk"]), x @ c(bp["Wv"])
+        h = h + attend(q, k, v) @ c(bp["Wo"])
+    with jax.named_scope("block.mlp"):
+        x = _ln(h, c(bp["ln2_g"]), c(bp["ln2_b"]))
+        if ffn is not None:
+            return h + ffn(x)
+        return (h + jax.nn.gelu(x @ c(bp["W1"]) + c(bp["b1"]))
+                @ c(bp["W2"]) + c(bp["b2"]))
 
 
 def _moe_ffn(bp, h, cfg: TransformerConfig, capacity: int = 0):
@@ -341,27 +343,35 @@ def _moe_block(bp, h, cfg: TransformerConfig, *, attend=None, cdt,
 def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
             ) -> Tuple[jax.Array, jax.Array]:
     """tokens [N, T] int32 -> (logits [N, T, V] f32, aux_loss scalar)."""
+    # the scopes (embed, block.attn, block.mlp, head_loss; grad_accum and
+    # adam in the step) name the step's parts in the compiled program's
+    # metadata and so in a device trace; they change no arithmetic
     cdt = cfg.compute_dtype
     n, t = tokens.shape
-    h = params["embed"][tokens] + params["pos"][:t][None]
-    h = h.astype(cdt)
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens] + params["pos"][:t][None]
+        h = h.astype(cdt)
 
     def block(carry, bp):
         h, aux = carry
-        x = _ln(h, bp["ln1_g"].astype(cdt), bp["ln1_b"].astype(cdt))
-        q, k, v = x @ bp["Wq"].astype(cdt), x @ bp["Wk"].astype(cdt), \
-            x @ bp["Wv"].astype(cdt)
-        h = h + _attention(q, k, v, cfg.n_heads,
-                           use_flash=cfg.use_flash) @ bp["Wo"].astype(cdt)
-        x = _ln(h, bp["ln2_g"].astype(cdt), bp["ln2_b"].astype(cdt))
-        if cfg.moe_experts:
-            bp16 = {kk: vv.astype(cdt) for kk, vv in bp.items()}
-            y, a = _moe_ffn(bp16, x, cfg)
-            h = h + y
-            aux = aux + a
-        else:
-            inner = jax.nn.gelu(x @ bp["W1"].astype(cdt) + bp["b1"].astype(cdt))
-            h = h + inner @ bp["W2"].astype(cdt) + bp["b2"].astype(cdt)
+        with jax.named_scope("block.attn"):
+            x = _ln(h, bp["ln1_g"].astype(cdt), bp["ln1_b"].astype(cdt))
+            q, k, v = x @ bp["Wq"].astype(cdt), x @ bp["Wk"].astype(cdt), \
+                x @ bp["Wv"].astype(cdt)
+            h = h + _attention(q, k, v, cfg.n_heads,
+                               use_flash=cfg.use_flash) \
+                @ bp["Wo"].astype(cdt)
+        with jax.named_scope("block.mlp"):
+            x = _ln(h, bp["ln2_g"].astype(cdt), bp["ln2_b"].astype(cdt))
+            if cfg.moe_experts:
+                bp16 = {kk: vv.astype(cdt) for kk, vv in bp.items()}
+                y, a = _moe_ffn(bp16, x, cfg)
+                h = h + y
+                aux = aux + a
+            else:
+                inner = jax.nn.gelu(x @ bp["W1"].astype(cdt)
+                                    + bp["b1"].astype(cdt))
+                h = h + inner @ bp["W2"].astype(cdt) + bp["b2"].astype(cdt)
         return (h, aux), None
 
     from deeplearning4j_tpu.ops.remat import remat_wrap
@@ -374,9 +384,10 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
     block = remat_wrap(block, cfg.remat, prevent_cse=False)
     (h, aux), _ = lax.scan(block, (h, jnp.zeros((), jnp.float32)),
                            params["blocks"])
-    h = _ln(h.astype(jnp.float32), params["lnf_g"], params["lnf_b"])
-    logits = h @ params["embed"].T  # tied head
-    return logits.astype(jnp.float32), aux / cfg.n_layers
+    with jax.named_scope("head_loss"):
+        h = _ln(h.astype(jnp.float32), params["lnf_g"], params["lnf_b"])
+        logits = h @ params["embed"].T  # tied head
+        return logits.astype(jnp.float32), aux / cfg.n_layers
 
 
 def nll_loss(logits: jax.Array, targets: jax.Array, mask=None) -> jax.Array:
@@ -399,7 +410,8 @@ def nll_loss(logits: jax.Array, targets: jax.Array, mask=None) -> jax.Array:
 def loss_fn(params: Params, tokens: jax.Array, targets: jax.Array,
             cfg: TransformerConfig) -> jax.Array:
     logits, aux = forward(params, tokens, cfg)
-    return nll_loss(logits, targets) + cfg.moe_aux_coef * aux
+    with jax.named_scope("head_loss"):
+        return nll_loss(logits, targets) + cfg.moe_aux_coef * aux
 
 
 # ---------------------------------------------------------------------------
@@ -453,6 +465,7 @@ def _decay_mask(params):
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
+@partial(jax.named_call, name="adam")
 def _adam_update(params, grads, opt, lr, b1=0.9, b2=0.999, eps=1e-8,
                  weight_decay=0.0, clip_grad_norm=0.0):
     if clip_grad_norm:
@@ -585,8 +598,9 @@ def _build_step(cfg: TransformerConfig):
                 loss_a, grads_a = carry
                 loss_i, grads_i = jax.value_and_grad(grad_loss)(
                     params, xy[0], xy[1])
-                grads_a = jax.tree_util.tree_map(
-                    lambda a, g: a + g / accum_steps, grads_a, grads_i)
+                with jax.named_scope("grad_accum"):
+                    grads_a = jax.tree_util.tree_map(
+                        lambda a, g: a + g / accum_steps, grads_a, grads_i)
                 return (loss_a + loss_i / accum_steps, grads_a), None
 
             zero = jax.tree_util.tree_map(jnp.zeros_like, params)

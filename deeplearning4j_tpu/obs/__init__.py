@@ -12,8 +12,11 @@ survives preemption; Prometheus text exposition is served by both the
 serving engine's ``/metrics`` and the standalone training
 :class:`MetricsExporter`.
 
-Everything here is host-side and stdlib-only — no jax import, no device
-syncs (the listener-chain bulk-readback rule).
+Everything here is host-side and imports only the stdlib — no device
+syncs (the listener-chain bulk-readback rule). The one touch of jax: where
+the process has ALREADY loaded it, a live span is also a
+``jax.profiler.TraceAnnotation`` (obs/trace.py), so a profiler session
+sees the program's spans on the device trace's clock.
 """
 
 from deeplearning4j_tpu.obs.exporter import MetricsExporter
@@ -31,7 +34,9 @@ from deeplearning4j_tpu.obs.trace import (
     ENV_OBS,
     Span,
     Tracer,
+    close_span,
     obs_enabled,
+    open_span,
     record_span,
     set_enabled,
     span,
@@ -45,10 +50,12 @@ __all__ = [
     "MetricsRegistry",
     "Span",
     "Tracer",
+    "close_span",
     "default_journal",
     "default_journal_path",
     "default_registry",
     "obs_enabled",
+    "open_span",
     "record_span",
     "register_net",
     "set_enabled",

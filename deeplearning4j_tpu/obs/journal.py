@@ -37,7 +37,7 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from deeplearning4j_tpu.ops import env as envknob
 
@@ -90,6 +90,8 @@ class FlightRecorder:
         self._markers: deque = deque(
             maxlen=min(self.capacity, max(16, self.capacity // 16)))
         self._seq = 0
+        # what turns a span record (append_span) into its event's fields
+        self._span_fields: Optional[Callable[[Any], Dict[str, Any]]] = None
         self._dirty = False
         self._last_flush = time.monotonic()
         self.flushes = 0
@@ -106,16 +108,33 @@ class FlightRecorder:
         return ev
 
     def append(self, ev: Dict[str, Any]) -> None:
-        """Light-path append for PRE-stamped events — the tracer's
-        finished spans already carry ``t_wall``/``t_mono``, so re-reading
-        both clocks and merging a second dict would be pure hot-path
-        waste. Assigns ``seq`` and rings; same flush policy as record."""
+        """Light-path append for PRE-stamped events: assigns ``seq`` and
+        rings; same flush policy as record."""
+        self._push(ev, ev)
+
+    def append_span(self, rec: Any,
+                    fields: Callable[[Any], Dict[str, Any]]) -> None:
+        """A finished span as the tracer keeps it. ``rec`` is the
+        tracer's own and opaque here: it is kept as it came (the tracer
+        makes it something the garbage collector does not track, as it
+        would an event's dict), and ``fields(rec)`` gives the event's
+        fields when the ring is read or flushed (``kind`` ``span``). The
+        record is already stamped with both clocks."""
+        self._span_fields = fields
+        self._push(rec, None)
+
+    def _push(self, entry: Any, ev: Optional[Dict[str, Any]]) -> None:
+        """Ring one entry: an event dict (``ev`` is ``entry``) or a span
+        record (``ev`` None). Every push takes the next ``seq`` and one
+        place in the main ring, so a record's ``seq`` is its place there
+        and need not be written into it."""
         with self._lock:
             self._seq += 1
-            ev["seq"] = self._seq
-            self._ring.append(ev)
-            if ev.get("kind") != "span":
-                self._markers.append(ev)
+            if ev is not None:
+                ev["seq"] = self._seq
+                if ev.get("kind") != "span":
+                    self._markers.append(ev)
+            self._ring.append(entry)
             self._dirty = True
             due = (time.monotonic() - self._last_flush
                    >= self.flush_interval_s and not self._bg_pending)
@@ -156,14 +175,18 @@ class FlightRecorder:
             with self._lock:
                 if not self._dirty and not fsync:
                     return None
-                events = self._merged_locked()
+                entries = self._merged_locked()
                 self._dirty = False
                 self._last_flush = time.monotonic()
             tmp = f"{self.path}.tmp-{os.getpid()}"
             try:
                 with open(tmp, "w") as f:
-                    for ev in events:
-                        f.write(json.dumps(ev, default=str) + "\n")
+                    # one event at a time: a span's dict lives for its
+                    # own line only, so a flush leaves the collector
+                    # nothing to promote
+                    for seq, e in entries:
+                        f.write(json.dumps(self._event(seq, e),
+                                           default=str) + "\n")
                     f.flush()
                     if fsync:
                         os.fsync(f.fileno())
@@ -189,18 +212,28 @@ class FlightRecorder:
             self.flushes += 1
         return self.path
 
-    def _merged_locked(self) -> List[Dict[str, Any]]:
-        """Main ring + pinned markers, seq-ordered and deduped (a recent
-        marker sits in both rings) — the one timeline every read surface
-        and every flush presents."""
+    def _merged_locked(self) -> List[Tuple[int, Any]]:
+        """Main ring + pinned markers as ``(seq, entry)``, seq-ordered
+        and deduped (a recent marker sits in both rings) — the one
+        timeline every read surface and every flush presents, once
+        :meth:`_event` has made each entry its event."""
         merged = {e["seq"]: e for e in self._markers}
-        merged.update({e["seq"]: e for e in self._ring})
-        return [merged[s] for s in sorted(merged)]
+        merged.update(enumerate(self._ring,
+                                self._seq - len(self._ring) + 1))
+        return sorted(merged.items())
+
+    def _event(self, seq: int, entry: Any) -> Dict[str, Any]:
+        """A ring entry as its event: a dict is one already, a span
+        record becomes one through the reader that came with it."""
+        if type(entry) is dict:
+            return entry
+        return dict(self._span_fields(entry), kind="span", seq=seq)
 
     # -- reading ----------------------------------------------------------
     def events(self, kind: Optional[str] = None) -> List[Dict[str, Any]]:
         with self._lock:
-            out = self._merged_locked()
+            entries = self._merged_locked()
+        out = [self._event(seq, e) for seq, e in entries]
         if kind is not None:
             out = [e for e in out if e.get("kind") == kind]
         return out

@@ -151,8 +151,9 @@ _register("DL4J_TPU_SERVE_KV_DTYPE", "", "enum",
 _register("DL4J_TPU_OBS", "0", "bool",
           "span tracer master switch (default OFF; obs off => training "
           "bit-exact)")
-_register("DL4J_TPU_OBS_SPANS", "4096", "int",
-          "span ring capacity per tracer")
+_register("DL4J_TPU_OBS_SPANS", "65536", "int",
+          "span ring capacity per tracer (what the full ring pushes out is "
+          "counted: Tracer.dropped, dl4j_spans_dropped_total)")
 _register("DL4J_TPU_OBS_JOURNAL", "", "path",
           "flight-recorder JSONL path; '' = .obs_journal[.pN].jsonl under "
           "cwd (N = fleet/multihost process id)")

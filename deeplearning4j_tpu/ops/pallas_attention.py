@@ -143,6 +143,7 @@ def _flash_raw(q, k, v, *, causal: bool, interpret: bool):
             jax.ShapeDtypeStruct((b, 8, t), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",   # the kernel's name in the compiled program
     )(q, k, v)
 
 
@@ -217,7 +218,9 @@ def _flash_bwd(causal, interpret, res, g):
             unstack(dvs).astype(v.dtype))
 
 
-_flash.defvjp(_flash_fwd, _flash_bwd)
+# the backward is plain XLA: the scope is what tells its fusions from the
+# rest of the step in a device trace
+_flash.defvjp(_flash_fwd, jax.named_call(_flash_bwd, name="flash_bwd"))
 
 
 # ---------------------------------------------------------------------------
@@ -319,6 +322,7 @@ def _flash_ext_raw(q, k, v, kb, off, *, interpret: bool):
             jax.ShapeDtypeStruct((b, 8, tq), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_ext_fwd",
     )(off, q, k, v, kb)
 
 
@@ -382,7 +386,8 @@ def _flash_ext_bwd(interpret, res, gs):
             np.zeros(off.shape, jax.dtypes.float0))
 
 
-_flash_ext.defvjp(_flash_ext_fwd, _flash_ext_bwd)
+_flash_ext.defvjp(_flash_ext_fwd,
+                  jax.named_call(_flash_ext_bwd, name="flash_ext_bwd"))
 
 
 def flash_attention_block(q, k, v, *, offset, key_mask=None,

@@ -76,6 +76,7 @@ import queue as stdqueue
 import signal
 import threading
 import time
+import weakref
 from concurrent.futures import TimeoutError as FutureTimeoutError
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Dict, Optional
@@ -559,21 +560,39 @@ class ServingEngine:
         breaker = self._admit(rec)
         rid = next(self._rid)
         q: stdqueue.Queue = stdqueue.Queue()
-        with obs_trace.span("serve.request", rid=rid, model=rec.key,
-                            rows=1, kind="generate_stream"):
+        # the request's span lives from here to the end of the stream,
+        # which another thread may reach: the decoder hangs the queue,
+        # admission and tick spans under it, all carrying rid
+        sp = obs_trace.open_span("serve.request", rid=rid, model=rec.key,
+                                 rows=1, kind="generate_stream")
+        on_token = q.put
+        if sp is not obs_trace.NULL_SPAN:
+            def on_token(t):
+                if "ttft_s" not in sp.attrs:
+                    sp.set_attr("ttft_s", time.perf_counter() - sp.start)
+                q.put(t)
+        try:
             fut = decoder.submit(prompt, int(n_new),
                                  temperature=float(temperature),
-                                 seed=int(seed), slo=slo, on_token=q.put)
+                                 seed=int(seed), slo=slo, on_token=on_token,
+                                 parent=sp)
+        except BaseException as e:
+            sp.set_attr("error", type(e).__name__)
+            obs_trace.close_span(sp)
+            raise
 
         def stream():
-            while True:
-                try:
-                    yield int(q.get(timeout=0.2))
-                    continue
-                except stdqueue.Empty:
-                    pass
-                if not fut.done():
-                    continue
+            sent = 0
+            try:
+                while True:
+                    try:
+                        t = q.get(timeout=0.2)
+                    except stdqueue.Empty:
+                        if fut.done():
+                            break
+                        continue
+                    sent += 1
+                    yield int(t)
                 # on_token callbacks run BEFORE the future resolves
                 # (serving/paged.py), so a done future means every token
                 # is already queued — drain, then finish
@@ -588,11 +607,25 @@ class ServingEngine:
                 breaker.record_success()
                 while True:
                     try:
-                        yield int(q.get_nowait())
+                        t = q.get_nowait()
                     except stdqueue.Empty:
                         return
+                    sent += 1
+                    yield int(t)
+            except BaseException as e:
+                sp.set_attr("error", type(e).__name__)
+                raise
+            finally:
+                sp.set_attr("tokens", sent)
+                obs_trace.close_span(sp)
 
-        return stream()
+        out = stream()
+        if sp is not obs_trace.NULL_SPAN:
+            # a generator nobody starts never reaches its ``finally``:
+            # the span then closes when the generator is let go, so the
+            # spans under it are not left naming a parent outside the ring
+            weakref.finalize(out, obs_trace.close_span, sp)
+        return out
 
     def prefill_for(self, name, version, tokens, n_new: int):
         """Prefill half of the disaggregated handoff (serving/mesh role

@@ -157,21 +157,25 @@ def paged_decode_step(params, arena, tok, pos, tables,
         q = (x @ c(bp["Wq"])).reshape(s, cfg.n_heads, hd)
         k1 = (x @ c(bp["Wk"])).reshape(s, cfg.n_heads, hd)
         v1 = (x @ c(bp["Wv"])).reshape(s, cfg.n_heads, hd)
-        ck = ck.at[wb, off].set(k1.astype(ck.dtype))
-        cv = cv.at[wb, off].set(v1.astype(cv.dtype))
+        with jax.named_scope("tick.scatter"):
+            ck = ck.at[wb, off].set(k1.astype(ck.dtype))
+            cv = cv.at[wb, off].set(v1.astype(cv.dtype))
         if attention == "kernel":
-            att = pallas_paged.paged_attention(
-                q, ck, cv, tables, pos).reshape(s, 1, cfg.d_model)
+            with jax.named_scope("tick.attend"):
+                att = pallas_paged.paged_attention(
+                    q, ck, cv, tables, pos).reshape(s, 1, cfg.d_model)
         else:
-            kg = ck[tables].reshape(s, t_total, cfg.n_heads, hd)
-            vg = cv[tables].reshape(s, t_total, cfg.n_heads, hd)
-            sc = jnp.einsum("nhd,nthd->nht", q.astype(jnp.float32),
-                            kg.astype(jnp.float32)) * scale
-            sc = jnp.where(visible[:, None, :], sc, -jnp.inf)
-            p = jax.nn.softmax(sc, axis=-1)
-            att = jnp.einsum(
-                "nht,nthd->nhd", p,
-                vg.astype(jnp.float32)).reshape(s, 1, cfg.d_model)
+            with jax.named_scope("tick.gather_kv"):
+                kg = ck[tables].reshape(s, t_total, cfg.n_heads, hd)
+                vg = cv[tables].reshape(s, t_total, cfg.n_heads, hd)
+            with jax.named_scope("tick.attend"):
+                sc = jnp.einsum("nhd,nthd->nht", q.astype(jnp.float32),
+                                kg.astype(jnp.float32)) * scale
+                sc = jnp.where(visible[:, None, :], sc, -jnp.inf)
+                p = jax.nn.softmax(sc, axis=-1)
+                att = jnp.einsum(
+                    "nht,nthd->nhd", p,
+                    vg.astype(jnp.float32)).reshape(s, 1, cfg.d_model)
         h = h + att.astype(cdt) @ c(bp["Wo"])
         x = _ln(h, c(bp["ln2_g"]), c(bp["ln2_b"]))
         h = h + jax.nn.gelu(x @ c(bp["W1"]) + c(bp["b1"])) @ c(bp["W2"]) \
@@ -208,7 +212,8 @@ def _paged_tick_for(cfg: TransformerConfig, block_tokens: int, k: int = 1):
         def tick(params, arena, tok, pos, tables, keys, temps):
             arena, logits = paged_decode_step(params, arena, tok, pos,
                                               tables, cfg, attention=path)
-            nxt, nkeys = _sample_step(logits, keys, temps)
+            with jax.named_scope("tick.sample"):
+                nxt, nkeys = _sample_step(logits, keys, temps)
             return arena, nxt[:, None], nkeys
     else:
         # k scanned steps in ONE dispatch: the per-step body (scatter at
@@ -221,7 +226,8 @@ def _paged_tick_for(cfg: TransformerConfig, block_tokens: int, k: int = 1):
                 arena, tok, pos, keys = carry
                 arena, logits = paged_decode_step(
                     params, arena, tok, pos, tables, cfg, attention=path)
-                nxt, keys = _sample_step(logits, keys, temps)
+                with jax.named_scope("tick.sample"):
+                    nxt, keys = _sample_step(logits, keys, temps)
                 return (arena, nxt, pos + 1, keys), nxt
 
             (arena, _, _, keys), toks = lax.scan(
@@ -250,13 +256,17 @@ def _paged_admit_for(cfg: TransformerConfig, width: int, block_tokens: int):
         # shared-prefix and beyond-prompt entries to trash block 0:
         # shared blocks are NEVER written after their creating prefill
         # (the prefix-cache byte-stability invariant).
-        c1, _ = prefill_cache(params, window, cfg)
-        kb = c1["k"][:, 0].reshape(cfg.n_layers, m, block_tokens,
-                                   cfg.n_heads, hd)
-        vb = c1["v"][:, 0].reshape(cfg.n_layers, m, block_tokens,
-                                   cfg.n_heads, hd)
-        ak = arena["k"].at[:, write_table].set(kb.astype(arena["k"].dtype))
-        av = arena["v"].at[:, write_table].set(vb.astype(arena["v"].dtype))
+        with jax.named_scope("admit.prefill"):
+            c1, _ = prefill_cache(params, window, cfg)
+        with jax.named_scope("admit.scatter"):
+            kb = c1["k"][:, 0].reshape(cfg.n_layers, m, block_tokens,
+                                       cfg.n_heads, hd)
+            vb = c1["v"][:, 0].reshape(cfg.n_layers, m, block_tokens,
+                                       cfg.n_heads, hd)
+            ak = arena["k"].at[:, write_table].set(
+                kb.astype(arena["k"].dtype))
+            av = arena["v"].at[:, write_table].set(
+                vb.astype(arena["v"].dtype))
         return {"k": ak, "v": av}
 
     admit = dispatch.arena_jit(admit, donate=(1,))
@@ -420,14 +430,29 @@ class PrefixCache:
         return freed
 
 
+class _ReqTrace:
+    """What the spans of one request share, made at submit when tracing
+    is on: the span that caused the request (the engine's
+    ``serve.request``) and its ``rid``, when the request last joined the
+    queue, and how often a preemption has put it back there."""
+
+    __slots__ = ("parent", "rid", "since", "requeued")
+
+    def __init__(self, parent, rid, since, requeued=0) -> None:
+        self.parent = parent
+        self.rid = rid
+        self.since = since
+        self.requeued = requeued
+
+
 class _PendingReq:
     __slots__ = ("prompt", "n_new", "temperature", "seed", "future",
                  "deadline", "enqueued", "slo", "on_token", "tokens",
-                 "key_override", "seq")
+                 "key_override", "seq", "trace")
 
     def __init__(self, prompt, n_new, temperature, seed, deadline, slo,
                  on_token, seq, future=None, tokens=None,
-                 key_override=None, enqueued=None) -> None:
+                 key_override=None, enqueued=None, trace=None) -> None:
         self.prompt = prompt
         self.n_new = n_new
         self.temperature = temperature
@@ -441,12 +466,13 @@ class _PendingReq:
         self.tokens = tokens if tokens is not None else []
         self.key_override = key_override  # preemption-saved PRNG key
         self.seq = seq
+        self.trace = trace  # _ReqTrace, or None with tracing off
 
 
 class _Lane:
     __slots__ = ("future", "tokens", "remaining", "deadline", "enqueued",
                  "temperature", "seed", "slo", "on_token", "blocks",
-                 "n_table", "window", "admit_seq")
+                 "n_table", "window", "admit_seq", "trace")
 
     def __init__(self, req: _PendingReq, blocks: List[int], n_table: int,
                  window: np.ndarray, admit_seq: int) -> None:
@@ -463,6 +489,7 @@ class _Lane:
         self.n_table = n_table    # allocated read-table entries
         self.window = window      # re-based prompt (for preempt requeue)
         self.admit_seq = admit_seq
+        self.trace = req.trace
 
 
 class PagedDecoder:
@@ -549,6 +576,10 @@ class PagedDecoder:
         self._dead: Optional[str] = None
         self._seq = 0        # submit/requeue order (shed picks youngest)
         self._admit_seq = 0  # admission order (preemption picks youngest)
+        # prefills dispatched since the last tick, and their summed
+        # widths: attributes of the tick's span, which waits them out
+        self._admits = 0
+        self._admit_width_sum = 0
         self.peak_active = 0
         # multi-token ticks (ISSUE 16): steady-state decode scans tick_k
         # steps per dispatch, adaptively dropping to 1 whenever
@@ -656,12 +687,17 @@ class PagedDecoder:
     # -- client side ------------------------------------------------------
     def submit(self, prompt, n_new: int, temperature: float = 1.0,
                seed: int = 0, timeout_s: Optional[float] = None,
-               slo: Optional[str] = None, on_token=None) -> Future:
+               slo: Optional[str] = None, on_token=None,
+               parent=None) -> Future:
         """Queue one prompt ([T] int ids) for n_new sampled tokens;
         returns a Future of the [n_new] int32 continuation. ``slo``
         names a scheduling class (default: the highest-priority one);
         ``on_token`` is called with each token as it is sampled (the
-        streaming hook — keep it fast, it runs on the decode thread)."""
+        streaming hook — keep it fast, it runs on the decode thread).
+        ``parent`` is the caller's request span where it is not the one
+        open on this thread (a streamed request's outlives this call):
+        with tracing on, the request's queue, admission and tick spans
+        hang under it and carry its ``rid``."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("empty prompt")
@@ -693,6 +729,12 @@ class PagedDecoder:
             req = _PendingReq(prompt, int(n_new), float(temperature),
                               int(seed), deadline, cls.name, on_token,
                               self._seq)
+            if obs_trace.obs_enabled():
+                if parent is None:
+                    parent = obs_trace.tracer().current_span()
+                req.trace = _ReqTrace(
+                    getattr(parent, "span_id", None),
+                    getattr(parent, "attrs", {}).get("rid"), req.enqueued)
             if self.queue_cap is not None and \
                     self._total_pending() >= self.queue_cap:
                 victim = self._shed_for(cls)
@@ -804,7 +846,10 @@ class PagedDecoder:
                           lane.on_token, self._seq, future=lane.future,
                           tokens=lane.tokens,
                           key_override=self._keys[i].copy(),
-                          enqueued=lane.enqueued)
+                          enqueued=lane.enqueued, trace=lane.trace)
+        if lane.trace is not None:
+            lane.trace.since = time.monotonic()
+            lane.trace.requeued += 1
         self._release_lane(i)
         self._pending[lane.slo].appendleft(req)
         self.stats.record_preemption()
@@ -850,11 +895,22 @@ class PagedDecoder:
             if not q:
                 continue
             req = q.popleft()
+            tr = req.trace
+            picked = time.monotonic() if tr is not None else 0.0
             booked = self._admit_bookkeeping(free, req)
             if booked is None:
                 q.appendleft(req)
                 return None
-            self.stats.set_queue_depth(self._total_pending(), "decode")
+            pending = self._total_pending()
+            self.stats.set_queue_depth(pending, "decode")
+            if tr is not None:
+                # the wait ended at the pick; the booking since then is
+                # the admission's own time (serve.admit)
+                obs_trace.record_span(
+                    "serve.queue", picked - tr.since,
+                    ago=time.monotonic() - picked, parent=tr.parent,
+                    rid=tr.rid, slo=req.slo, pending=pending,
+                    requeued=tr.requeued)
             return (free,) + booked
         return None
 
@@ -1105,96 +1161,138 @@ class PagedDecoder:
 
     def _run_inner(self) -> None:
         while True:
-            with self._cond:
-                now = time.monotonic()
-                for i in range(self.lanes):
-                    st = self._slots[i]
-                    if st is not None and st.deadline < now:
-                        if not st.future.done():
-                            self.stats.record_timeout()
-                            st.future.set_exception(RequestTimeoutError(
-                                "generation exceeded its deadline"))
-                        self._release_lane(i)
-                for name, q in self._pending.items():
-                    alive = deque()
-                    for req in q:
-                        if req.deadline < now and not req.future.done():
-                            self.stats.record_timeout()
-                            req.future.set_exception(RequestTimeoutError(
-                                "generation request expired in queue"))
-                        else:
-                            alive.append(req)
-                    self._pending[name] = alive
-            # adopt handed-off prefix blocks BEFORE admissions so a
-            # request admitted in this same pass hits them (the
-            # prefill/decode disaggregation import path)
-            while True:
-                with self._cond:
-                    item = self._imports.popleft() if self._imports \
-                        else None
-                if item is None:
-                    break
-                self._apply_import(*item)
+            with obs_trace.span("serve.sweep"):
+                self._sweep()
             # admission: ONE request per pick so a request admitted
             # later in the same pass can hit the prefix blocks an
             # earlier prefill just cached — inserts land between
             # prefills, and only after the block content is actually
             # written (a crashed prefill never publishes its digests)
-            while True:
-                with self._cond:
-                    picked = self._pick_admission()
-                if picked is None:
-                    break
-                i, buf, width, write_table, inserts = picked
-                try:
-                    if self._chaos is not None:
-                        self._chaos.on_admit()
-                    self._admit_prefill(i, buf, width, write_table)
-                except Exception as e:  # noqa: BLE001 — lane isolation boundary
-                    # a crashed admission evicts ONLY its own lane and
-                    # returns its blocks to the free list; the prefill
-                    # wrote (at most) trash + this lane's private
-                    # blocks, so co-residents' tokens are untouched
-                    # (the PR 8 crash-eviction contract carried onto
-                    # the paged pool)
-                    with self._cond:
-                        st = self._slots[i]
-                        self._release_lane(i)
-                        self._cond.notify_all()
-                    if st is not None and not st.future.done():
-                        st.future.set_exception(e)
-                    self.stats.record_slot_crash()
-                    try:
-                        deleted = self._arena["k"].is_deleted()
-                    except Exception:  # noqa: BLE001 — probe only
-                        deleted = False
-                    if deleted:
-                        # the DONATED admit died mid-execution and took
-                        # the arena with it: co-resident KV is gone, so
-                        # honest failure beats silently garbage tokens
-                        self._fail_active_lanes(e)
-                        break
-                else:
-                    with self._cond:
-                        for digest, block in inserts:
-                            self._prefix.insert(digest, block)
+            while self._admit_one():
+                pass
             if not self._tick_phase():
                 return
+
+    def _sweep(self) -> None:
+        """The head of a worker pass: expire what has outlived its
+        deadline, in a lane or in the queue, and adopt handed-off prefix
+        blocks."""
+        with self._cond:
+            now = time.monotonic()
+            for i in range(self.lanes):
+                st = self._slots[i]
+                if st is not None and st.deadline < now:
+                    if not st.future.done():
+                        self.stats.record_timeout()
+                        st.future.set_exception(RequestTimeoutError(
+                            "generation exceeded its deadline"))
+                    self._release_lane(i)
+            for name, q in self._pending.items():
+                alive = deque()
+                for req in q:
+                    if req.deadline < now and not req.future.done():
+                        self.stats.record_timeout()
+                        req.future.set_exception(RequestTimeoutError(
+                            "generation request expired in queue"))
+                    else:
+                        alive.append(req)
+                self._pending[name] = alive
+        # adopt handed-off prefix blocks BEFORE admissions so a
+        # request admitted in this same pass hits them (the
+        # prefill/decode disaggregation import path)
+        while True:
+            with self._cond:
+                item = self._imports.popleft() if self._imports \
+                    else None
+            if item is None:
+                break
+            self._apply_import(*item)
+
+    def _admit_one(self) -> bool:
+        """Pick, book and prefill ONE request; False when the pass is
+        over (nothing admissible, or the arena died with the prefill).
+        The ``serve.admit`` span runs from the pick to the dispatch's
+        return; less its child ``serve.admit.dispatch`` (upload and
+        dispatch) it is the booking under the lock. The prefill is NOT
+        waited for: its device time shows in the next tick's wait, which
+        is why ``serve.batch`` counts the admissions ahead of it."""
+        if not self._total_pending():
+            # an unlocked look, so that a pass with nothing to admit opens
+            # no span: a request that lands now is picked by the next pass
+            return False
+        with obs_trace.span("serve.admit") as sp:
+            with self._cond:
+                picked = self._pick_admission()
+            if picked is None:
+                sp.discard()
+                return False
+            i, buf, width, write_table, inserts = picked
+            lane = self._slots[i]
+            tr = lane.trace
+            if tr is not None:
+                fresh = int(np.count_nonzero(write_table))
+                sp.set_parent(tr.parent)
+                for key, value in (
+                        ("rid", tr.rid), ("lane", i),
+                        ("prompt_tokens", int(lane.window.size)),
+                        ("width", width),
+                        ("hit_blocks", lane.n_table - fresh),
+                        ("lookup_blocks", lane.n_table - 1),
+                        ("fresh_blocks", fresh)):
+                    sp.set_attr(key, value)
+            try:
+                if self._chaos is not None:
+                    self._chaos.on_admit()
+                with obs_trace.span("serve.admit.dispatch", width=width):
+                    self._admit_prefill(i, buf, width, write_table)
+            except Exception as e:  # noqa: BLE001 — lane isolation boundary
+                # a crashed admission evicts ONLY its own lane and
+                # returns its blocks to the free list; the prefill
+                # wrote (at most) trash + this lane's private
+                # blocks, so co-residents' tokens are untouched
+                # (the PR 8 crash-eviction contract carried onto
+                # the paged pool)
+                with self._cond:
+                    st = self._slots[i]
+                    self._release_lane(i)
+                    self._cond.notify_all()
+                if st is not None and not st.future.done():
+                    st.future.set_exception(e)
+                self.stats.record_slot_crash()
+                try:
+                    deleted = self._arena["k"].is_deleted()
+                except Exception:  # noqa: BLE001 — probe only
+                    deleted = False
+                if deleted:
+                    # the DONATED admit died mid-execution and took
+                    # the arena with it: co-resident KV is gone, so
+                    # honest failure beats silently garbage tokens
+                    self._fail_active_lanes(e)
+                    return False
+                return True
+            self._admits += 1
+            self._admit_width_sum += width
+            with self._cond:
+                for digest, block in inserts:
+                    self._prefix.insert(digest, block)
+            return True
 
     def _tick_phase(self) -> bool:
         """One scheduling decision + device tick + host unpack (the tail
         of the worker iteration, factored out so serving/speculate.py can
         interpose its draft-verify round). Returns False only when the
         worker should exit (stopped and idle)."""
-        with self._cond:
+        with obs_trace.span("serve.tick.plan") as sp_plan, self._cond:
             self.stats.set_queue_depth(self._total_pending(), "decode")
             active = [i for i in range(self.lanes)
                       if self._slots[i] is not None]
             self.peak_active = max(self.peak_active, len(active))
             if not active:
+                sp_plan.discard()   # nothing to plan; the wait has a name
                 if not self._running:
                     return False
-                self._cond.wait()
+                with obs_trace.span("serve.idle"):
+                    self._cond.wait()
                 return True
             # adaptive k (ISSUE 16): a literal drop to 1 — never an
             # intermediate clamp — so only the k=1 and k=tick_k
@@ -1224,20 +1322,40 @@ class PagedDecoder:
         # one fixed-shape device tick for the whole pool (no lock
         # held): k scanned steps per dispatch, tokens [S, k]; the
         # serve.batch span joins the request spans the engine
-        # opened (PR 7 tracer)
+        # opened (PR 7 tracer). The prefills dispatched since the last
+        # tick run on the device ahead of this one: its wait absorbs them
+        admits, width_sum = self._admits, self._admit_width_sum
+        self._admits = self._admit_width_sum = 0
         try:
             with obs_trace.span("serve.batch", kind="decode.paged",
-                                lanes=len(active), tick_k=k):
-                self._arena, nxt, keys = self._tick_fn(k)(
-                    self._infer_params, self._arena,
-                    jnp.asarray(self._tok), jnp.asarray(self._pos),
-                    jnp.asarray(self._tables),
-                    jnp.asarray(self._keys),
-                    jnp.asarray(self._temps))
-                nxt = np.asarray(nxt)
+                                lanes=len(active), tick_k=k, admits=admits,
+                                admit_width_sum=width_sum) as sp_tick:
+                with obs_trace.span("serve.tick.stage"):
+                    self._arena, nxt, keys = self._tick_fn(k)(
+                        self._infer_params, self._arena,
+                        jnp.asarray(self._tok), jnp.asarray(self._pos),
+                        jnp.asarray(self._tables),
+                        jnp.asarray(self._keys),
+                        jnp.asarray(self._temps))
+                with obs_trace.span("serve.tick.wait"):
+                    nxt = np.asarray(nxt)
         except Exception as e:  # noqa: BLE001 — device boundary
             self._fail_active_lanes(e)
             return True
+        with obs_trace.span("serve.tick.emit", tick=sp_tick.span_id):
+            self._emit(active, k, nxt, keys)
+            # the tick's last device output dies HERE, inside the span:
+            # freeing a device array lets go of the GIL, and the
+            # streaming threads that the callbacks just woke take their
+            # turn (2 ms at 31 lanes) before the worker runs on
+            del keys
+        return True
+
+    def _emit(self, active: List[int], k: int, nxt: np.ndarray,
+              keys) -> None:
+        """The host's share of a tick after the readback: unpack the
+        [lanes, k] tokens, fire the streaming callbacks, resolve the
+        futures of finished lanes."""
         self._keys = np.array(keys)  # writable copy (admits write rows)
         self.dispatch_stats.decode_ticks += 1
         self.dispatch_stats.decode_tokens += len(active) * k
@@ -1278,4 +1396,3 @@ class PagedDecoder:
             if not st.future.done():
                 st.future.set_result(np.asarray(st.tokens, np.int32))
                 self.stats.record_latency(time.monotonic() - st.enqueued)
-        return True

@@ -93,9 +93,9 @@ class TestTransformerHbmPreflight:
 # host-side legs earlier PRs registered (control planes, schedulers, byte
 # contracts): each must be runnable by name and must NOT demand a chip
 HOST_SIDE_LEGS = ("autoscale", "serving_decode", "decode_amortize",
-                  "obs_overhead", "serving_fleet", "serving_resilience",
-                  "serving_mesh", "checkpoint_overhead", "input_pipeline",
-                  "elastic_dp", "online_loop", "lowprec", "retrieval")
+                  "serving_fleet", "serving_resilience", "serving_mesh",
+                  "checkpoint_overhead", "input_pipeline", "elastic_dp",
+                  "online_loop", "lowprec", "retrieval")
 
 
 class TestBenchCommandLine:

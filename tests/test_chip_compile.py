@@ -1,0 +1,186 @@
+"""Compile-time guards that cost no chip time (ISSUE 26).
+
+1. The flash-attention forward kernel at the shapes the benchmark's cells
+   run it at compiles for a DESCRIBED v5e (the TPU's compiler is installed
+   here; nothing is attached, nothing runs), and the compiled text holds the
+   Mosaic call and the kernel's own name: a device trace names the event
+   after that instruction, and the benchmark's readers key on it.
+2. On the CPU backend the lowered text of a tiny train step, decode tick and
+   admission program holds every ``jax.named_scope`` the device-time-by-scope
+   readers will key on.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU's library, every xdist worker imports
+every test file, and a fixture keeps the load to the worker that runs this
+file. All such tests live in THIS file for the same reason.
+"""
+
+from __future__ import annotations
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+TRAIN_SCOPES = ("embed", "block.attn", "block.mlp", "head_loss",
+                "grad_accum", "adam")
+TICK_SCOPES = ("tick.scatter", "tick.gather_kv", "tick.attend",
+               "tick.sample")
+ADMIT_SCOPES = ("admit.prefill", "admit.scatter", "block.attn",
+                "block.mlp")
+
+
+def _has_scope(text: str, name: str) -> bool:
+    """`name` as one element of an operation's name stack: plain
+    (`jit(step)/adam/mul`) or under a transform (`jvp(embed)/gather`,
+    `transpose(jvp(head_loss))/dot_general`)."""
+    return re.search(r'[/"(]' + re.escape(name) + r"[)/]", text) is not None
+
+
+# ---------------------------------------------------------------------------
+# 1. the kernel, for a described chip
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler here, no test
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# batch/accum x heads of the cells: 590m 1 x 12, 1.3b-stage8 1 x 16
+@pytest.mark.parametrize("rows", [12, 16])
+def test_flash_forward_compiles_for_v5e_under_its_name(one_chip, rows,
+                                                       no_compile_cache):
+    from deeplearning4j_tpu.ops import pallas_attention as pa
+
+    assert pa.flash_fits(2048, 128)
+    x = jax.ShapeDtypeStruct((rows, 2048, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    # the test session runs with 64-bit types on (the gradient checks);
+    # the chip does not, and Mosaic lowers no 64-bit index arithmetic
+    with jax.enable_x64(False):
+        text = jax.jit(
+            lambda q, k, v: pa._flash_raw(q, k, v, causal=True,
+                                          interpret=False)
+        ).lower(x, x, x).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    # the instruction is named after the kernel, and so is the device event
+    assert re.match(r"\s*(ROOT )?%flash_fwd(\.\d+)? = ", calls[0]), calls[0]
+    assert f"bf16[{rows},2048,128]" in calls[0]
+    assert "flash_fwd/pallas_call" in calls[0]
+
+
+# ---------------------------------------------------------------------------
+# 2. the scopes, in the lowered programs
+# ---------------------------------------------------------------------------
+
+
+def _tiny_cfg(**over):
+    from deeplearning4j_tpu.models.transformer import TransformerConfig
+
+    kw = dict(vocab_size=29, d_model=16, n_layers=2, n_heads=2, d_ff=32,
+              max_len=32, use_flash=False)
+    kw.update(over)
+    return TransformerConfig(**kw)
+
+
+def _shapes(cfg):
+    from deeplearning4j_tpu.models.transformer import (
+        init_opt_state,
+        init_params,
+    )
+
+    params = jax.eval_shape(lambda: init_params(cfg))
+    return params, jax.eval_shape(init_opt_state, params)
+
+
+def test_train_step_holds_the_scope_names():
+    from deeplearning4j_tpu.models.transformer import make_train_step
+
+    cfg = _tiny_cfg(accum_steps=2, remat="dots")
+    params, opt = _shapes(cfg)
+    tok = jax.ShapeDtypeStruct((4, 16), jnp.int32)
+    text = make_train_step(cfg).lower(params, opt, tok, tok).as_text(
+        debug_info=True)
+    missing = [s for s in TRAIN_SCOPES if not _has_scope(text, s)]
+    assert not missing, missing
+
+
+def _arena(cfg, n_blocks, bt):
+    hd = cfg.d_model // cfg.n_heads
+    leaf = jax.ShapeDtypeStruct(
+        (cfg.n_layers, n_blocks + 1, bt, cfg.n_heads, hd), cfg.compute_dtype)
+    return {"k": leaf, "v": leaf}
+
+
+def test_tick_and_admit_programs_hold_the_scope_names():
+    from deeplearning4j_tpu.serving import paged
+
+    cfg, bt, lanes = _tiny_cfg(), 8, 3
+    params, _ = _shapes(cfg)
+    arena = _arena(cfg, 6, bt)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    m = cfg.max_len // bt
+    tick = paged._paged_tick_for(cfg, bt).lower(
+        params, arena, i32(lanes), i32(lanes), i32(lanes, m),
+        jax.ShapeDtypeStruct((lanes, 2), jnp.uint32),
+        jax.ShapeDtypeStruct((lanes,), jnp.float32)).as_text(debug_info=True)
+    missing = [s for s in TICK_SCOPES if not _has_scope(tick, s)]
+    assert not missing, missing
+    admit = paged._paged_admit_for(cfg, 16, bt).lower(
+        params, arena, i32(1, 16), i32(m)).as_text(debug_info=True)
+    missing = [s for s in ADMIT_SCOPES if not _has_scope(admit, s)]
+    assert not missing, missing
+
+
+@pytest.mark.parametrize("which", ["flash_bwd", "flash_ext_bwd"])
+def test_flash_backward_rules_are_scoped(which):
+    """The backward is plain XLA: the scope is all that tells its fusions
+    from the rest of the step. Lowered through the interpreter, as the CPU
+    has no Mosaic."""
+    from deeplearning4j_tpu.ops import pallas_attention as pa
+
+    q = jax.ShapeDtypeStruct((2, 128, 64), jnp.float32)
+    if which == "flash_bwd":
+        loss = lambda q, k, v: pa._flash(q, k, v, True, True).sum()
+        fwd = "flash_fwd"
+    else:
+        loss = lambda q, k, v: pa.flash_attention_block(
+            q, k, v, offset=jnp.int32(0), interpret=True)[0].sum()
+        fwd = "flash_ext_fwd"
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q).as_text(
+        debug_info=True)
+    assert _has_scope(text, which)
+    assert _has_scope(text, fwd)
