@@ -1,10 +1,11 @@
 """Pallas TPU paged-decode attention kernel.
 
-The paged serving tick (serving/paged.py paged_decode_step) gathers every
-lane's KV blocks into a dense contiguous copy per generated token —
-``ck[tables].reshape(S, T, H, hd)`` materializes S * max_len * H * hd
-floats of HBM traffic each tick even though a lane typically occupies a
-handful of blocks. This kernel is the vLLM PagedAttention move (Kwon et
+The paged serving tick's XLA path (serving/paged.py chunked_attention)
+gathers every lane's KV blocks a chunk at a time, up to the longest live
+lane, into a copy it reads back once — S * chunk * H * hd elements of HBM
+traffic a pass, for dead lanes and short ones too, even though a lane
+typically occupies a handful of blocks. This kernel is the vLLM
+PagedAttention move (Kwon et
 al., 2023) fused with the flash-attention online softmax (Dao et al.,
 2022; same recipe as ops/pallas_attention.py): the grid walks each lane's
 BLOCK TABLE via scalar prefetch, Mosaic streams exactly the referenced

@@ -53,7 +53,8 @@ fallback): ``DL4J_TPU_SERVE_KV_DTYPE=bf16`` and ``DL4J_TPU_SERVE_SPEC``
 both raise at decoder build; ``n_heads % devices != 0`` raises; the
 pallas paged-attention kernel is never used under shard_map (its
 PALLAS_BENCH verdicts were measured dense), the sharded tick always
-gathers.
+takes the chunked gather loop (paged.chunked_attention, the single
+device's own function over the local heads).
 
 Prefill/decode disaggregation rides the PagedDecoder half of this PR:
 ``export_prefix``/``import_prefix`` (serving/paged.py) hand
@@ -68,7 +69,6 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -83,7 +83,7 @@ from deeplearning4j_tpu.ops import lowprec
 from deeplearning4j_tpu.parallel.mesh import MODEL_AXIS, device_mesh
 from deeplearning4j_tpu.parallel.tensor_parallel import local_head_columns
 from deeplearning4j_tpu.serving.decode import _sample_step
-from deeplearning4j_tpu.serving.paged import PagedDecoder
+from deeplearning4j_tpu.serving.paged import PagedDecoder, chunked_attention
 
 # the arena's k/v buffers shard on their HEAD axis (dim 3 of
 # [L, n_blocks+1, bt, H, hd]); everything else the tick touches is
@@ -121,11 +121,7 @@ def mesh_paged_decode_step(params, arena, tok, pos, tables,
     hd = cfg.d_model // cfg.n_heads
     hl = cfg.n_heads // n_devices
     bt = arena["k"].shape[2]
-    t_total = tables.shape[1] * bt                    # == cfg.max_len
     h = (params["embed"][tok] + params["pos"][pos])[:, None, :].astype(cdt)
-    scale = 1.0 / float(np.sqrt(hd))
-    t_idx = jnp.arange(t_total)[None, :]              # [1, T]
-    visible = t_idx <= pos[:, None]                   # [S, T]
     wb = jnp.take_along_axis(tables, (pos // bt)[:, None], axis=1)[:, 0]
     off = pos % bt
 
@@ -146,17 +142,12 @@ def mesh_paged_decode_step(params, arena, tok, pos, tables,
             n_devices=n_devices, axis=axis)).reshape(s, hl, hd)
         ck = ck.at[wb, off].set(k1.astype(ck.dtype))
         cv = cv.at[wb, off].set(v1.astype(cv.dtype))
-        # per-head attention over the LOCAL arena shard — the dense
-        # gather path verbatim, just over H/d heads (per-head math is
-        # device-independent: the einsums contract hd/T only and
-        # softmax runs per head)
-        kg = ck[tables].reshape(s, t_total, hl, hd)
-        vg = cv[tables].reshape(s, t_total, hl, hd)
-        sc = jnp.einsum("nhd,nthd->nht", q.astype(jnp.float32),
-                        kg.astype(jnp.float32)) * scale
-        sc = jnp.where(visible[:, None, :], sc, -jnp.inf)
-        p = jax.nn.softmax(sc, axis=-1)
-        att_l = jnp.einsum("nht,nthd->nhd", p, vg.astype(jnp.float32))
+        # per-head attention over the LOCAL arena shard — the single
+        # device's own function (paged.chunked_attention), just over H/d
+        # heads: per-head math is device-independent (the einsums
+        # contract hd/T only and the running softmax is per head), and
+        # pos is replicated, so every device loops to the same bound
+        att_l = chunked_attention(q, ck, cv, tables, pos)
         # reassemble the full [S, H, hd] head outputs by CONCATENATION
         # (axis-index order == head order) — not a psum: Megatron's
         # row-parallel Wo would reorder the contraction's float sum and

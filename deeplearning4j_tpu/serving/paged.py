@@ -24,12 +24,18 @@ Layout and invariants:
     harmless to read (the ``arange <= pos`` mask zeroes its softmax
     weight exactly, the same argument decode.py makes for garbage pad
     K/V).
-  * the tick gathers each lane's blocks ``arena[tables]`` back into the
-    contiguous ``[S, max_len, H, hd]`` view and then runs the identical
-    per-slot masked-attention math as decode_step_slots — per-lane
-    outputs are functions of the gathered VALUES, not the physical
-    block ids, which is why a request's tokens are byte-invariant to
-    allocation history and pool co-residents (tests/test_serving_paged).
+  * the tick reads each lane's blocks through its table a chunk of
+    whole blocks at a time (``arena[tables[:, chunk]]``, in the arena's
+    dtype) and folds each chunk into an online softmax, looping only as
+    far as the longest LIVE lane reaches (chunked_attention; the trip
+    count is data, so there is one program). No ``[S, max_len, H, hd]``
+    view exists. Per-lane outputs are functions of the gathered VALUES,
+    not the physical block ids, and a chunk wholly past a lane's
+    position is an exact no-op on its running softmax, which is why a
+    request's tokens are byte-invariant to allocation history and pool
+    co-residents (tests/test_serving_paged, tests/test_paged_chunked).
+    The masked-attention math is decode_step_slots' up to the order of
+    the float32 softmax sums.
   * prefix cache: full prompt blocks strictly BELOW a request's first
     write position are content-addressed (chained sha256 over the
     re-based token window) and refcounted; a hit points the new
@@ -102,18 +108,122 @@ def attention_path(cfg: TransformerConfig, block_tokens: int) -> str:
     """Which attention path the paged tick traces for this config:
     ``kernel`` = the pallas paged-decode kernel (ops/pallas_paged.py,
     behind DL4J_TPU_PALLAS_PAGED + the measured-win gate), ``gather`` =
-    the dense ``ck[tables]`` fallback. Resolved at trace time; the tick
-    cache keys on it, and the serving_decode bench stamps it."""
+    the chunked ``ck[tables[:, chunk]]`` loop of chunked_attention.
+    Resolved at trace time; the tick cache keys on it, and the
+    serving_decode bench stamps it."""
     hd = cfg.d_model // cfg.n_heads
     if jnp.dtype(lowprec.kv_dtype(cfg)) != jnp.dtype(cfg.compute_dtype):
         # a down-cast KV arena (DL4J_TPU_SERVE_KV_DTYPE=bf16 on an f32
-        # model) takes the gather path, which casts blocks to f32 for
-        # the attention math; the pallas kernel's bench verdicts were
-        # measured at the compute dtype
+        # model) takes the gather path, which reads the blocks as
+        # stored against the query's exact rows; the pallas kernel's
+        # bench verdicts were measured at the compute dtype
         return "gather"
     if pallas_paged.paged_kernel_enabled(cfg.n_heads, hd, block_tokens):
         return "kernel"
     return "gather"
+
+
+# table columns one pass of the tick's attention gathers: whole blocks,
+# ATTN_CHUNK_COLS * block_tokens tokens a pass. One constant, chosen on
+# the chip (PERF.md section 6, PR 27); chunk edges sit at fixed global
+# positions, which is what keeps a lane's bits free of its co-residents.
+ATTN_CHUNK_COLS = 8
+
+
+def _chunk_tokens(block_tokens: int, table_width: int) -> int:
+    """Tokens one pass covers (a table narrower than the chunk is one
+    pass): the program's and the host's count of it."""
+    return min(ATTN_CHUNK_COLS, table_width) * block_tokens
+
+
+def _exact_rows(x):
+    """``x`` [S, H, n] as three bfloat16 rows [S, H, 3, n] whose sum is
+    float32(x) exactly: the leading 8 bits of the mantissa, the next 8,
+    the last 8. A dot of these rows with a bfloat16 operand, accumulated
+    in float32, is the dot of the float32 ``x`` with it: every product
+    is exact and nothing of ``x`` is rounded away. It is also a matrix
+    product of three rows, which the TPU's compiler gives to the matrix
+    unit reading the other operand as stored; a one-row product it
+    rewrites as multiply-and-reduce over a float32 COPY of that operand
+    (the converts that were 38% of the tick, PERF.md section 6)."""
+    x = x.astype(jnp.float32)
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return jnp.stack([hi, mid, lo], axis=2)
+
+
+def chunked_attention(q, ck, cv, tables, pos):
+    """Masked single-query attention over the block arena, chunk by
+    chunk up to the longest lane: q [S, H, hd], ck/cv [B, bt, H, hd]
+    (block 0 = trash), tables [S, m] int32, pos [S] int32 (every entry
+    >= 0) -> att [S, H, hd] float32.
+
+    Each pass gathers ``c`` table columns of K and V in the arena's
+    dtype, takes the scores against the query's exact rows with float32
+    accumulation, masks ``t <= pos`` and folds the chunk into a running
+    (max, denominator, accumulator) in float32: the online softmax of
+    ops/pallas_paged.py. The probabilities stay float32 (_exact_rows
+    again). K and V are read as stored (a float32 arena at full
+    precision); nothing of ``max_len`` width exists in any dtype. The
+    trip count is data (``max(pos) // chunk + 1``, a ``while``), so one
+    program serves every live length.
+
+    A lane's output does not depend on the trip count: a chunk wholly
+    past ``pos`` leaves the running max where it was, so ``corr`` is
+    exactly 1 and ``p`` exactly 0, and the triple keeps its bits.
+    ``-inf`` never meets ``-inf`` in an ``exp``: chunk 0 holds position
+    0, which every lane sees, so the running max is finite from the
+    first pass on."""
+    s, n_heads, hd = q.shape
+    bt = ck.shape[1]
+    chunk = _chunk_tokens(bt, tables.shape[1])
+    c = chunk // bt
+    # a table whose width c does not divide reads trash past its end,
+    # at positions no lane can see
+    tables = jnp.pad(tables, ((0, 0), (0, -tables.shape[1] % c)))
+    scale = 1.0 / float(np.sqrt(hd))
+    q_rows = _exact_rows(q).astype(ck.dtype)
+    t_in = jnp.arange(chunk)[None, :]                 # [1, chunk]
+
+    def rows_dot(spec, rows, gathered):
+        # HIGHEST touches float32 operands only (a float32 arena)
+        return jnp.einsum(spec, rows, gathered,
+                          precision=lax.Precision.HIGHEST,
+                          preferred_element_type=jnp.float32).sum(axis=2)
+
+    def fold(j, carry):
+        m, l, acc = carry
+        cols = lax.dynamic_slice_in_dim(tables, j * c, c, axis=1)
+        with jax.named_scope("tick.gather_kv"):
+            kg = ck[cols].reshape(s, chunk, n_heads, hd)
+            vg = cv[cols].reshape(s, chunk, n_heads, hd)
+        with jax.named_scope("tick.attend"):
+            sc = rows_dot("nhrd,nthd->nhrt", q_rows, kg) * scale
+            visible = j * chunk + t_in <= pos[:, None]    # [S, chunk]
+            sc = jnp.where(visible[:, None, :], sc, -jnp.inf)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1))  # [S, H], finite
+            p = jnp.exp(sc - m_new[..., None])
+            corr = jnp.exp(m - m_new)
+            l = l * corr + jnp.sum(p, axis=-1)
+            acc = acc * corr[..., None] + rows_dot(
+                "nhrt,nthd->nhrd", _exact_rows(p).astype(cv.dtype), vg)
+        return m_new, l, acc
+
+    init = (jnp.full((s, n_heads), -jnp.inf, jnp.float32),
+            jnp.zeros((s, n_heads), jnp.float32),
+            jnp.zeros((s, n_heads, hd), jnp.float32))
+    _, l, acc = lax.fori_loop(0, jnp.max(pos) // chunk + 1, fold, init)
+    return acc / l[..., None]
+
+
+def kv_read_tokens(max_pos: int, block_tokens: int, table_width: int) -> int:
+    """Tokens a lane's attention loops over when the longest lane of its
+    tick stands at ``max_pos``: chunked_attention's trip count times its
+    chunk, as the host counts it for the ``serve.batch`` span."""
+    chunk = _chunk_tokens(block_tokens, table_width)
+    return (max_pos // chunk + 1) * chunk
 
 
 def paged_decode_step(params, arena, tok, pos, tables,
@@ -123,18 +233,19 @@ def paged_decode_step(params, arena, tok, pos, tables,
     [S, V]).
 
     The paged variant of serving/decode.decode_step_slots: the per-slot
-    cache stripe becomes a gather of the lane's blocks (``ck[tables]``
-    reshaped back to the contiguous [S, T, H, hd] view) and the one-hot
-    cache write becomes a scatter into (block, offset) =
-    (tables[s, pos//bt], pos % bt). Active lanes write distinct blocks
-    by allocation invariant; inactive lanes all scatter into trash
-    block 0, whose content is never visible under the causal mask.
+    cache stripe becomes the lane's blocks, read through its table a
+    chunk at a time and only as far as the longest lane reaches
+    (chunked_attention), and the one-hot cache write becomes a scatter
+    into (block, offset) = (tables[s, pos//bt], pos % bt). Active lanes
+    write distinct blocks by allocation invariant; inactive lanes all
+    scatter into trash block 0, whose content is never visible under
+    the causal mask.
 
     ``attention`` picks the per-layer attention body ('kernel' streams
-    blocks through the pallas online-softmax kernel and never
-    materializes the gathered window; 'gather' is the dense fallback;
-    None resolves via attention_path at trace time). Both honor the same
-    ``arange <= pos`` visibility mask, so outputs agree to f32 rounding
+    blocks through the pallas online-softmax kernel; 'gather' is the
+    chunked XLA loop; None resolves via attention_path at trace time).
+    Neither materializes the gathered window, and both honor the same
+    ``t <= pos`` visibility mask, so outputs agree to f32 rounding
     (tests/test_pallas_paged.py pins 1e-6)."""
     cdt = cfg.compute_dtype
     s = tok.shape[0]
@@ -142,11 +253,7 @@ def paged_decode_step(params, arena, tok, pos, tables,
     bt = arena["k"].shape[2]
     if attention is None:
         attention = attention_path(cfg, bt)
-    t_total = tables.shape[1] * bt                    # == cfg.max_len
     h = (params["embed"][tok] + params["pos"][pos])[:, None, :].astype(cdt)
-    scale = 1.0 / float(np.sqrt(hd))
-    t_idx = jnp.arange(t_total)[None, :]              # [1, T]
-    visible = t_idx <= pos[:, None]                   # [S, T]
     wb = jnp.take_along_axis(tables, (pos // bt)[:, None], axis=1)[:, 0]
     off = pos % bt
 
@@ -162,20 +269,10 @@ def paged_decode_step(params, arena, tok, pos, tables,
             cv = cv.at[wb, off].set(v1.astype(cv.dtype))
         if attention == "kernel":
             with jax.named_scope("tick.attend"):
-                att = pallas_paged.paged_attention(
-                    q, ck, cv, tables, pos).reshape(s, 1, cfg.d_model)
+                att = pallas_paged.paged_attention(q, ck, cv, tables, pos)
         else:
-            with jax.named_scope("tick.gather_kv"):
-                kg = ck[tables].reshape(s, t_total, cfg.n_heads, hd)
-                vg = cv[tables].reshape(s, t_total, cfg.n_heads, hd)
-            with jax.named_scope("tick.attend"):
-                sc = jnp.einsum("nhd,nthd->nht", q.astype(jnp.float32),
-                                kg.astype(jnp.float32)) * scale
-                sc = jnp.where(visible[:, None, :], sc, -jnp.inf)
-                p = jax.nn.softmax(sc, axis=-1)
-                att = jnp.einsum(
-                    "nht,nthd->nhd", p,
-                    vg.astype(jnp.float32)).reshape(s, 1, cfg.d_model)
+            att = chunked_attention(q, ck, cv, tables, pos)
+        att = att.reshape(s, 1, cfg.d_model)
         h = h + att.astype(cdt) @ c(bp["Wo"])
         x = _ln(h, c(bp["ln2_g"]), c(bp["ln2_b"]))
         h = h + jax.nn.gelu(x @ c(bp["W1"]) + c(bp["b1"])) @ c(bp["W2"]) \
@@ -821,8 +918,27 @@ class PagedDecoder:
         for b in lane.blocks:
             self._blocks.decref(b)
         self._tables[i, :] = 0
+        # a dead lane attends position 0 of the trash block, as a fresh
+        # one does: left at the finished request's last position it
+        # would hold up the tick's bound (max over lanes of pos) for as
+        # long as the lane stays empty
+        self._pos[i] = 0
+        self._tok[i] = 0
         self._slots[i] = None
         self.stats.set_kv_blocks(self._blocks.in_use, self.n_blocks)
+
+    def _kv_counts(self, active: List[int], k: int) -> Dict[str, int]:
+        """What the next k-step tick's attention reads, for its span:
+        ``kv_live`` the positions the active lanes can see, ``kv_read``
+        the positions the program loops over for every lane, dead ones
+        too (the bound follows the longest lane, step by step)."""
+        pos = self._pos[active].astype(np.int64)
+        top = int(self._pos.max())
+        return {
+            "kv_live": int(sum((pos + 1 + j).sum() for j in range(k))),
+            "kv_read": self.lanes * sum(
+                kv_read_tokens(top + j, self.block_tokens, self.table_width)
+                for j in range(k))}
 
     def _youngest_active(self) -> Optional[int]:
         best, best_seq = None, -1
@@ -1326,10 +1442,12 @@ class PagedDecoder:
         # tick run on the device ahead of this one: its wait absorbs them
         admits, width_sum = self._admits, self._admit_width_sum
         self._admits = self._admit_width_sum = 0
+        kv = self._kv_counts(active, k) if obs_trace.obs_enabled() else {}
         try:
             with obs_trace.span("serve.batch", kind="decode.paged",
                                 lanes=len(active), tick_k=k, admits=admits,
-                                admit_width_sum=width_sum) as sp_tick:
+                                admit_width_sum=width_sum,
+                                **kv) as sp_tick:
                 with obs_trace.span("serve.tick.stage"):
                     self._arena, nxt, keys = self._tick_fn(k)(
                         self._infer_params, self._arena,

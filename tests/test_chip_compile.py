@@ -22,6 +22,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 TRAIN_SCOPES = ("embed", "block.attn", "block.mlp", "head_loss",
@@ -100,6 +101,49 @@ def test_flash_forward_compiles_for_v5e_under_its_name(one_chip, rows,
     assert re.match(r"\s*(ROOT )?%flash_fwd(\.\d+)? = ", calls[0]), calls[0]
     assert f"bf16[{rows},2048,128]" in calls[0]
     assert "flash_fwd/pallas_call" in calls[0]
+
+
+# heads of the cells' widths: 590m 12 of 128, 1.3b 16 of 128
+@pytest.mark.parametrize("heads", [12, 16])
+def test_tick_attention_reads_the_arena_as_stored_on_v5e(one_chip, heads,
+                                                         no_compile_cache):
+    """The serve cell's tick (40 lanes, 1,280 blocks of 16, bf16 arena), two
+    layers of it: the chip's compiler gives both attention products to the
+    matrix unit and makes no float32 copy of a gathered chunk. A one-row
+    product it rewrites as multiply-and-reduce over such a copy, which was
+    38% of the tick (PERF.md section 6, PR 27): paged._exact_rows is what
+    keeps it from that, and this is what watches it."""
+    from deeplearning4j_tpu.models.transformer import (
+        TransformerConfig,
+        init_params,
+    )
+    from deeplearning4j_tpu.serving import paged
+
+    cfg = TransformerConfig(vocab_size=1024, d_model=128 * heads, n_layers=2,
+                            n_heads=heads, d_ff=512 * heads, max_len=2048,
+                            dtype_policy="performance")
+    lanes, n_blocks, bt = 40, 1280, 16
+    on = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(on, jax.eval_shape(lambda: init_params(cfg)))
+    arena = jax.tree.map(on, _arena(cfg, n_blocks, bt))
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    with jax.enable_x64(False):
+        text = paged._paged_tick_for(cfg, bt).lower(
+            params, arena, arg((lanes,), jnp.int32), arg((lanes,), jnp.int32),
+            arg((lanes, cfg.max_len // bt), jnp.int32),
+            arg((lanes, 2), jnp.uint32), arg((lanes,), jnp.float32)
+        ).compile().as_text()
+    paged._PAGED_TICK_CACHE.pop((cfg, bt, "gather", 1), None)
+    cols = paged.ATTN_CHUNK_COLS
+    chunk = lanes * cols * bt * heads * 128
+    assert f"bf16[{lanes * cols},{bt},{heads},128]" in text   # the gathers
+    sizes = {int(np.prod([int(d) for d in dims.split(",")]))
+             for dims in re.findall(r"f32\[([\d,]+)\]", text)}
+    assert chunk not in sizes and chunk // 2 not in sizes
+    products = [ln for ln in text.splitlines()
+                if " convolution(" in ln and "tick.attend" in ln]
+    assert len(products) == 2, products
 
 
 # ---------------------------------------------------------------------------

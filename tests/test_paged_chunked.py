@@ -1,0 +1,293 @@
+"""The paged tick attends what is live (ISSUE 27): K and V read in the
+arena's dtype, chunk by chunk up to the longest live lane, one program.
+
+  * paged.chunked_attention against a dense masked-softmax oracle in
+    float32: lanes at the chunk's edges and at max_len - 1, trash block
+    filled with large values, f32 / bf16 / down-cast arenas;
+  * a lane's logits are bit-equal alone and beside a lane four chunks
+    longer (the trip count is the longest lane's, and a chunk wholly
+    past a lane's position is an exact no-op);
+  * the lowered tick holds no float32 array of the gathered window's
+    size and no gather wider than one chunk;
+  * the bound follows LIVE lanes: the release resets the lane's
+    position, so the next tick's ``kv_read`` falls;
+  * one tick program whatever the live lengths (no retrace);
+  * a k = 2 scanned tick equals two k = 1 ticks across a chunk edge.
+
+Reference anchor: none in the reference (one record per route callback,
+dl4j-streaming/.../routes/DL4jServeRouteBuilder.java); provenance is the
+online softmax of ops/pallas_paged.py and the vLLM block table.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu.models.transformer import (
+    TransformerConfig,
+    TransformerLM,
+    init_params,
+)
+from deeplearning4j_tpu.obs import trace as obs_trace
+from deeplearning4j_tpu.serving import paged
+
+BT = 4                                   # block_tokens of every case here
+CHUNK = paged.ATTN_CHUNK_COLS * BT       # tokens a pass
+MAX_LEN = 6 * CHUNK                      # six chunks: room for "four longer"
+
+
+def _cfg(**over):
+    kw = dict(vocab_size=29, d_model=16, n_layers=2, n_heads=2, d_ff=32,
+              max_len=MAX_LEN, use_flash=False)
+    kw.update(over)
+    return TransformerConfig(**kw)
+
+
+def _dense_tables(lanes, m):
+    """Lane i owns blocks 1 + i*m .. (i+1)*m: every position is backed, so
+    the oracle can attend any ``pos``."""
+    return (1 + np.arange(lanes * m, dtype=np.int32)).reshape(lanes, m)
+
+
+def _oracle(q, ck, cv, tables, pos):
+    """Dense masked softmax attention in float32 over the gathered window,
+    with numpy: the parent's gather path, one lane at a time."""
+    q, ck, cv = (np.asarray(a, np.float32) for a in (q, ck, cv))
+    s, n_heads, hd = q.shape
+    out = np.zeros((s, n_heads, hd), np.float32)
+    for i in range(s):
+        k = ck[tables[i]].reshape(-1, n_heads, hd)[:pos[i] + 1]
+        v = cv[tables[i]].reshape(-1, n_heads, hd)[:pos[i] + 1]
+        sc = np.einsum("hd,thd->ht", q[i], k) / np.sqrt(hd)
+        p = np.exp(sc - sc.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        out[i] = np.einsum("ht,thd->hd", p, v)
+    return out
+
+
+# ---------------------------------------------------------------------------
+# (a) against the dense oracle
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("q_dtype,kv_dtype", [
+    (jnp.float32, jnp.float32),      # f32 model, f32 arena
+    (jnp.bfloat16, jnp.bfloat16),    # the benchmark's: bf16 model and arena
+    (jnp.float32, jnp.bfloat16),     # down-cast arena under an f32 model
+], ids=["f32", "bf16", "downcast"])
+@pytest.mark.parametrize("pos", [
+    [0, 0, 0],
+    [CHUNK - 1, CHUNK, MAX_LEN - 1],
+    [0, CHUNK - 1, 3 * CHUNK + 5],
+    [MAX_LEN - 1, 1, CHUNK],
+], ids=["zero", "edges", "mixed", "full"])
+def test_chunked_attention_equals_dense_oracle(q_dtype, kv_dtype, pos):
+    lanes, n_heads, hd = 3, 2, 8
+    m = MAX_LEN // BT
+    rng = np.random.default_rng(11)
+    tables = _dense_tables(lanes, m)
+    shape = (lanes * m + 1, BT, n_heads, hd)
+    ck = rng.normal(size=shape).astype(np.float32)
+    cv = rng.normal(size=shape).astype(np.float32)
+    ck[0] = cv[0] = 1e4           # trash: visible to nobody, whatever it holds
+    # a lane's table ends where its blocks end; the tail is trash
+    pos = np.asarray(pos, np.int32)
+    for i in range(lanes):
+        tables[i, pos[i] // BT + 1:] = 0
+    q = jnp.asarray(rng.normal(size=(lanes, n_heads, hd)), q_dtype)
+    ck, cv = jnp.asarray(ck, kv_dtype), jnp.asarray(cv, kv_dtype)
+    got = jax.jit(paged.chunked_attention)(
+        q, ck, cv, jnp.asarray(tables), jnp.asarray(pos))
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got),
+                               _oracle(q, ck, cv, tables, pos),
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_exact_rows_sum_to_the_float32_value():
+    """The three bfloat16 rows hold all 24 bits: probabilities are not
+    rounded on their way into the value product."""
+    rng = np.random.default_rng(3)
+    x = np.concatenate([rng.random(4096), np.exp(-30 * rng.random(4096)),
+                        [0.0, 1.0, 2.0 ** -100]]).astype(np.float32)
+    rows = paged._exact_rows(jnp.asarray(x).reshape(1, 1, -1))
+    assert rows.dtype == jnp.bfloat16 and rows.shape[2] == 3
+    back = np.asarray(rows, np.float32).sum(axis=2).reshape(-1)
+    assert np.array_equal(back, x)
+
+
+# ---------------------------------------------------------------------------
+# (b) a lane's bits do not depend on its co-residents
+# ---------------------------------------------------------------------------
+
+
+def _tick_inputs(cfg, lanes, pos, seed=0):
+    params = init_params(cfg)
+    m = cfg.max_len // BT
+    hd = cfg.d_model // cfg.n_heads
+    rng = np.random.default_rng(seed)
+    shape = (cfg.n_layers, lanes * m + 1, BT, cfg.n_heads, hd)
+    arena = {"k": jnp.asarray(rng.normal(size=shape), cfg.compute_dtype),
+             "v": jnp.asarray(rng.normal(size=shape), cfg.compute_dtype)}
+    tok = jnp.asarray(rng.integers(0, cfg.vocab_size, lanes), jnp.int32)
+    return params, arena, tok, jnp.asarray(pos, jnp.int32), \
+        jnp.asarray(_dense_tables(lanes, m))
+
+
+def test_lane_logits_bit_equal_alone_and_beside_a_longer_lane():
+    cfg = _cfg()
+    short, long_ = CHUNK // 2, 4 * CHUNK + CHUNK // 2
+    params, arena, tok, _, tables = _tick_inputs(cfg, 2, [0, 0])
+    step = jax.jit(lambda a, p: paged.paged_decode_step(
+        params, a, tok, p, tables, cfg, attention="gather")[1])
+    # lane 1 dead at position 0: the loop runs one chunk; then live four
+    # chunks further on: it runs five
+    alone = np.asarray(step(arena, jnp.asarray([short, 0], jnp.int32)))
+    beside = np.asarray(step(arena, jnp.asarray([short, long_], jnp.int32)))
+    assert np.array_equal(alone[0], beside[0])
+    assert not np.array_equal(alone[1], beside[1])
+
+
+# ---------------------------------------------------------------------------
+# (c) nothing of the window's width in the lowered tick
+# ---------------------------------------------------------------------------
+
+
+def _sizes(dims):
+    return int(np.prod([int(d) for d in dims.split("x") if d]))
+
+
+def test_lowered_tick_holds_no_window_wide_array():
+    # the benchmark's policy: bf16 compute, bf16 arena, f32 masters
+    cfg = _cfg(dtype_policy="performance")
+    lanes = 3
+    params, arena, tok, pos, tables = _tick_inputs(cfg, lanes, [0] * lanes)
+    assert arena["k"].dtype == jnp.bfloat16
+    tick = paged._paged_tick_for(cfg, BT)
+    keys = jnp.zeros((lanes, 2), jnp.uint32)
+    temps = jnp.zeros((lanes,), jnp.float32)
+    text = tick.lower(params, arena, tok, pos, tables, keys, temps).as_text()
+    hd = cfg.d_model // cfg.n_heads
+    window = lanes * cfg.max_len * cfg.n_heads * hd
+    one_chunk = lanes * CHUNK * cfg.n_heads * hd
+    f32 = [_sizes(dims) for dims in
+           re.findall(r"tensor<((?:\d+x)+)f32>", text)]
+    # no float32 array of the gathered window's size (the parent's upcast),
+    # and none beyond one chunk but the largest master weight
+    assert max(f32) < window
+    assert max(f32) <= max(one_chunk, max(
+        leaf.size for leaf in jax.tree.leaves(params)))
+    # what the gathers of K and V return, in any dtype, is one chunk wide
+    gathered = [_sizes(dims) for dims in re.findall(
+        r"stablehlo\.gather.*-> tensor<((?:\d+x)+)\w+>", text)]
+    assert one_chunk in gathered
+    assert max(gathered) == one_chunk < window
+
+
+# ---------------------------------------------------------------------------
+# (d) the bound follows live lanes
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def tracing():
+    obs_trace.set_enabled(True)
+    obs_trace.tracer().clear()
+    yield obs_trace.tracer()
+    obs_trace.set_enabled(None)
+
+
+def _paged_ticks(tracer):
+    return [s for s in tracer.spans("serve.batch")
+            if s["attrs"].get("kind") == "decode.paged"]
+
+
+def test_kv_read_falls_when_the_longest_lane_finishes(tracing):
+    lm = TransformerLM(_cfg())
+    dec = paged.PagedDecoder(lm, block_tokens=BT, lanes=2, n_blocks=80)
+    try:
+        rng = np.random.default_rng(0)
+        long_p = rng.integers(0, 29, 3 * CHUNK + 2).astype(np.int32)
+        short_p = rng.integers(0, 29, 3).astype(np.int32)
+        f_long = dec.submit(long_p, 2, temperature=0.0)
+        f_short = dec.submit(short_p, 12, temperature=0.0)
+        f_long.result(timeout=120)
+        f_short.result(timeout=120)
+    finally:
+        dec.stop()
+    ticks = _paged_ticks(tracing)
+    reads = [s["attrs"]["kv_read"] for s in ticks]
+    lives = [s["attrs"]["kv_live"] for s in ticks]
+    # beside the long lane both lanes loop four chunks; once it is released
+    # (its position back at 0) the short lane's one chunk is all that is read
+    assert reads[0] == 2 * 4 * CHUNK
+    assert reads[-1] == 2 * CHUNK
+    assert all(0 < live <= read for live, read in zip(lives, reads))
+    # kv_live counts active lanes alone: the last ticks hold the short one
+    assert lives[-1] == short_p.size + 12 - 1
+    assert int(dec._pos.max()) == 0 and int(dec._tok.max()) == 0
+
+
+def test_kv_read_tokens_is_the_programs_trip_count():
+    m = MAX_LEN // BT
+    for top, chunks in [(0, 1), (CHUNK - 1, 1), (CHUNK, 2),
+                        (MAX_LEN - 1, MAX_LEN // CHUNK)]:
+        assert paged.kv_read_tokens(top, BT, m) == chunks * CHUNK
+    # a table narrower than the chunk is one pass of its own width
+    assert paged.kv_read_tokens(5, BT, 2) == 2 * BT
+
+
+# ---------------------------------------------------------------------------
+# (e) one program whatever the live lengths
+# ---------------------------------------------------------------------------
+
+
+def test_one_tick_program_for_every_live_length():
+    cfg = _cfg(vocab_size=31)          # a config no other test has compiled
+    bt, blocks = BT, [1, 5, 40]
+    assert 40 * bt <= cfg.max_len
+    lanes = 2
+    params, arena, tok, _, tables = _tick_inputs(cfg, lanes, [0, 0])
+    before = dict(paged._PAGED_TICK_CACHE)
+    tick = paged._paged_tick_for(cfg, bt)
+    keys = jnp.zeros((lanes, 2), jnp.uint32)
+    temps = jnp.zeros((lanes,), jnp.float32)
+    for nb in blocks:
+        pos = jnp.asarray([nb * bt - 1, 0], jnp.int32)
+        arena, nxt, keys = tick(params, arena, tok, pos, tables, keys, temps)
+        np.asarray(nxt)
+    added = [k for k in paged._PAGED_TICK_CACHE if k not in before]
+    assert added == [(cfg, bt, "gather", 1)]
+    assert paged._paged_tick_for(cfg, bt) is tick
+    assert tick._cache_size() == 1
+
+
+# ---------------------------------------------------------------------------
+# (f) k = 2 across a chunk edge
+# ---------------------------------------------------------------------------
+
+
+def test_k2_tick_equals_two_k1_ticks_across_a_chunk_edge():
+    cfg = _cfg()
+    lanes = 2
+    # lane 0 steps from the last position of chunk 0 into chunk 1: the
+    # scanned tick's second step loops one chunk further than its first
+    start = [CHUNK - 1, 3]
+    params, arena, tok, pos, tables = _tick_inputs(cfg, lanes, start)
+    keys = jnp.asarray(np.arange(2 * lanes, dtype=np.uint32).reshape(lanes, 2))
+    temps = jnp.asarray([0.0, 0.9], jnp.float32)
+    copy = lambda a: jax.tree.map(jnp.copy, a)
+    one, two = paged._paged_tick_for(cfg, BT, 1), \
+        paged._paged_tick_for(cfg, BT, 2)
+    a1, t1, k1 = one(params, copy(arena), tok, pos, tables, keys, temps)
+    a1, t2, k1 = one(params, a1, t1[:, 0], pos + 1, tables, k1, temps)
+    a2, toks, k2 = two(params, copy(arena), tok, pos, tables, keys, temps)
+    assert np.array_equal(np.asarray(toks),
+                          np.concatenate([np.asarray(t1), np.asarray(t2)], 1))
+    assert np.array_equal(np.asarray(k1), np.asarray(k2))
+    for leaf in ("k", "v"):
+        assert np.array_equal(np.asarray(a1[leaf], np.float32),
+                              np.asarray(a2[leaf], np.float32))
