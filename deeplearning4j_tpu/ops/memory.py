@@ -335,41 +335,98 @@ def auto_fit_transformer(cfg, *, batches=(32, 16, 8, 4),
 # ---------------------------------------------------------------------------
 
 
+@dataclasses.dataclass(frozen=True)
+class StateLeaf:
+    """One kind of per-lane recurrent state a model keeps beside its KV
+    blocks: ``layers`` buffers of ``[lanes, *shape]`` in ``dtype`` (one
+    buffer a layer, so that a tick updates each in place)."""
+    name: str
+    layers: int
+    shape: Tuple[int, ...]
+    dtype: str
+
+    @property
+    def lane_bytes(self) -> int:
+        return self.layers * int(np.prod(self.shape)) \
+            * np.dtype(self.dtype).itemsize
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheNeeds:
+    """What a served model holds per request on the device, as the paged
+    decoder asks it: keys and values for ``kv_layers`` layers of
+    ``kv_heads`` heads of ``head_dim`` (paged, priced a block), and
+    ``state`` leaves indexed by lane (priced a lane). The GPT-2-shaped
+    TransformerLM is the instance with every layer a KV layer, as many
+    KV heads as query heads and no state. ``kv_per_layer`` asks for K
+    and V as one buffer a layer (a tick whose layers are unrolled
+    scatters into and reads each in place), a token's heads side by
+    side in one row, ``[blocks+1, block_tokens, kv_heads * head_dim]``
+    (a last dimension of one head of 64 the chip stores in another
+    order than the scatter and the gather want, and re-lays the whole
+    arena twice a tick), instead of one ``[kv_layers, blocks+1,
+    block_tokens, kv_heads, head_dim]`` stacked on a leading layer axis
+    (what a tick that scans over its layers wants)."""
+    kv_layers: int
+    kv_heads: int
+    head_dim: int
+    state: Tuple[StateLeaf, ...] = ()
+    kv_per_layer: bool = False
+
+    @property
+    def state_lane_bytes(self) -> int:
+        return sum(leaf.lane_bytes for leaf in self.state)
+
+
+def cache_needs(cfg) -> CacheNeeds:
+    """The model's own statement (``cfg.cache_needs()``) where its
+    config makes one; else the dense transformer's: K and V in every
+    layer for every head."""
+    own = getattr(cfg, "cache_needs", None)
+    if own is not None:
+        return own()
+    return CacheNeeds(cfg.n_layers, cfg.n_heads, cfg.d_model // cfg.n_heads)
+
+
 def kv_block_bytes(cfg, block_tokens: int, dtype=None,
                    devices: int = 1) -> int:
-    """PER-DEVICE bytes of ONE paged KV block across all layers: K and
-    V, [n_layers, block_tokens, n_heads/devices, head_dim] each, in the
+    """PER-DEVICE bytes of ONE paged KV block across the layers that
+    hold keys and values (:func:`cache_needs`): K and V,
+    [kv_layers, block_tokens, kv_heads/devices, head_dim] each, in the
     arena dtype (serving/paged.py's layout). ``dtype=None`` resolves
     through ops/lowprec.kv_dtype — the model's compute dtype unless
     ``DL4J_TPU_SERVE_KV_DTYPE`` overrides it (bf16 halves KV bytes, so
     the same HBM budget admits ~2x tokens). ``devices`` is the serving
     mesh width (serving/mesh.py head-shards the arena, so each device
-    holds only its n_heads/devices slice of every block); closed-form
+    holds only its heads/devices slice of every block); closed-form
     arithmetic over shapes, no device touch."""
     from deeplearning4j_tpu.ops import lowprec
 
     if dtype is None:
         dtype = lowprec.kv_dtype(cfg)
     devices = max(1, int(devices))
-    hd = cfg.d_model // cfg.n_heads
-    heads_local = -(-cfg.n_heads // devices)  # ceil: honest off-grid
+    needs = cache_needs(cfg)
+    heads_local = -(-needs.kv_heads // devices)  # ceil: honest off-grid
     itemsize = np.dtype(dtype).itemsize
-    return 2 * cfg.n_layers * int(block_tokens) * heads_local * hd \
-        * itemsize
+    return 2 * needs.kv_layers * int(block_tokens) * heads_local \
+        * needs.head_dim * itemsize
 
 
 def kv_arena_blocks(cfg, block_tokens: int, *, params=None,
                     hbm_gb: Optional[float] = None,
                     kv_fraction: float = 0.5,
                     max_blocks: int = 4096, dtype=None,
-                    devices: int = 1) -> int:
+                    devices: int = 1, lanes: int = 64) -> int:
     """How many KV blocks the arena can afford under ``DL4J_TPU_HBM_GB``
     (interpreted PER DEVICE when ``devices`` > 1).
 
     Budget = HBM minus twice the parameter bytes (weights resident plus
     one transient copy for dispatch headroom; the serving mesh
     REPLICATES params — projections are column-sliced at trace time —
-    so param bytes are NOT divided by ``devices``), times
+    so param bytes are NOT divided by ``devices``), minus the per-lane
+    state pool of a model that keeps one (``lanes`` x
+    ``cache_needs(cfg).state_lane_bytes``; nothing for a model of KV
+    layers only), times
     ``kv_fraction`` (the rest stays free for prefill temporaries and
     the serving batcher's bucket programs), divided by
     :func:`kv_block_bytes` at that device count — head-sharding drops
@@ -383,6 +440,7 @@ def kv_arena_blocks(cfg, block_tokens: int, *, params=None,
     budget = (hbm_gb if hbm_gb is not None else hbm_budget_gb()) * 2.0**30
     if params is not None:
         budget -= 2.0 * _tree_bytes(params)
+    budget -= int(lanes) * cache_needs(cfg).state_lane_bytes
     per_block = kv_block_bytes(cfg, block_tokens, dtype, devices)
     blocks = int(max(0.0, budget) * float(kv_fraction) / per_block)
     floor = cfg.max_len // int(block_tokens) + 1

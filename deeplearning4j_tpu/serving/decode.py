@@ -54,6 +54,7 @@ from deeplearning4j_tpu.models.transformer import (
 from deeplearning4j_tpu.obs.registry import register_net
 from deeplearning4j_tpu.ops import dispatch
 from deeplearning4j_tpu.ops import env as envknob
+from deeplearning4j_tpu.ops import memory as opsmem
 from deeplearning4j_tpu.serving.batcher import RequestTimeoutError
 from deeplearning4j_tpu.serving.resilience import WorkerDeadError
 from deeplearning4j_tpu.serving.telemetry import ServingStats
@@ -234,6 +235,11 @@ class ContinuousDecoder:
         if lm.mesh is not None:
             raise ValueError("continuous decode needs a single-device LM "
                              "(mesh-sharded models generate via ring/GSPMD)")
+        if opsmem.cache_needs(cfg).state:
+            raise ValueError(
+                "the fixed-slot pool (DL4J_TPU_SERVE_KV_BLOCK=0) holds keys "
+                "and values only: not implemented for models with "
+                "recurrent layers")
         if cfg.moe_experts:
             raise ValueError("continuous decode does not support MoE "
                              "(capacity routing is batch-dependent)")

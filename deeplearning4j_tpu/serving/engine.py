@@ -1265,11 +1265,23 @@ class ServingEngine:
                                 "reason": self._no_decoder[rec.key]}
         return out
 
+    def state_pools(self) -> Dict[str, Any]:
+        """Per live paged decoder of a model with recurrent layers, its
+        per-lane state pool as the last tick left it
+        (``PagedDecoder.state_pool``): the device buffers, for a reader
+        that looks while no request is in flight."""
+        with self._engine_lock:
+            decoders = dict(self._decoders)
+        pools = {key: d.state_pool() for key, d in decoders.items()
+                 if hasattr(d, "state_pool")}
+        return {key: pool for key, pool in pools.items() if pool}
+
     def hbm_report(self) -> Dict[str, Any]:
         """Per-replica HBM utilization (ISSUE 20 satellite): the
         AOT-priced resident bytes — every non-broken record's buffer
         pytrees (ops/memory.model_resident_bytes), every LIVE decoder's
-        KV arena (blocks x kv_block_bytes, incl. the trash block), and
+        KV arena (blocks x kv_block_bytes, incl. the trash block, plus
+        the per-lane state pool of a model with recurrent layers), and
         every registered ANN store's arena — summed against the
         HBM budget (ops/memory.hbm_budget_gb). Pure shape arithmetic,
         never a device read; it is also
@@ -1301,7 +1313,11 @@ class ServingEngine:
                             cfg, decoder.block_tokens,
                             getattr(decoder, "kv_dtype", None),
                             devices=int(getattr(decoder,
-                                                "mesh_devices", 1))))
+                                                "mesh_devices", 1)))
+                        # a model with recurrent layers: its per-lane
+                        # state pool rides in the arena (0 without)
+                        + decoder.lanes
+                        * opsmem.cache_needs(cfg).state_lane_bytes)
                 elif hasattr(decoder, "slots"):
                     # fixed pool: one slot == one max_len-token block
                     entry["kv_bytes"] = decoder.slots \

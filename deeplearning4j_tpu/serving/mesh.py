@@ -79,6 +79,7 @@ from deeplearning4j_tpu.models.transformer import (
 )
 from deeplearning4j_tpu.ops import dispatch
 from deeplearning4j_tpu.ops import env as envknob
+from deeplearning4j_tpu.ops import memory as opsmem
 from deeplearning4j_tpu.ops import lowprec
 from deeplearning4j_tpu.parallel.mesh import MODEL_AXIS, device_mesh
 from deeplearning4j_tpu.parallel.tensor_parallel import local_head_columns
@@ -315,6 +316,11 @@ class MeshPagedDecoder(PagedDecoder):
                     "tick: a mesh needs >= 2 devices (single-device "
                     "serving is PagedDecoder's job)")
             mesh = serving_mesh(nd)
+        if opsmem.cache_needs(cfg).state:
+            raise ValueError(
+                "the serving mesh (DL4J_TPU_SERVE_MESH) cannot carry the "
+                "per-lane recurrent state this model keeps: not "
+                "implemented for models with recurrent layers")
         self.serving_mesh = mesh
         nd = int(mesh.shape[MODEL_AXIS])
         if nd < 2:

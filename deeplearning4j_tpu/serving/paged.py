@@ -55,6 +55,13 @@ Layout and invariants:
     its SLO class with prompt := window + generated-so-far and its live
     PRNG key saved, so the resumed sample stream continues exactly
     where it stopped.
+  * a tick's tokens are booked as soon as they are read back, and
+    handed to their clients (streaming callbacks, then the futures of
+    finished lanes: _deliver) once the NEXT program is on the device,
+    a prefill or the tick, or at once where none follows: the
+    streaming threads a delivery wakes then run under the device's
+    time and not between two ticks. A lane's sampling key is made on
+    the host (seed_key), so an admission asks the device nothing.
 
 SLO classes (serving/slo.py) generalize the FIFO queue: admission is
 highest-class-first, per-class default deadlines feed the existing 504
@@ -63,6 +70,25 @@ path, and queue overflow sheds the youngest request of the lowest class
 
 Dense single-device models only, same gate as ContinuousDecoder. The
 fixed-slot pool remains the ``DL4J_TPU_SERVE_KV_BLOCK=0`` fallback.
+
+What is cached is the model's to say (ops/memory.cache_needs): how many
+layers hold keys and values, with how many KV heads of what size (the
+arena pages exactly those: ``[kv_layers, n_blocks+1, bt, kv_heads, hd]``,
+or one ``[n_blocks+1, bt, kv_heads * hd]`` a layer),
+and which per-lane recurrent state it keeps besides. The GPT-2-shaped
+TransformerLM is the instance with K and V in every layer for every head
+and no state; its tick and admit bodies are the ones in this file. A
+model with recurrent layers (models/hybrid.py) brings its own two bodies
+(decode_body, _paged_admit_for) and a state pool indexed by LANE: one
+``[lanes, *shape]`` buffer a layer and leaf, held in the arena pytree
+beside ``k`` and ``v``, so it is donated through every tick and
+admission and rewritten in place, zeroed with the arena, and overwritten
+whole when a lane is admitted (a released lane's state is dropped by
+never being read again). Such a model takes no prefix hit (blocks can
+be shared, state cannot be rebuilt from them), is preempted and
+requeued by recomputing from the window like any other, and is refused
+by the paths that cannot carry its state: scanned ticks, speculation,
+the serving mesh, the prefill/decode handoff, the fixed-slot pool.
 """
 
 from __future__ import annotations
@@ -111,6 +137,11 @@ def attention_path(cfg: TransformerConfig, block_tokens: int) -> str:
     the chunked ``ck[tables[:, chunk]]`` loop of chunked_attention.
     Resolved at trace time; the tick cache keys on it, and the
     serving_decode bench stamps it."""
+    if hasattr(cfg, "paged_decode_step"):
+        # a model that brings its own tick body (models/hybrid.py) reads
+        # the arena through chunked_attention; the kernel has no grouped
+        # heads
+        return "gather"
     hd = cfg.d_model // cfg.n_heads
     if jnp.dtype(lowprec.kv_dtype(cfg)) != jnp.dtype(cfg.compute_dtype):
         # a down-cast KV arena (DL4J_TPU_SERVE_KV_DTYPE=bf16 on an f32
@@ -154,11 +185,16 @@ def _exact_rows(x):
     return jnp.stack([hi, mid, lo], axis=2)
 
 
-def chunked_attention(q, ck, cv, tables, pos):
+def chunked_attention(q, ck, cv, tables, pos, scale=None):
     """Masked single-query attention over the block arena, chunk by
-    chunk up to the longest lane: q [S, H, hd], ck/cv [B, bt, H, hd]
-    (block 0 = trash), tables [S, m] int32, pos [S] int32 (every entry
-    >= 0) -> att [S, H, hd] float32.
+    chunk up to the longest lane: q [S, H, hd], ck/cv [B, bt, Hkv, hd]
+    or, a token's heads side by side, [B, bt, Hkv * hd] (block 0 =
+    trash), tables [S, m] int32, pos [S] int32 (every entry >= 0) ->
+    att [S, H, hd] float32. ``Hkv`` divides ``H``: KV head j
+    serves query heads g*j .. g*j+g-1 (g = H // Hkv; 1 is full
+    multi-head attention, whose program is what it was), their rows
+    side by side in one product against the head's blocks as stored.
+    ``scale`` multiplies the scores (None: 1/sqrt(hd)).
 
     Each pass gathers ``c`` table columns of K and V in the arena's
     dtype, takes the scores against the query's exact rows with float32
@@ -178,27 +214,34 @@ def chunked_attention(q, ck, cv, tables, pos):
     first pass on."""
     s, n_heads, hd = q.shape
     bt = ck.shape[1]
+    kv_heads = ck.shape[2] if ck.ndim == 4 else ck.shape[2] // hd
     chunk = _chunk_tokens(bt, tables.shape[1])
     c = chunk // bt
     # a table whose width c does not divide reads trash past its end,
     # at positions no lane can see
     tables = jnp.pad(tables, ((0, 0), (0, -tables.shape[1] % c)))
-    scale = 1.0 / float(np.sqrt(hd))
+    if scale is None:
+        scale = 1.0 / float(np.sqrt(hd))
     q_rows = _exact_rows(q).astype(ck.dtype)
     t_in = jnp.arange(chunk)[None, :]                 # [1, chunk]
 
     def rows_dot(spec, rows, gathered):
+        # rows [S, H, 3, x]: a KV head's query heads stand as further
+        # rows of its one product (a reshape to the shape it has is no
+        # operation: full multi-head attention traces as it did).
         # HIGHEST touches float32 operands only (a float32 arena)
-        return jnp.einsum(spec, rows, gathered,
-                          precision=lax.Precision.HIGHEST,
-                          preferred_element_type=jnp.float32).sum(axis=2)
+        rows = rows.reshape(s, kv_heads, -1, rows.shape[-1])
+        out = jnp.einsum(spec, rows, gathered,
+                         precision=lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
+        return out.reshape(s, n_heads, 3, out.shape[-1]).sum(axis=2)
 
     def fold(j, carry):
         m, l, acc = carry
         cols = lax.dynamic_slice_in_dim(tables, j * c, c, axis=1)
         with jax.named_scope("tick.gather_kv"):
-            kg = ck[cols].reshape(s, chunk, n_heads, hd)
-            vg = cv[cols].reshape(s, chunk, n_heads, hd)
+            kg = ck[cols].reshape(s, chunk, kv_heads, hd)
+            vg = cv[cols].reshape(s, chunk, kv_heads, hd)
         with jax.named_scope("tick.attend"):
             sc = rows_dot("nhrd,nthd->nhrt", q_rows, kg) * scale
             visible = j * chunk + t_in <= pos[:, None]    # [S, chunk]
@@ -285,6 +328,19 @@ def paged_decode_step(params, arena, tok, pos, tables,
     return {"k": ks, "v": vs}, h @ params["embed"].T
 
 
+def decode_body(cfg, attention: Optional[str] = None):
+    """The tick's body for a model: ``step(params, arena, tok, pos,
+    tables) -> (arena, logits)``. A config that brings its own
+    (``cfg.paged_decode_step``, models/hybrid.py: recurrent layers beside
+    attention, their per-lane state riding in the arena) is asked for
+    it; the GPT-2-shaped TransformerConfig is the instance above."""
+    own = getattr(cfg, "paged_decode_step", None)
+    if own is not None:
+        return own
+    return lambda params, arena, tok, pos, tables: paged_decode_step(
+        params, arena, tok, pos, tables, cfg, attention=attention)
+
+
 # jitted paged programs shared across decoder instances (the _TICK_CACHE
 # discipline from serving/decode.py): cfg is a frozen dataclass, and the
 # arena/lane shapes are jit trace dimensions, so one compiled program
@@ -305,10 +361,11 @@ def _paged_tick_for(cfg: TransformerConfig, block_tokens: int, k: int = 1):
     if fn is not None:
         return fn
 
+    step_body = decode_body(cfg, path)
+
     if k == 1:
         def tick(params, arena, tok, pos, tables, keys, temps):
-            arena, logits = paged_decode_step(params, arena, tok, pos,
-                                              tables, cfg, attention=path)
+            arena, logits = step_body(params, arena, tok, pos, tables)
             with jax.named_scope("tick.sample"):
                 nxt, nkeys = _sample_step(logits, keys, temps)
             return arena, nxt[:, None], nkeys
@@ -321,8 +378,7 @@ def _paged_tick_for(cfg: TransformerConfig, block_tokens: int, k: int = 1):
         def tick(params, arena, tok, pos, tables, keys, temps):
             def step(carry, _):
                 arena, tok, pos, keys = carry
-                arena, logits = paged_decode_step(
-                    params, arena, tok, pos, tables, cfg, attention=path)
+                arena, logits = step_body(params, arena, tok, pos, tables)
                 with jax.named_scope("tick.sample"):
                     nxt, keys = _sample_step(logits, keys, temps)
                 return (arena, nxt, pos + 1, keys), nxt
@@ -344,6 +400,17 @@ def _paged_admit_for(cfg: TransformerConfig, width: int, block_tokens: int):
     fn = _PAGED_ADMIT_CACHE.get(key)
     if fn is not None:
         return fn
+    own = getattr(cfg, "paged_admit", None)
+    if own is not None:
+        # the model's own admission body (models/hybrid.py): one more
+        # argument, ``lane`` int32 [2] = (lane index, positions that feed
+        # the lane's recurrent state), which it writes beside the blocks
+        def admit(params, arena, window, write_table, lane):
+            return own(params, arena, window, write_table, lane)
+
+        admit = dispatch.arena_jit(admit, donate=(1,))
+        _PAGED_ADMIT_CACHE[key] = admit
+        return admit
     m = cfg.max_len // block_tokens
     hd = cfg.d_model // cfg.n_heads
 
@@ -424,6 +491,33 @@ def _prefix_import_for(cfg: TransformerConfig, block_tokens: int,
     fn = dispatch.arena_jit(imp, donate=(0,))
     _PREFIX_IMPORT_CACHE[key] = fn
     return fn
+
+
+# admission gathers a burst that meets an idle pool: while no lane has given
+# a token yet, no tick is dispatched as long as requests are still arriving
+# (one was submitted within GATHER_S), for at most GATHER_CAP_S a pass. A
+# tick costs every waiting request its whole length, and how many of a
+# burst's requests have reached the queue when the worker first runs out of
+# them is up to the interpreter's scheduling: without the wait the same 32
+# requests are admitted in one pass or in four, a tick between each. The
+# wait runs under the first prefills. A pool in mid-generation never waits:
+# there it would add to the gap between two tokens of every live lane
+GATHER_S = 0.005
+GATHER_CAP_S = 0.025
+
+
+def seed_key(seed: int) -> np.ndarray:
+    """``jax.random.PRNGKey(seed)`` as the raw uint32 [2] it is, made on
+    the host: asked of the device, the key of every admission waits out
+    whatever the device has queued (the prefill before it, in a burst of
+    admissions), and the worker with it. The default implementation's
+    seeding is (seed >> 32, seed & 0xffffffff), the high word 0 without
+    x64; any other implementation is asked as before."""
+    if jax.config.jax_default_prng_impl != "threefry2x32":
+        return np.asarray(jax.random.PRNGKey(seed))
+    seed = int(seed)
+    high = (seed >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([high, seed & 0xFFFFFFFF], np.uint32)
 
 
 class BlockArena:
@@ -615,6 +709,19 @@ class PagedDecoder:
                              "(capacity routing is batch-dependent)")
         self.lm = lm
         self.cfg = cfg
+        # what the model holds per request (ops/memory.cache_needs): the
+        # layers and heads of K and V the arena pages, and the per-lane
+        # recurrent state that rides beside them in the arena pytree
+        self.needs = opsmem.cache_needs(cfg)
+        # multi-token ticks (ISSUE 16): steady-state decode scans tick_k
+        # steps per dispatch, adaptively dropping to 1 whenever
+        # admissions are pending or any lane is within k tokens of its
+        # budget — scheduling semantics stay per-token
+        self.tick_k = max(1, int(
+            tick_k if tick_k is not None
+            else envknob.get_int("DL4J_TPU_SERVE_TICK_K", 1)))
+        self._refuse_state("scanned ticks (DL4J_TPU_SERVE_TICK_K > 1)",
+                           self.tick_k > 1)
         # every device program reads params through this alias so the
         # mesh subclass (serving/mesh.py) can swap in a replicated
         # placement without re-plumbing the call sites
@@ -632,7 +739,8 @@ class PagedDecoder:
             # arena, so each device prices only H/d heads per block
             n_blocks = opsmem.kv_arena_blocks(cfg, bt, params=lm.params,
                                               dtype=self.kv_dtype,
-                                              devices=self.mesh_devices)
+                                              devices=self.mesh_devices,
+                                              lanes=lanes or 64)
         self.n_blocks = int(n_blocks)
         if self.n_blocks < self.table_width + 1:
             raise ValueError(
@@ -671,6 +779,7 @@ class PagedDecoder:
         self._running = True
         self._chaos = chaos
         self._dead: Optional[str] = None
+        self._last_submit = 0.0   # monotonic time of the newest submit
         self._seq = 0        # submit/requeue order (shed picks youngest)
         self._admit_seq = 0  # admission order (preemption picks youngest)
         # prefills dispatched since the last tick, and their summed
@@ -678,13 +787,6 @@ class PagedDecoder:
         self._admits = 0
         self._admit_width_sum = 0
         self.peak_active = 0
-        # multi-token ticks (ISSUE 16): steady-state decode scans tick_k
-        # steps per dispatch, adaptively dropping to 1 whenever
-        # admissions are pending or any lane is within k tokens of its
-        # budget — scheduling semantics stay per-token
-        self.tick_k = max(1, int(
-            tick_k if tick_k is not None
-            else envknob.get_int("DL4J_TPU_SERVE_TICK_K", 1)))
         # decoder-owned dispatch ledger (TransformerLM carries only
         # memory_stats): decode_ticks / decode_tokens surface the
         # amortization win at /metrics
@@ -694,12 +796,24 @@ class PagedDecoder:
         # (prefill/decode disaggregation — the worker owns the donated
         # arena, so imports must run on its thread)
         self._imports: deque = deque()
+        # the last tick's callbacks and finished lanes, kept back until
+        # the next program is on the device (_deliver); the worker's alone
+        self._undelivered: Optional[tuple] = None
         # per-k tick memo: the attention path is resolved ONCE per k at
         # first use (construction-time for k=1, matching the old
         # self._tick behavior) — not per iteration, where the kernel
         # gate's measured-win lookup would run per generated token
         self._ticks: Dict[int, object] = {1: self._build_tick(1)}
         self._start_worker()
+
+    def _refuse_state(self, what: str, asked: bool = True) -> None:
+        """A path that cannot carry per-lane recurrent state refuses a
+        model that keeps one, loudly, where it is asked for."""
+        if asked and self.needs.state:
+            raise ValueError(
+                f"{what} cannot carry the recurrent state this model keeps "
+                f"per lane ({', '.join(x.name for x in self.needs.state)}): "
+                "not implemented for models with recurrent layers")
 
     def _tick_fn(self, k: int):
         fn = self._ticks.get(k)
@@ -727,6 +841,10 @@ class PagedDecoder:
         self._worker.start()
 
     supports_streaming = True  # engine.generate_stream dispatches on this
+    # a tick's tokens reach their clients once the NEXT program is on the
+    # device (_deliver): serving/speculate.py, whose rounds stream on
+    # their own, hands them over at once
+    defer_delivery = True
     mesh_devices = 1  # serving-mesh width; MeshPagedDecoder overrides
     _arena_sharding = None  # default placement; MeshPagedDecoder overrides
 
@@ -736,8 +854,6 @@ class PagedDecoder:
         may have invalidated the old buffers, and with every lane failed
         no block content is worth keeping — cached prefixes included
         (they would read garbage from a reset arena)."""
-        cfg = self.cfg
-        hd = cfg.d_model // cfg.n_heads
         self._arena = self._zero_arena()
         self._blocks = BlockArena(self.n_blocks)
         self._prefix = PrefixCache(self._blocks)
@@ -750,15 +866,43 @@ class PagedDecoder:
         auto-sizer priced). Two distinct buffers: k and v donate
         separately and must not alias each other; the scatter in
         paged_decode_step casts k/v onto ck.dtype, so a bf16 arena under
-        an f32 model just works."""
-        cfg = self.cfg
-        hd = cfg.d_model // cfg.n_heads
-        shape = (cfg.n_layers, self.n_blocks + 1, self.block_tokens,
-                 cfg.n_heads, hd)
-        return {"k": jnp.zeros(shape, self.kv_dtype,
-                               device=self._arena_sharding),
-                "v": jnp.zeros(shape, self.kv_dtype,
-                               device=self._arena_sharding)}
+        an f32 model just works. A model with recurrent layers gets its
+        state pool here too: for each leaf it names, one ``[lanes,
+        *shape]`` buffer a layer, donated with k and v through every
+        tick and admission and so rewritten in place."""
+        needs = opsmem.cache_needs(self.cfg)
+        shape = (needs.kv_layers, self.n_blocks + 1, self.block_tokens,
+                 needs.kv_heads, needs.head_dim)
+        zeros = lambda shape: jnp.zeros(shape, self.kv_dtype,
+                                        device=self._arena_sharding)
+        if needs.kv_per_layer:
+            flat = shape[1:3] + (needs.kv_heads * needs.head_dim,)
+            arena = {"k": tuple(zeros(flat) for _ in range(shape[0])),
+                     "v": tuple(zeros(flat) for _ in range(shape[0]))}
+        else:
+            arena = {"k": zeros(shape), "v": zeros(shape)}
+        for leaf in needs.state:
+            arena[leaf.name] = tuple(
+                jnp.zeros((self.lanes,) + leaf.shape, leaf.dtype)
+                for _ in range(leaf.layers))
+        return arena
+
+    def _arena_deleted(self) -> bool:
+        """Did a donated program that failed take the arena with it?"""
+        try:
+            return jax.tree_util.tree_leaves(self._arena)[0].is_deleted()
+        except Exception:  # noqa: BLE001 — probe only
+            return False
+
+    def state_pool(self) -> Dict[str, tuple]:
+        """The per-lane recurrent state as the last tick or admission
+        left it: for each leaf the model names, its ``[lanes, *shape]``
+        buffers, one a layer (nothing for a model of KV layers alone).
+        The buffers themselves, not copies: the next tick donates them,
+        so read them while the pool is idle and let them go."""
+        with self._cond:
+            return {leaf.name: tuple(self._arena[leaf.name])
+                    for leaf in self.needs.state}
 
     # -- capacity ---------------------------------------------------------
     def kv_capacity(self) -> Dict[str, object]:
@@ -779,6 +923,16 @@ class PagedDecoder:
             "lanes": self.lanes,
             "prefix_blocks_cached": len(self._prefix),
             "mesh_devices": int(self.mesh_devices),
+            # what the model said it holds: the arena's layers and heads,
+            # and the state pool's lanes and bytes (0 without recurrent
+            # layers)
+            "kv_layers": self.needs.kv_layers,
+            "kv_heads": self.needs.kv_heads,
+            "block_bytes": opsmem.kv_block_bytes(
+                self.cfg, self.block_tokens, self.kv_dtype,
+                devices=int(self.mesh_devices)),
+            "state_lanes": self.lanes if self.needs.state else 0,
+            "state_bytes": self.lanes * self.needs.state_lane_bytes,
         }
 
     # -- client side ------------------------------------------------------
@@ -846,6 +1000,7 @@ class PagedDecoder:
                 victim.future.set_exception(QueueFullError(
                     f"shed by higher-priority class {cls.name!r}"))
             self._pending[cls.name].append(req)
+            self._last_submit = time.monotonic()
             self.stats.set_queue_depth(self._total_pending(), "decode")
             self._cond.notify_all()
         return req.future
@@ -934,11 +1089,22 @@ class PagedDecoder:
         too (the bound follows the longest lane, step by step)."""
         pos = self._pos[active].astype(np.int64)
         top = int(self._pos.max())
-        return {
+        counts = {
             "kv_live": int(sum((pos + 1 + j).sum() for j in range(k))),
             "kv_read": self.lanes * sum(
                 kv_read_tokens(top + j, self.block_tokens, self.table_width)
                 for j in range(k))}
+        if self.needs.state:
+            # live lanes whose recurrent state the tick advances, and the
+            # least it moves for them: each lane's ``ssm`` leaf read once
+            # and written once a step (the leaf of that name alone: the
+            # conv tail beside it is a hundredth of it, and the reader of
+            # these bytes times the events of the ssm leaf's shape)
+            counts["ssm_lanes"] = len(active) * k
+            counts["ssm_state_bytes"] = 2 * counts["ssm_lanes"] * sum(
+                leaf.lane_bytes for leaf in self.needs.state
+                if leaf.name == "ssm")
+        return counts
 
     def _youngest_active(self) -> Optional[int]:
         best, best_seq = None, -1
@@ -1042,7 +1208,11 @@ class PagedDecoder:
         window = np.ascontiguousarray(req.prompt[req.prompt.size - keep:])
         wb0 = (keep - 1) // bt        # first write block: always private
         nb_prompt = wb0 + 1
-        hashes = PrefixCache.chain_hashes(window, bt, wb0)
+        # a prefix hit restores KV blocks only: a lane whose recurrent
+        # state started after tokens it never saw would be wrong, so a
+        # model with such state takes no hit and counts no lookup
+        hashes = [] if self.needs.state \
+            else PrefixCache.chain_hashes(window, bt, wb0)
         hits = self._prefix.lookup(hashes)
         if hashes:
             self.stats.record_prefix(len(hits), len(hashes))
@@ -1062,7 +1232,7 @@ class PagedDecoder:
         # cache candidates: private FULL blocks strictly below the write
         # block — they are fully prompt-covered and never written again
         inserts = [(hashes[j], int(read_table[j]))
-                   for j in range(len(hits), wb0)]
+                   for j in range(len(hits), len(hashes))]
         width = min(max(dispatch.bucket_size(keep), keep), cfg.max_len)
         buf = np.zeros((1, width), np.int32)
         buf[0, :keep] = window
@@ -1070,7 +1240,7 @@ class PagedDecoder:
         self._pos[i] = keep - 1  # re-consume the last prompt token
         self._temps[i] = req.temperature
         self._keys[i] = (req.key_override if req.key_override is not None
-                         else np.asarray(jax.random.PRNGKey(req.seed)))
+                         else seed_key(req.seed))
         self._tables[i, :] = read_table
         self._admit_seq += 1
         self._slots[i] = _Lane(req, hits + fresh, nb_prompt, window,
@@ -1083,9 +1253,14 @@ class PagedDecoder:
         # the lane index rides the signature so subclasses with per-lane
         # side state (serving/speculate.py prefills its draft cache row
         # here) share this crash-isolation boundary
+        # a model with recurrent layers is also told its lane and how
+        # many positions feed the lane's state: all but the last prompt
+        # token, which the first tick re-consumes (self._pos[i])
+        lane = (jnp.asarray([i, self._pos[i]], jnp.int32),) \
+            if self.needs.state else ()
         self._arena = self._build_admit(width)(
             self._infer_params, self._arena, jnp.asarray(buf),
-            jnp.asarray(write_table))
+            jnp.asarray(write_table), *lane)
 
     # -- prefill/decode disaggregation ------------------------------------
     def export_prefix(self, prompt, n_new: int):
@@ -1101,6 +1276,7 @@ class PagedDecoder:
         byte-stability argument). Returns (digests, k_blocks, v_blocks)
         with blocks [L, n, bt, H, hd] in the arena dtype; n may be 0
         for short prompts (nothing worth handing off)."""
+        self._refuse_state("the prefill/decode handoff (export_prefix)")
         cfg = self.cfg
         bt = self.block_tokens
         prompt = np.asarray(prompt, np.int32).reshape(-1)
@@ -1136,6 +1312,7 @@ class PagedDecoder:
         correctness never depends on it — an already-cached digest, an
         exhausted free list or a device failure just shrink the adopted
         run, and the next admission's prefill recomputes the rest."""
+        self._refuse_state("the prefill/decode handoff (import_prefix)")
         cfg = self.cfg
         hd = cfg.d_model // cfg.n_heads
         digests = list(digests)
@@ -1207,11 +1384,7 @@ class PagedDecoder:
                 with self._cond:
                     for b in fresh:
                         self._blocks.decref(b)
-                try:
-                    deleted = self._arena["k"].is_deleted()
-                except Exception:  # noqa: BLE001 — probe only
-                    deleted = False
-                if deleted:
+                if self._arena_deleted():
                     # the DONATED import died mid-execution and took the
                     # arena with it (same honesty as a crashed admit)
                     self._fail_active_lanes(e)
@@ -1236,7 +1409,10 @@ class PagedDecoder:
     # -- worker side ------------------------------------------------------
     def _run(self) -> None:
         try:
-            self._run_inner()
+            try:
+                self._run_inner()
+            finally:
+                self._deliver()   # what the last tick gave, whatever ended us
         except Exception as e:  # noqa: BLE001 — worker loop boundary
             with self._cond:
                 self._dead = f"{type(e).__name__}: {e}"
@@ -1284,15 +1460,52 @@ class PagedDecoder:
             # earlier prefill just cached — inserts land between
             # prefills, and only after the block content is actually
             # written (a crashed prefill never publishes its digests)
-            while self._admit_one():
-                pass
+            self._admit_pending()
+            self._gather()
             if not self._tick_phase():
+                return
+
+    def _admit_pending(self) -> bool:
+        """Admit what waits and can be admitted; True if anything was."""
+        any_admitted = False
+        while self._admit_one():
+            # the prefill is on the device: the last tick's tokens go out
+            # under it
+            self._deliver()
+            any_admitted = True
+        return any_admitted
+
+    def _gather(self) -> None:
+        """Hold the first tick of a pool that was idle back while a burst
+        is still arriving: as long as no lane has given a token and the
+        newest submit is younger than GATHER_S (GATHER_CAP_S at the most),
+        wait for the next and admit it. What waits but cannot be admitted
+        (no lane, no blocks) ends the wait: a tick frees both."""
+        if any(st is not None and st.tokens for st in self._slots):
+            return
+        cap = time.monotonic() + GATHER_CAP_S
+        while True:
+            now = time.monotonic()
+            until = min(self._last_submit + GATHER_S, cap)
+            if now >= until:
+                return
+            with self._cond:
+                if not self._running:
+                    return
+                if not self._total_pending():
+                    with obs_trace.span("serve.gather"):
+                        self._cond.wait(timeout=until - now)
+            if not self._admit_pending() and self._total_pending():
                 return
 
     def _sweep(self) -> None:
         """The head of a worker pass: expire what has outlived its
         deadline, in a lane or in the queue, and adopt handed-off prefix
         blocks."""
+        now = time.monotonic()
+        if self._undelivered is not None and any(
+                st is not None and st.deadline < now for st in self._slots):
+            self._deliver()     # a lane's last tokens before its timeout
         with self._cond:
             now = time.monotonic()
             for i in range(self.lanes):
@@ -1356,6 +1569,8 @@ class PagedDecoder:
                         ("lookup_blocks", lane.n_table - 1),
                         ("fresh_blocks", fresh)):
                     sp.set_attr(key, value)
+                if self.needs.state:
+                    sp.set_attr("scan_chunks", self.cfg.scan_chunks(width))
             try:
                 if self._chaos is not None:
                     self._chaos.on_admit()
@@ -1375,11 +1590,7 @@ class PagedDecoder:
                 if st is not None and not st.future.done():
                     st.future.set_exception(e)
                 self.stats.record_slot_crash()
-                try:
-                    deleted = self._arena["k"].is_deleted()
-                except Exception:  # noqa: BLE001 — probe only
-                    deleted = False
-                if deleted:
+                if self._arena_deleted():
                     # the DONATED admit died mid-execution and took
                     # the arena with it: co-resident KV is gone, so
                     # honest failure beats silently garbage tokens
@@ -1407,9 +1618,10 @@ class PagedDecoder:
                 sp_plan.discard()   # nothing to plan; the wait has a name
                 if not self._running:
                     return False
-                with obs_trace.span("serve.idle"):
-                    self._cond.wait()
-                return True
+                if self._undelivered is None:
+                    with obs_trace.span("serve.idle"):
+                        self._cond.wait()
+                    return True
             # adaptive k (ISSUE 16): a literal drop to 1 — never an
             # intermediate clamp — so only the k=1 and k=tick_k
             # programs ever compile. Pending admissions must not
@@ -1434,6 +1646,10 @@ class PagedDecoder:
             active = [i for i in range(self.lanes)
                       if self._slots[i] is not None]
         if not active:
+            # nothing goes to the device in this pass, so nothing hides
+            # the last tick's delivery: it goes out now (the pool emptied
+            # under a sweep or a preemption), and the next pass waits
+            self._deliver()
             return True
         # one fixed-shape device tick for the whole pool (no lock
         # held): k scanned steps per dispatch, tokens [S, k]; the
@@ -1455,25 +1671,33 @@ class PagedDecoder:
                         jnp.asarray(self._tables),
                         jnp.asarray(self._keys),
                         jnp.asarray(self._temps))
+                # the device has its next program: the last tick's tokens
+                # go to their clients under it
+                self._deliver()
                 with obs_trace.span("serve.tick.wait"):
                     nxt = np.asarray(nxt)
         except Exception as e:  # noqa: BLE001 — device boundary
+            self._deliver()     # tokens the last tick gave come first
             self._fail_active_lanes(e)
             return True
         with obs_trace.span("serve.tick.emit", tick=sp_tick.span_id):
             self._emit(active, k, nxt, keys)
             # the tick's last device output dies HERE, inside the span:
-            # freeing a device array lets go of the GIL, and the
-            # streaming threads that the callbacks just woke take their
-            # turn (2 ms at 31 lanes) before the worker runs on
+            # freeing a device array lets go of the GIL, and where the
+            # tokens went out at once the streaming threads they woke
+            # take their turn (2 ms at 31 lanes) before the worker runs on
             del keys
         return True
 
     def _emit(self, active: List[int], k: int, nxt: np.ndarray,
               keys) -> None:
         """The host's share of a tick after the readback: unpack the
-        [lanes, k] tokens, fire the streaming callbacks, resolve the
-        futures of finished lanes."""
+        [lanes, k] tokens and book them; the streaming callbacks and the
+        futures of finished lanes are _deliver's, at once where nothing
+        follows on the device (the pool emptied and nobody waits) or
+        the decoder does not defer, else once the next program is on it:
+        waking one streaming thread a lane costs the worker the
+        interpreter for milliseconds, which the device then idles."""
         self._keys = np.array(keys)  # writable copy (admits write rows)
         self.dispatch_stats.decode_ticks += 1
         self.dispatch_stats.decode_tokens += len(active) * k
@@ -1501,16 +1725,35 @@ class PagedDecoder:
                         completions.append(st)
                         self._release_lane(i)
                         break
+            follows = self.defer_delivery and (
+                self._total_pending() > 0
+                or any(st is not None for st in self._slots))
             self._cond.notify_all()  # drain() waiters see evictions
-        # stream callbacks BEFORE resolving futures (a client
-        # iterating tokens must see the last token before done), and
-        # outside the lock (a slow client must not stall the pool)
-        for cb, t in callbacks:
-            try:
-                cb(t)
-            except Exception:  # noqa: BLE001 — client callback boundary
-                pass
-        for st in completions:
-            if not st.future.done():
-                st.future.set_result(np.asarray(st.tokens, np.int32))
-                self.stats.record_latency(time.monotonic() - st.enqueued)
+        self._undelivered = (callbacks, completions)
+        if not follows:
+            self._deliver()
+
+    def _deliver(self) -> None:
+        """Hand the last tick's tokens to their clients and resolve the
+        futures of the lanes it finished (worker thread only; nothing to
+        do where nothing is kept back). Stream callbacks BEFORE resolving
+        futures (a client iterating tokens must see the last token before
+        done), and outside the lock (a slow client must not stall the
+        pool)."""
+        if self._undelivered is None:
+            return
+        callbacks, completions = self._undelivered
+        self._undelivered = None
+        if not callbacks and not completions:
+            return
+        with obs_trace.span("serve.tick.deliver", tokens=len(callbacks)):
+            for cb, t in callbacks:
+                try:
+                    cb(t)
+                except Exception:  # noqa: BLE001 — client callback boundary
+                    pass
+            for st in completions:
+                if not st.future.done():
+                    st.future.set_result(np.asarray(st.tokens, np.int32))
+                    self.stats.record_latency(
+                        time.monotonic() - st.enqueued)

@@ -75,6 +75,7 @@ from deeplearning4j_tpu.models.transformer import TransformerConfig
 from deeplearning4j_tpu.obs import trace as obs_trace
 from deeplearning4j_tpu.ops import dispatch
 from deeplearning4j_tpu.ops import env as envknob
+from deeplearning4j_tpu.ops import memory as opsmem
 from deeplearning4j_tpu.serving import decode
 from deeplearning4j_tpu.serving.paged import (
     PagedDecoder,
@@ -139,6 +140,10 @@ class SpeculativeDecoder(PagedDecoder):
     proposals — forcing all-reject rounds deterministically; config-
     driven, never ambient."""
 
+    # a round streams its own tokens as it commits them, and reads
+    # nothing of a tick's that is kept back: hand every tick's over at once
+    defer_delivery = False
+
     def __init__(self, lm, *, draft, spec_k: Optional[int] = None,
                  spec_chaos=None, **kw) -> None:
         if draft is None:
@@ -148,6 +153,12 @@ class SpeculativeDecoder(PagedDecoder):
             raise ValueError("speculative drafts must be single-device")
         dcfg = draft._run_cfg
         cfg = lm._run_cfg
+        if opsmem.cache_needs(cfg).state or opsmem.cache_needs(dcfg).state:
+            raise ValueError(
+                "speculative decoding (DL4J_TPU_SERVE_SPEC) cannot carry "
+                "per-lane recurrent state: a rejected draft token would "
+                "have to be taken back out of it; not implemented for "
+                "models with recurrent layers")
         if (dcfg.vocab_size != cfg.vocab_size
                 or dcfg.max_len != cfg.max_len):
             raise ValueError(

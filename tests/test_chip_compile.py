@@ -146,6 +146,76 @@ def test_tick_attention_reads_the_arena_as_stored_on_v5e(one_chip, heads,
     assert len(products) == 2, products
 
 
+def test_hybrid_tick_rewrites_its_state_in_place_on_v5e(one_chip,
+                                                        no_compile_cache):
+    """The hybrid serve cell's tick at the published widths (d 2048, 64
+    Mamba heads of 64 over a state of 128, 32 query heads over 8 KV heads),
+    three layers of its pattern, 64 lanes: every layer's state buffer is an
+    argument that the program aliases to its output (donated, rewritten in
+    place), the update is ONE fusion that reads S once and gives S and y,
+    and nothing at the top level copies a buffer of the state's shape or
+    of the KV arena's (the arena's fault, ROADMAP S15, not repeated for
+    4.8 GB of state nor for these K and V). The
+    scopes the trace readers look for are in the program."""
+    from deeplearning4j_tpu.models import hybrid
+    from deeplearning4j_tpu.ops import memory as opsmem
+    from deeplearning4j_tpu.serving import paged
+
+    cfg = hybrid.HybridConfig(
+        vocab_size=4096, d_model=2048, n_heads=32, n_kv_heads=8, d_ff=8192,
+        layer_types=("mamba", "attention", "mamba"), max_len=2048,
+        ssm_heads=64, ssm_head_dim=64, ssm_state=128)
+    lanes, n_blocks, bt = 64, 512, 16
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    params = jax.tree.map(lambda s: arg(s, jnp.bfloat16),
+                          hybrid.param_shapes(cfg),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    needs = opsmem.cache_needs(cfg)
+    kv = (n_blocks + 1, bt, needs.kv_heads * needs.head_dim)
+    arena = {"k": (arg(kv, jnp.bfloat16),), "v": (arg(kv, jnp.bfloat16),)}
+    for leaf in needs.state:
+        arena[leaf.name] = tuple(arg((lanes,) + leaf.shape, leaf.dtype)
+                                 for _ in range(leaf.layers))
+    with jax.enable_x64(False):
+        compiled = paged._paged_tick_for(cfg, bt).lower(
+            params, arena, arg((lanes,), jnp.int32), arg((lanes,), jnp.int32),
+            arg((lanes, cfg.max_len // bt), jnp.int32),
+            arg((lanes, 2), jnp.uint32), arg((lanes,), jnp.float32)
+        ).compile()
+    paged._PAGED_TICK_CACHE.pop((cfg, bt, "gather", 1), None)
+    text = compiled.as_text()
+    state = f"f32[{lanes},64,64,128]"
+    entry = text[text.index("\nENTRY "):]
+    made = [m for m in (re.match(
+        r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(", ln)
+        for ln in entry.splitlines()) if m and state in m.group(1)]
+    # the two state buffers come in as parameters, each leaves through one
+    # fusion that also gives y [lanes, 64, 64], and nothing else of that
+    # shape is made at the top level: no copy, no transpose
+    kinds = sorted(m.group(2) for m in made)
+    assert kinds.count("parameter") == 2, kinds
+    assert kinds.count("fusion") == 2, kinds
+    assert not [k for k in kinds if k not in
+                ("parameter", "fusion", "get-tuple-element", "bitcast",
+                 "tuple")], kinds
+    fused = [m.group(1) for m in made if m.group(2) == "fusion"]
+    assert all(f"f32[{lanes},64,64]" in t for t in fused), fused
+    # donated and aliased: the whole arena pytree, byte for byte
+    donated = sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
+                  for a in jax.tree.leaves(arena))
+    # (at least: the chip pads a tiled buffer, here the conv tails)
+    assert compiled.memory_analysis().alias_size_in_bytes >= donated
+    # nor a buffer of the KV arena's: a token's heads lie side by side in
+    # one row of 512, which the chip stores as the scatter and the gather
+    # want it (as [.., 8, 64] it re-laid each buffer twice a tick)
+    assert not re.findall(rf" = bf16\[{n_blocks + 1},{bt},[\d,]+\]\S* "
+                          r"(?:copy|transpose)\(", entry)
+    for scope in ("tick.ssm_step", "tick.ssm_conv", "tick.scatter",
+                  "tick.gather_kv", "tick.attend", "tick.sample"):
+        assert scope in text, scope
+
+
 # ---------------------------------------------------------------------------
 # 2. the scopes, in the lowered programs
 # ---------------------------------------------------------------------------

@@ -137,18 +137,81 @@ def _tick_inputs(cfg, lanes, pos, seed=0):
         jnp.asarray(_dense_tables(lanes, m))
 
 
-def test_lane_logits_bit_equal_alone_and_beside_a_longer_lane():
-    cfg = _cfg()
+def _pr27_chunked_attention(q, ck, cv, tables, pos):
+    """paged.chunked_attention as PR 27 left it, kept here letter for
+    letter: full multi-head attention only, scores over sqrt(hd). What the
+    tick of a GPT-2-shaped model has to stay, bit for bit, now that the
+    function also serves grouped heads (ISSUE 28)."""
+    from jax import lax
+
+    s, n_heads, hd = q.shape
+    bt = ck.shape[1]
+    chunk = paged._chunk_tokens(bt, tables.shape[1])
+    c = chunk // bt
+    tables = jnp.pad(tables, ((0, 0), (0, -tables.shape[1] % c)))
+    scale = 1.0 / float(np.sqrt(hd))
+    q_rows = paged._exact_rows(q).astype(ck.dtype)
+    t_in = jnp.arange(chunk)[None, :]
+
+    def rows_dot(spec, rows, gathered):
+        return jnp.einsum(spec, rows, gathered,
+                          precision=lax.Precision.HIGHEST,
+                          preferred_element_type=jnp.float32).sum(axis=2)
+
+    def fold(j, carry):
+        m, l, acc = carry
+        cols = lax.dynamic_slice_in_dim(tables, j * c, c, axis=1)
+        with jax.named_scope("tick.gather_kv"):
+            kg = ck[cols].reshape(s, chunk, n_heads, hd)
+            vg = cv[cols].reshape(s, chunk, n_heads, hd)
+        with jax.named_scope("tick.attend"):
+            sc = rows_dot("nhrd,nthd->nhrt", q_rows, kg) * scale
+            visible = j * chunk + t_in <= pos[:, None]
+            sc = jnp.where(visible[:, None, :], sc, -jnp.inf)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
+            p = jnp.exp(sc - m_new[..., None])
+            corr = jnp.exp(m - m_new)
+            l = l * corr + jnp.sum(p, axis=-1)
+            acc = acc * corr[..., None] + rows_dot(
+                "nhrt,nthd->nhrd", paged._exact_rows(p).astype(cv.dtype), vg)
+        return m_new, l, acc
+
+    init = (jnp.full((s, n_heads), -jnp.inf, jnp.float32),
+            jnp.zeros((s, n_heads), jnp.float32),
+            jnp.zeros((s, n_heads, hd), jnp.float32))
+    _, l, acc = lax.fori_loop(0, jnp.max(pos) // chunk + 1, fold, init)
+    return acc / l[..., None]
+
+
+@pytest.mark.parametrize("shape", [
+    {},                                        # the file's tiny model
+    # the serve cell's heads: 12 of 128 at d 1536, bfloat16 (one layer and a
+    # narrow MLP: the attention is what is pinned)
+    dict(d_model=1536, n_heads=12, n_layers=1, d_ff=64,
+         dtype_policy="performance"),
+], ids=["tiny", "590m-shaped"])
+def test_lane_logits_bit_equal_alone_and_beside_a_longer_lane(shape,
+                                                              monkeypatch):
+    cfg = _cfg(**shape)
     short, long_ = CHUNK // 2, 4 * CHUNK + CHUNK // 2
     params, arena, tok, _, tables = _tick_inputs(cfg, 2, [0, 0])
-    step = jax.jit(lambda a, p: paged.paged_decode_step(
-        params, a, tok, p, tables, cfg, attention="gather")[1])
+    tick = lambda a, p: paged.paged_decode_step(
+        params, a, tok, p, tables, cfg, attention="gather")[1]
+    step = jax.jit(tick)
     # lane 1 dead at position 0: the loop runs one chunk; then live four
     # chunks further on: it runs five
+    both = jnp.asarray([short, long_], jnp.int32)
     alone = np.asarray(step(arena, jnp.asarray([short, 0], jnp.int32)))
-    beside = np.asarray(step(arena, jnp.asarray([short, long_], jnp.int32)))
+    beside = np.asarray(step(arena, both))
     assert np.array_equal(alone[0], beside[0])
     assert not np.array_equal(alone[1], beside[1])
+    # and the tick is bit for bit what it was before chunked_attention
+    # learned grouped heads: the same program text, the same logits
+    text = jax.jit(tick).lower(arena, both).as_text()
+    monkeypatch.setattr(paged, "chunked_attention", _pr27_chunked_attention)
+    was = jax.jit(lambda a, p: tick(a, p))
+    assert was.lower(arena, both).as_text() == text
+    assert np.array_equal(np.asarray(was(arena, both)), beside)
 
 
 # ---------------------------------------------------------------------------

@@ -309,6 +309,121 @@ class TestStreaming:
         finally:
             d.stop()
 
+    @pytest.mark.parametrize("x64", [False, True])
+    @pytest.mark.parametrize("seed", [0, 1, 7, 2**31 - 1, 2**31, 2**32 - 1,
+                                      2**32 + 5, 4100003106, -1])
+    def test_seed_key_is_the_devices_key(self, seed, x64):
+        """A lane's key is made on the host (an admission asks the device
+        nothing): bit for bit what ``jax.random.PRNGKey`` gives, with and
+        without x64, past 32 bits and below zero."""
+        import jax
+
+        from deeplearning4j_tpu.serving.paged import seed_key
+
+        with jax.enable_x64(x64):
+            want = np.asarray(jax.random.PRNGKey(seed))
+            got = seed_key(seed)
+        assert got.dtype == want.dtype and got.tolist() == want.tolist()
+
+    def test_tokens_go_out_once_the_next_program_is_dispatched(self):
+        """A tick's tokens are kept back until the next program is on the
+        device, and handed over at once where none follows: while lanes
+        are live every callback of tick t runs after tick t+1's dispatch
+        began; the last tick's run inside its own emit; order and the
+        done-after-last-token contract hold."""
+        from deeplearning4j_tpu.serving.paged import PagedDecoder
+
+        lm = tiny_lm()
+        d = PagedDecoder(lm, block_tokens=8, n_blocks=16)
+        events = []
+        fn = d._tick_fn(1)
+
+        def spy(*a):
+            events.append("dispatch")
+            return fn(*a)
+
+        d._ticks[1] = spy
+        try:
+            fut = d.submit([1, 5, 2, 9], 4, temperature=0.0,
+                           on_token=lambda t: events.append(("token", t)))
+            fut.add_done_callback(lambda f: events.append("done"))
+            out = fut.result(timeout=120)
+        finally:
+            d.stop()
+        tokens = [e[1] for e in events if isinstance(e, tuple)]
+        assert tokens == list(out)
+        kinds = ["t" if isinstance(e, tuple) else e for e in events]
+        # ticks 1 to 3 leave the lane live: token t is handed over after
+        # dispatch t+1; tick 4 empties the pool: its token goes at once
+        assert kinds == ["dispatch", "dispatch", "t", "dispatch", "t",
+                         "dispatch", "t", "t", "done"]
+
+    def test_a_burst_is_admitted_before_its_first_tick(self):
+        """Requests that arrive a millisecond apart (closer than
+        paged.GATHER_S) all sit in lanes when the first tick after them
+        is dispatched, though each admission is faster than the gap: the
+        worker holds the tick back while the burst is still arriving."""
+        from deeplearning4j_tpu.serving import paged
+
+        lm = tiny_lm()
+        d = paged.PagedDecoder(lm, block_tokens=8, n_blocks=32, lanes=8)
+        try:
+            # every program the burst uses is compiled first
+            d.submit([3, 1, 4, 1], 2, temperature=0.0).result(timeout=120)
+            live = []
+            fn = d._tick_fn(1)
+
+            def spy(*a):
+                live.append(sum(st is not None for st in d._slots))
+                return fn(*a)
+
+            d._ticks[1] = spy
+            futs = []
+            for i in range(6):
+                futs.append(d.submit([1 + i, 5, 2, 9], 3, temperature=0.0))
+                time.sleep(0.001)
+            for f in futs:
+                f.result(timeout=120)
+        finally:
+            d.stop()
+        assert paged.GATHER_S > 0.001
+        assert live[0] == 6, live
+
+    def test_a_pool_in_mid_generation_does_not_wait_for_arrivals(self):
+        """The wait is for a pool that was idle: with a lane that has given
+        tokens, a submit a moment ago holds no tick back (it would add to
+        the gap between two tokens of every live lane)."""
+        from deeplearning4j_tpu.serving import paged
+
+        d = paged.PagedDecoder(tiny_lm(), block_tokens=8, n_blocks=32,
+                               lanes=4)
+        try:
+            d.submit([3, 1, 4, 1], 2, temperature=0.0).result(timeout=120)
+            time.sleep(0.05)      # the worker sleeps in serve.idle: by hand
+
+            class Lane:
+                tokens = [7]
+
+            for slots, least, most in (([None] * 4, paged.GATHER_S * 0.8,
+                                        paged.GATHER_CAP_S + 0.5),
+                                       ([Lane()] + [None] * 3, 0.0,
+                                        paged.GATHER_S * 0.5)):
+                d._slots = slots
+                d._last_submit = time.monotonic()
+                t0 = time.monotonic()
+                d._gather()
+                assert least <= time.monotonic() - t0 <= most, slots
+        finally:
+            d._slots = [None] * 4
+            d.stop()
+
+    def test_speculative_decoder_hands_over_at_once(self):
+        from deeplearning4j_tpu.serving.paged import PagedDecoder
+        from deeplearning4j_tpu.serving.speculate import SpeculativeDecoder
+
+        assert PagedDecoder.defer_delivery
+        assert not SpeculativeDecoder.defer_delivery
+
     def test_http_stream_matches_nonstream(self):
         """POST /generate with stream=true chunks NDJSON token events
         and a final done record whose tokens equal the non-streaming

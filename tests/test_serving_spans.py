@@ -478,3 +478,29 @@ def test_profiler_host_plane_holds_the_span_names(obs_on, lm, tmp_path):
     # a wait recorded after the fact and a span that crosses threads are
     # not host work: no annotation
     assert "serve.queue" not in names and "serve.request" not in names
+
+
+def test_delivery_runs_under_the_next_dispatch(obs_on, lm):
+    """``serve.tick.deliver`` (callbacks and futures) of a tick that leaves
+    lanes live lies inside the NEXT tick, after its dispatch and before its
+    wait; the tick that empties the pool delivers inside its own emit."""
+    engine = ServingEngine(model=lm)
+    try:
+        assert len(_stream(engine, [5, 1, 4], 4)) == 4
+    finally:
+        engine.stop()
+    by_start = lambda spans: sorted(spans, key=lambda s: s["t_mono"])
+    ticks = by_start(s for s in obs_on.spans("serve.batch")
+                     if s["attrs"]["kind"] == "decode.paged")
+    delivers = by_start(obs_on.spans("serve.tick.deliver"))
+    assert len(ticks) == len(delivers) == 4
+    assert [d["attrs"]["tokens"] for d in delivers] == [1, 1, 1, 1]
+    stages = {s["parent_id"]: s for s in obs_on.spans("serve.tick.stage")}
+    waits = {s["parent_id"]: s for s in obs_on.spans("serve.tick.wait")}
+    for d, t in zip(delivers[:3], ticks[1:]):
+        assert d["parent_id"] == t["span_id"]
+        assert _end(stages[t["span_id"]]) <= d["t_mono"] + EPS
+        assert _end(d) <= waits[t["span_id"]]["t_mono"] + EPS
+    last_emit = by_start(obs_on.spans("serve.tick.emit"))[-1]
+    assert last_emit["attrs"]["tick"] == ticks[-1]["span_id"]
+    assert delivers[-1]["parent_id"] == last_emit["span_id"]
