@@ -358,15 +358,17 @@ class CacheNeeds:
     ``kv_heads`` heads of ``head_dim`` (paged, priced a block), and
     ``state`` leaves indexed by lane (priced a lane). The GPT-2-shaped
     TransformerLM is the instance with every layer a KV layer, as many
-    KV heads as query heads and no state. ``kv_per_layer`` asks for K
-    and V as one buffer a layer (a tick whose layers are unrolled
-    scatters into and reads each in place), a token's heads side by
-    side in one row, ``[blocks+1, block_tokens, kv_heads * head_dim]``
-    (a last dimension of one head of 64 the chip stores in another
-    order than the scatter and the gather want, and re-lays the whole
-    arena twice a tick), instead of one ``[kv_layers, blocks+1,
-    block_tokens, kv_heads, head_dim]`` stacked on a leading layer axis
-    (what a tick that scans over its layers wants)."""
+    KV heads as query heads and no state. A block's row is always one
+    token's heads side by side, ``kv_heads * head_dim`` wide (with the
+    heads as an axis of their own the chip stores a buffer in another
+    order than the scatter and the gathers want, and re-lays it).
+    ``kv_per_layer`` asks for K and V as one buffer a layer,
+    ``[blocks+1, block_tokens, kv_heads * head_dim]`` (a tick whose
+    layers differ and are unrolled scatters into and reads each in
+    place), instead of ONE ``[kv_layers, blocks+1, block_tokens,
+    kv_heads * head_dim]`` (a tick that scans over layers that are
+    alike carries it through the scan and reaches layer ``l`` at rows
+    ``l * (blocks+1) + block``)."""
     kv_layers: int
     kv_heads: int
     head_dim: int
@@ -392,13 +394,16 @@ def kv_block_bytes(cfg, block_tokens: int, dtype=None,
                    devices: int = 1) -> int:
     """PER-DEVICE bytes of ONE paged KV block across the layers that
     hold keys and values (:func:`cache_needs`): K and V,
-    [kv_layers, block_tokens, kv_heads/devices, head_dim] each, in the
-    arena dtype (serving/paged.py's layout). ``dtype=None`` resolves
+    [kv_layers, block_tokens, (kv_heads/devices) * head_dim] each, in
+    the arena dtype (serving/paged.py's layout, ``[L, n_blocks+1, bt,
+    H*hd]``: a token's heads side by side; bytes by shape, whatever the
+    order). ``dtype=None`` resolves
     through ops/lowprec.kv_dtype — the model's compute dtype unless
     ``DL4J_TPU_SERVE_KV_DTYPE`` overrides it (bf16 halves KV bytes, so
     the same HBM budget admits ~2x tokens). ``devices`` is the serving
-    mesh width (serving/mesh.py head-shards the arena, so each device
-    holds only its heads/devices slice of every block); closed-form
+    mesh width (serving/mesh.py shards the arena's last axis by heads,
+    so each device holds only its heads/devices slice of every block);
+    closed-form
     arithmetic over shapes, no device touch."""
     from deeplearning4j_tpu.ops import lowprec
 

@@ -34,10 +34,12 @@ Sharding scheme — chosen for the BYTE-identity bar, not peak FLOPs:
     per-device block bytes drop to 1/d (ops/memory.kv_block_bytes
     ``devices=``) and the same per-device HBM budget admits ~d× blocks.
 
-  * arena: the global ``[L, n_blocks+1, bt, H, hd]`` buffers shard on
-    the HEAD axis (ARENA_SPEC); each device owns a local
-    ``[L, n_blocks+1, bt, H/d, hd]`` pool including its own slice of
-    trash block 0. Block tables, tok/pos/keys/temps and params are
+  * arena: the global ``[L, n_blocks+1, bt, H*hd]`` buffers (a token's
+    heads side by side, paged.py's one stored layout) shard on that
+    last axis (ARENA_SPEC), where a device's heads are contiguous; each
+    device owns a local ``[L, n_blocks+1, bt, (H/d)*hd]`` pool including
+    its own slice of every layer's trash block 0. Block tables,
+    tok/pos/keys/temps and params are
     replicated, so every device executes the identical scatter indices
     — write-then-gather and the zero-retrace contract survive
     unchanged, and ALL host-side scheduling (BlockArena, PrefixCache,
@@ -86,9 +88,9 @@ from deeplearning4j_tpu.parallel.tensor_parallel import local_head_columns
 from deeplearning4j_tpu.serving.decode import _sample_step
 from deeplearning4j_tpu.serving.paged import PagedDecoder, chunked_attention
 
-# the arena's k/v buffers shard on their HEAD axis (dim 3 of
-# [L, n_blocks+1, bt, H, hd]); everything else the tick touches is
-# replicated
+# the arena's k/v buffers shard on their last axis (dim 3 of
+# [L, n_blocks+1, bt, H*hd]: heads side by side, a device's H/d heads
+# contiguous); everything else the tick touches is replicated
 ARENA_SPEC = P(None, None, None, MODEL_AXIS)
 
 
@@ -114,20 +116,23 @@ def mesh_paged_decode_step(params, arena, tok, pos, tables,
                            axis: str = MODEL_AXIS):
     """Per-device decode tick body (runs INSIDE shard_map): the
     head-local mirror of paged.paged_decode_step, byte-for-byte per
-    head. ``arena`` k/v arrive as local shards [L, B, bt, H/d, hd];
+    head. ``arena`` k/v arrive as local shards [L, B, bt, (H/d)*hd]
+    and are carried through the layer scan as [L * B, bt, (H/d)*hd],
+    layer ``l`` at rows ``l * B + block``, as the single device's are;
     params and every index input are replicated, so the scatter/gather
     indices are identical on all devices."""
     cdt = cfg.compute_dtype
     s = tok.shape[0]
     hd = cfg.d_model // cfg.n_heads
     hl = cfg.n_heads // n_devices
-    bt = arena["k"].shape[2]
+    n_layers, rows, bt, width = arena["k"].shape
     h = (params["embed"][tok] + params["pos"][pos])[:, None, :].astype(cdt)
     wb = jnp.take_along_axis(tables, (pos // bt)[:, None], axis=1)[:, 0]
     off = pos % bt
 
-    def block(h, xs):
-        bp, ck, cv = xs  # ck/cv: local [B, bt, H/d, hd]
+    def block(carry, xs):
+        h, ck, cv = carry  # ck/cv: the local arena [L * B, bt, (H/d)*hd]
+        bp, base = xs      # base: the layer's first row, l * B
         c = lambda a: a.astype(cdt)
         x = _ln(h, c(bp["ln1_g"]), c(bp["ln1_b"]))
         # column-parallel q/k/v over the replicated weights: exact —
@@ -137,18 +142,18 @@ def mesh_paged_decode_step(params, arena, tok, pos, tables,
             n_devices=n_devices, axis=axis)).reshape(s, hl, hd)
         k1 = (x @ local_head_columns(
             c(bp["Wk"]), num_heads=cfg.n_heads, head_dim=hd,
-            n_devices=n_devices, axis=axis)).reshape(s, hl, hd)
+            n_devices=n_devices, axis=axis)).reshape(s, width)
         v1 = (x @ local_head_columns(
             c(bp["Wv"]), num_heads=cfg.n_heads, head_dim=hd,
-            n_devices=n_devices, axis=axis)).reshape(s, hl, hd)
-        ck = ck.at[wb, off].set(k1.astype(ck.dtype))
-        cv = cv.at[wb, off].set(v1.astype(cv.dtype))
+            n_devices=n_devices, axis=axis)).reshape(s, width)
+        ck = ck.at[base + wb, off].set(k1.astype(ck.dtype))
+        cv = cv.at[base + wb, off].set(v1.astype(cv.dtype))
         # per-head attention over the LOCAL arena shard — the single
         # device's own function (paged.chunked_attention), just over H/d
         # heads: per-head math is device-independent (the einsums
         # contract hd/T only and the running softmax is per head), and
         # pos is replicated, so every device loops to the same bound
-        att_l = chunked_attention(q, ck, cv, tables, pos)
+        att_l = chunked_attention(q, ck, cv, tables + base, pos)
         # reassemble the full [S, H, hd] head outputs by CONCATENATION
         # (axis-index order == head order) — not a psum: Megatron's
         # row-parallel Wo would reorder the contraction's float sum and
@@ -161,12 +166,16 @@ def mesh_paged_decode_step(params, arena, tok, pos, tables,
         x = _ln(h, c(bp["ln2_g"]), c(bp["ln2_b"]))
         h = h + jax.nn.gelu(x @ c(bp["W1"]) + c(bp["b1"])) @ c(bp["W2"]) \
             + c(bp["b2"])
-        return h, (ck, cv)
+        return (h, ck, cv), None
 
-    h, (ks, vs) = lax.scan(block, h, (params["blocks"], arena["k"],
-                                      arena["v"]))
+    flat = lambda a: a.reshape(n_layers * rows, bt, width)
+    bases = jnp.arange(n_layers, dtype=tables.dtype) * rows
+    (h, ck, cv), _ = lax.scan(
+        block, (h, flat(arena["k"]), flat(arena["v"])),
+        (params["blocks"], bases))
     h = _ln(h[:, 0].astype(jnp.float32), params["lnf_g"], params["lnf_b"])
-    return {"k": ks, "v": vs}, h @ params["embed"].T
+    return {"k": ck.reshape(arena["k"].shape),
+            "v": cv.reshape(arena["v"].shape)}, h @ params["embed"].T
 
 
 # jitted sharded programs shared across decoder instances (the
@@ -230,8 +239,7 @@ def _mesh_admit_for(cfg: TransformerConfig, width: int, block_tokens: int,
     if fn is not None:
         return fn
     m = cfg.max_len // block_tokens
-    hd = cfg.d_model // cfg.n_heads
-    hl = cfg.n_heads // nd
+    wl = cfg.d_model // nd  # a device's heads, side by side
 
     def device_admit(params, arena, window, write_table):
         # the FULL prefill runs replicated on every device — the
@@ -240,12 +248,12 @@ def _mesh_admit_for(cfg: TransformerConfig, width: int, block_tokens: int,
         # head-slice; only the scatter is head-local
         c1, _ = prefill_cache(params, window, cfg)
         kb = c1["k"][:, 0].reshape(cfg.n_layers, m, block_tokens,
-                                   cfg.n_heads, hd)
+                                   cfg.d_model)
         vb = c1["v"][:, 0].reshape(cfg.n_layers, m, block_tokens,
-                                   cfg.n_heads, hd)
+                                   cfg.d_model)
         idx = lax.axis_index(MODEL_AXIS)
-        kb = lax.dynamic_slice_in_dim(kb, idx * hl, hl, axis=3)
-        vb = lax.dynamic_slice_in_dim(vb, idx * hl, hl, axis=3)
+        kb = lax.dynamic_slice_in_dim(kb, idx * wl, wl, axis=3)
+        vb = lax.dynamic_slice_in_dim(vb, idx * wl, wl, axis=3)
         ak = arena["k"].at[:, write_table].set(kb.astype(arena["k"].dtype))
         av = arena["v"].at[:, write_table].set(vb.astype(arena["v"].dtype))
         return {"k": ak, "v": av}
@@ -267,14 +275,14 @@ def _mesh_import_for(cfg: TransformerConfig, block_tokens: int,
     fn = _MESH_IMPORT_CACHE.get(key)
     if fn is not None:
         return fn
-    hl = cfg.n_heads // nd
+    wl = cfg.d_model // nd
 
     def device_imp(arena, kb, vb, table):
-        # handed-off blocks arrive dense [L, tw, bt, H, hd]; each device
-        # adopts its head slice (unadopted entries scatter into trash 0)
+        # handed-off blocks arrive dense [L, tw, bt, H*hd]; each device
+        # adopts its heads' columns (unadopted entries scatter into trash 0)
         idx = lax.axis_index(MODEL_AXIS)
-        kb = lax.dynamic_slice_in_dim(kb, idx * hl, hl, axis=3)
-        vb = lax.dynamic_slice_in_dim(vb, idx * hl, hl, axis=3)
+        kb = lax.dynamic_slice_in_dim(kb, idx * wl, wl, axis=3)
+        vb = lax.dynamic_slice_in_dim(vb, idx * wl, wl, axis=3)
         ak = arena["k"].at[:, table].set(kb.astype(arena["k"].dtype))
         av = arena["v"].at[:, table].set(vb.astype(arena["v"].dtype))
         return {"k": ak, "v": av}

@@ -17,13 +17,18 @@ between ticks.
 
 Layout and invariants:
 
-  * arena k/v: ``[L, n_blocks+1, block_tokens, H, hd]``; physical block
-    0 is a TRASH block that is never allocated — inactive lanes and the
+  * arena k/v: ``[L, n_blocks+1, block_tokens, H*hd]``, a token's heads
+    side by side in one row (the order the chip stores is then the one
+    the scatter and the gathers want: no program copies or re-lays the
+    arena, tests/test_chip_compile.py). Physical block 0 OF EVERY LAYER
+    is a TRASH block that is never allocated — inactive lanes and the
     unallocated tail of every table point at it, so the tick's scatter
     always has somewhere harmless to write and the gather somewhere
     harmless to read (the ``arange <= pos`` mask zeroes its softmax
     weight exactly, the same argument decode.py makes for garbage pad
-    K/V).
+    K/V). The tick carries the arena through its layer scan as
+    ``[L * (n_blocks+1), block_tokens, H*hd]`` and layer ``l`` reaches
+    its blocks at rows ``l * (n_blocks+1) + block``.
   * the tick reads each lane's blocks through its table a chunk of
     whole blocks at a time (``arena[tables[:, chunk]]``, in the arena's
     dtype) and folds each chunk into an online softmax, looping only as
@@ -73,8 +78,10 @@ fixed-slot pool remains the ``DL4J_TPU_SERVE_KV_BLOCK=0`` fallback.
 
 What is cached is the model's to say (ops/memory.cache_needs): how many
 layers hold keys and values, with how many KV heads of what size (the
-arena pages exactly those: ``[kv_layers, n_blocks+1, bt, kv_heads, hd]``,
-or one ``[n_blocks+1, bt, kv_heads * hd]`` a layer),
+arena pages exactly those: ``[kv_layers, n_blocks+1, bt, kv_heads * hd]``
+where the layers are alike and scanned, or one
+``[n_blocks+1, bt, kv_heads * hd]`` a layer where they differ and are
+unrolled),
 and which per-lane recurrent state it keeps besides. The GPT-2-shaped
 TransformerLM is the instance with K and V in every layer for every head
 and no state; its tick and admit bodies are the ones in this file. A
@@ -284,6 +291,13 @@ def paged_decode_step(params, arena, tok, pos, tables,
     scatter into trash block 0, whose content is never visible under
     the causal mask.
 
+    ``arena`` k/v are ``[L, n_blocks+1, bt, H*hd]``. The layer scan does
+    not scan over them: it CARRIES them beside ``h``, viewed as
+    ``[L * (n_blocks+1), bt, H*hd]`` (a bitcast), and layer ``l`` writes
+    and reads at rows ``l * (n_blocks+1) + block``, so the program
+    updates the donated buffers where they lie and nothing of the
+    arena's or of a layer's size is sliced, copied or re-laid.
+
     ``attention`` picks the per-layer attention body ('kernel' streams
     blocks through the pallas online-softmax kernel; 'gather' is the
     chunked XLA loop; None resolves via attention_path at trace time).
@@ -293,39 +307,49 @@ def paged_decode_step(params, arena, tok, pos, tables,
     cdt = cfg.compute_dtype
     s = tok.shape[0]
     hd = cfg.d_model // cfg.n_heads
-    bt = arena["k"].shape[2]
+    n_layers, rows, bt, width = arena["k"].shape
     if attention is None:
         attention = attention_path(cfg, bt)
     h = (params["embed"][tok] + params["pos"][pos])[:, None, :].astype(cdt)
     wb = jnp.take_along_axis(tables, (pos // bt)[:, None], axis=1)[:, 0]
     off = pos % bt
 
-    def block(h, xs):
-        bp, ck, cv = xs  # ck/cv: [B, bt, H, hd]
+    def block(carry, xs):
+        # ck/cv: the WHOLE arena [L * rows, bt, H * hd], carried: a layer
+        # scanned over as xs/ys is sliced out of the stack and written
+        # back, 63 MB each way a layer at the serve cell's size
+        h, ck, cv = carry
+        bp, base = xs  # base: the layer's first row, l * rows
         c = lambda a: a.astype(cdt)
         x = _ln(h, c(bp["ln1_g"]), c(bp["ln1_b"]))
         q = (x @ c(bp["Wq"])).reshape(s, cfg.n_heads, hd)
-        k1 = (x @ c(bp["Wk"])).reshape(s, cfg.n_heads, hd)
-        v1 = (x @ c(bp["Wv"])).reshape(s, cfg.n_heads, hd)
+        k1 = (x @ c(bp["Wk"])).reshape(s, width)
+        v1 = (x @ c(bp["Wv"])).reshape(s, width)
         with jax.named_scope("tick.scatter"):
-            ck = ck.at[wb, off].set(k1.astype(ck.dtype))
-            cv = cv.at[wb, off].set(v1.astype(cv.dtype))
+            ck = ck.at[base + wb, off].set(k1.astype(ck.dtype))
+            cv = cv.at[base + wb, off].set(v1.astype(cv.dtype))
         if attention == "kernel":
             with jax.named_scope("tick.attend"):
-                att = pallas_paged.paged_attention(q, ck, cv, tables, pos)
+                heads = lambda a: a.reshape(a.shape[:2] + (cfg.n_heads, hd))
+                att = pallas_paged.paged_attention(
+                    q, heads(ck), heads(cv), tables + base, pos)
         else:
-            att = chunked_attention(q, ck, cv, tables, pos)
+            att = chunked_attention(q, ck, cv, tables + base, pos)
         att = att.reshape(s, 1, cfg.d_model)
         h = h + att.astype(cdt) @ c(bp["Wo"])
         x = _ln(h, c(bp["ln2_g"]), c(bp["ln2_b"]))
         h = h + jax.nn.gelu(x @ c(bp["W1"]) + c(bp["b1"])) @ c(bp["W2"]) \
             + c(bp["b2"])
-        return h, (ck, cv)
+        return (h, ck, cv), None
 
-    h, (ks, vs) = lax.scan(block, h, (params["blocks"], arena["k"],
-                                      arena["v"]))
+    flat = lambda a: a.reshape(n_layers * rows, bt, width)
+    bases = jnp.arange(n_layers, dtype=tables.dtype) * rows
+    (h, ck, cv), _ = lax.scan(
+        block, (h, flat(arena["k"]), flat(arena["v"])),
+        (params["blocks"], bases))
     h = _ln(h[:, 0].astype(jnp.float32), params["lnf_g"], params["lnf_b"])
-    return {"k": ks, "v": vs}, h @ params["embed"].T
+    return {"k": ck.reshape(arena["k"].shape),
+            "v": cv.reshape(arena["v"].shape)}, h @ params["embed"].T
 
 
 def decode_body(cfg, attention: Optional[str] = None):
@@ -412,7 +436,6 @@ def _paged_admit_for(cfg: TransformerConfig, width: int, block_tokens: int):
         _PAGED_ADMIT_CACHE[key] = admit
         return admit
     m = cfg.max_len // block_tokens
-    hd = cfg.d_model // cfg.n_heads
 
     def admit(params, arena, window, write_table):
         # window: [1, width]; prefill pads its K/V out to max_len, so
@@ -424,9 +447,9 @@ def _paged_admit_for(cfg: TransformerConfig, width: int, block_tokens: int):
             c1, _ = prefill_cache(params, window, cfg)
         with jax.named_scope("admit.scatter"):
             kb = c1["k"][:, 0].reshape(cfg.n_layers, m, block_tokens,
-                                       cfg.n_heads, hd)
+                                       cfg.d_model)
             vb = c1["v"][:, 0].reshape(cfg.n_layers, m, block_tokens,
-                                       cfg.n_heads, hd)
+                                       cfg.d_model)
             ak = arena["k"].at[:, write_table].set(
                 kb.astype(arena["k"].dtype))
             av = arena["v"].at[:, write_table].set(
@@ -455,17 +478,17 @@ def _prefix_export_for(cfg: TransformerConfig, width: int,
     if fn is not None:
         return fn
     m = cfg.max_len // block_tokens
-    hd = cfg.d_model // cfg.n_heads
 
     def export(params, window):
         # the cast to the arena dtype happens IN-program, the same
         # convert the admit scatter applies — exported bytes must equal
-        # what the importer's own prefill would have written
+        # what the importer's own prefill would have written. Blocks
+        # leave as the arena holds them, [L, m, bt, H*hd]
         c1, _ = prefill_cache(params, window, cfg)
         kb = c1["k"][:, 0].reshape(cfg.n_layers, m, block_tokens,
-                                   cfg.n_heads, hd)
+                                   cfg.d_model)
         vb = c1["v"][:, 0].reshape(cfg.n_layers, m, block_tokens,
-                                   cfg.n_heads, hd)
+                                   cfg.d_model)
         return kb.astype(dtype), vb.astype(dtype)
 
     fn = jax.jit(export)
@@ -481,6 +504,7 @@ def _prefix_import_for(cfg: TransformerConfig, block_tokens: int,
         return fn
 
     def imp(arena, kb, vb, table):
+        # kb/vb [L, table_width, bt, H*hd], as the arena holds blocks;
         # unadopted table entries point at trash block 0 and scatter
         # zeros there — invisible under the causal mask, the same
         # argument the admit path's write_table makes
@@ -869,18 +893,27 @@ class PagedDecoder:
         an f32 model just works. A model with recurrent layers gets its
         state pool here too: for each leaf it names, one ``[lanes,
         *shape]`` buffer a layer, donated with k and v through every
-        tick and admission and so rewritten in place."""
+        tick and admission and so rewritten in place.
+
+        A row of a block is one token's heads side by side,
+        ``kv_heads * head_dim`` wide. K and V are each ONE buffer
+        ``[kv_layers, n_blocks+1, bt, kv_heads * head_dim]`` where the
+        tick scans over layers that are alike (it carries the buffer
+        and addresses layer ``l`` by row), block 0 of every layer that
+        layer's trash; a model that asks for it (``kv_per_layer``: its
+        layers differ and are unrolled) gets one ``[n_blocks+1, bt,
+        kv_heads * head_dim]`` a layer."""
         needs = opsmem.cache_needs(self.cfg)
-        shape = (needs.kv_layers, self.n_blocks + 1, self.block_tokens,
-                 needs.kv_heads, needs.head_dim)
+        layer = (self.n_blocks + 1, self.block_tokens,
+                 needs.kv_heads * needs.head_dim)
         zeros = lambda shape: jnp.zeros(shape, self.kv_dtype,
                                         device=self._arena_sharding)
         if needs.kv_per_layer:
-            flat = shape[1:3] + (needs.kv_heads * needs.head_dim,)
-            arena = {"k": tuple(zeros(flat) for _ in range(shape[0])),
-                     "v": tuple(zeros(flat) for _ in range(shape[0]))}
+            arena = {"k": tuple(zeros(layer) for _ in range(needs.kv_layers)),
+                     "v": tuple(zeros(layer) for _ in range(needs.kv_layers))}
         else:
-            arena = {"k": zeros(shape), "v": zeros(shape)}
+            stacked = (needs.kv_layers,) + layer
+            arena = {"k": zeros(stacked), "v": zeros(stacked)}
         for leaf in needs.state:
             arena[leaf.name] = tuple(
                 jnp.zeros((self.lanes,) + leaf.shape, leaf.dtype)
@@ -1300,8 +1333,10 @@ class PagedDecoder:
         kb, vb = _prefix_export_for(cfg, width, bt, self.kv_dtype)(
             self._infer_params, jnp.asarray(buf))
         self.stats.record_prefix_export()
-        return (digests,
-                np.asarray(kb[:, :wb0]), np.asarray(vb[:, :wb0]))
+        # the handoff's format names the heads; the arena's row does not
+        wire = lambda a: np.asarray(a[:, :wb0]).reshape(
+            cfg.n_layers, wb0, bt, cfg.n_heads, hd)
+        return digests, wire(kb), wire(vb)
 
     def import_prefix(self, digests, k_blocks, v_blocks,
                       timeout_s: float = 60.0) -> int:
@@ -1366,11 +1401,13 @@ class PagedDecoder:
                 fut.set_result(0)
                 return
             cfg = self.cfg
-            hd = cfg.d_model // cfg.n_heads
             table = np.zeros((self.table_width,), np.int32)
-            kpad = np.zeros((cfg.n_layers, self.table_width,
-                             self.block_tokens, cfg.n_heads, hd),
-                            self.kv_dtype)
+            # blocks arrive [L, n, bt, H, hd] and are scattered as the
+            # arena holds them, a token's heads side by side
+            kb, vb = (a.reshape(a.shape[:3] + (cfg.d_model,))
+                      for a in (kb, vb))
+            kpad = np.zeros((cfg.n_layers, self.table_width)
+                            + kb.shape[2:], self.kv_dtype)
             vpad = np.zeros_like(kpad)
             for t, j in enumerate(range(start, start + avail)):
                 table[j] = fresh[t]

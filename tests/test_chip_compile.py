@@ -103,47 +103,115 @@ def test_flash_forward_compiles_for_v5e_under_its_name(one_chip, rows,
     assert "flash_fwd/pallas_call" in calls[0]
 
 
-# heads of the cells' widths: 590m 12 of 128, 1.3b 16 of 128
-@pytest.mark.parametrize("heads", [12, 16])
-def test_tick_attention_reads_the_arena_as_stored_on_v5e(one_chip, heads,
-                                                         no_compile_cache):
-    """The serve cell's tick (40 lanes, 1,280 blocks of 16, bf16 arena), two
-    layers of it: the chip's compiler gives both attention products to the
-    matrix unit and makes no float32 copy of a gathered chunk. A one-row
-    product it rewrites as multiply-and-reduce over such a copy, which was
-    38% of the tick (PERF.md section 6, PR 27): paged._exact_rows is what
-    keeps it from that, and this is what watches it."""
+def _compile_serve_program(one_chip, heads, n_layers, program="tick"):
+    """The serve cell's tick, or its admission at width 512, compiled for
+    the described chip at the cell's shape but for the depth: 40 lanes,
+    1,280 blocks of 16, bf16 arena, heads of 128 (590m 12 of them, 1.3b
+    16). Returns (compiled, the arena's shapes, lanes, block_tokens)."""
     from deeplearning4j_tpu.models.transformer import (
         TransformerConfig,
         init_params,
     )
     from deeplearning4j_tpu.serving import paged
 
-    cfg = TransformerConfig(vocab_size=1024, d_model=128 * heads, n_layers=2,
-                            n_heads=heads, d_ff=512 * heads, max_len=2048,
+    cfg = TransformerConfig(vocab_size=1024, d_model=128 * heads,
+                            n_layers=n_layers, n_heads=heads,
+                            d_ff=512 * heads, max_len=2048,
                             dtype_policy="performance")
-    lanes, n_blocks, bt = 40, 1280, 16
+    lanes, n_blocks, bt, width = 40, 1280, 16, 512
     on = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
     params = jax.tree.map(on, jax.eval_shape(lambda: init_params(cfg)))
     arena = jax.tree.map(on, _arena(cfg, n_blocks, bt))
     arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                     sharding=one_chip)
+    m = cfg.max_len // bt
     with jax.enable_x64(False):
-        text = paged._paged_tick_for(cfg, bt).lower(
-            params, arena, arg((lanes,), jnp.int32), arg((lanes,), jnp.int32),
-            arg((lanes, cfg.max_len // bt), jnp.int32),
-            arg((lanes, 2), jnp.uint32), arg((lanes,), jnp.float32)
-        ).compile().as_text()
-    paged._PAGED_TICK_CACHE.pop((cfg, bt, "gather", 1), None)
+        if program == "tick":
+            compiled = paged._paged_tick_for(cfg, bt).lower(
+                params, arena, arg((lanes,), jnp.int32),
+                arg((lanes,), jnp.int32), arg((lanes, m), jnp.int32),
+                arg((lanes, 2), jnp.uint32), arg((lanes,), jnp.float32)
+            ).compile()
+            paged._PAGED_TICK_CACHE.pop((cfg, bt, "gather", 1), None)
+        else:
+            compiled = paged._paged_admit_for(cfg, width, bt).lower(
+                params, arena, arg((1, width), jnp.int32),
+                arg((m,), jnp.int32)).compile()
+            paged._PAGED_ADMIT_CACHE.pop((cfg, width, bt), None)
+    return compiled, arena, lanes, bt
+
+
+@pytest.mark.parametrize("heads", [12, 16])
+def test_tick_attention_reads_the_arena_as_stored_on_v5e(one_chip, heads,
+                                                         no_compile_cache):
+    """The serve cell's tick, two layers of it: the chip's compiler gives
+    both attention products to the matrix unit and makes no float32 copy
+    of a gathered chunk. A one-row product it rewrites as
+    multiply-and-reduce over such a copy, which was 38% of the tick
+    (PERF.md section 6, PR 27): paged._exact_rows is what keeps it from
+    that, and this is what watches it."""
+    from deeplearning4j_tpu.serving import paged
+
+    compiled, _, lanes, bt = _compile_serve_program(one_chip, heads, 2)
+    text = compiled.as_text()
     cols = paged.ATTN_CHUNK_COLS
     chunk = lanes * cols * bt * heads * 128
-    assert f"bf16[{lanes * cols},{bt},{heads},128]" in text   # the gathers
+    assert f"bf16[{lanes * cols},{bt},{heads * 128}]" in text  # the gathers
     sizes = {int(np.prod([int(d) for d in dims.split(",")]))
              for dims in re.findall(r"f32\[([\d,]+)\]", text)}
     assert chunk not in sizes and chunk // 2 not in sizes
     products = [ln for ln in text.splitlines()
                 if " convolution(" in ln and "tick.attend" in ln]
     assert len(products) == 2, products
+
+
+def _makes_arena_sized(text, arena):
+    """Instructions of a compiled program, in any computation (ENTRY, a
+    loop's body, a fusion's), that YIELD a buffer as large as the whole
+    arena leaf ``arena`` [L, rows, bt, width] or as one layer of it by
+    copying, re-laying or slicing: (opcode, shape) pairs. A fusion is
+    named after what it holds (``copy_bitcast_fusion``,
+    ``copy_dynamic-update-slice_fusion``, ``constant_dynamic-slice_fusion``
+    are what the parent's tick spent half its time in) and the in-place
+    scatter of a tick's 40 rows is a fusion too, so a fusion counts by
+    what is inside it: its own computation is read like any other."""
+    dtype = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(arena.dtype).name]
+    whole = int(np.prod(arena.shape))
+    sizes = {whole, whole // arena.shape[0]}
+    found = []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
+                     r"([a-z][\w\-]*)\(", ln)
+        if not m or m.group(1) != dtype or m.group(3) not in (
+                "copy", "transpose", "dynamic-slice", "slice",
+                "dynamic-update-slice"):
+            continue
+        if int(np.prod([int(d) for d in m.group(2).split(",")])) in sizes:
+            found.append((m.group(3), m.group(2)))
+    return found
+
+
+@pytest.mark.parametrize("program", ["tick", "admit"])
+@pytest.mark.parametrize("heads", [12, 16])
+def test_serve_programs_write_into_the_arena_where_it_lies_on_v5e(
+        one_chip, heads, program, no_compile_cache):
+    """The serve cell's tick and one admission width, three scanned layers:
+    the arena comes in donated and leaves aliased, and NO instruction of
+    the compiled program, in the loop's body as little as in ENTRY, copies,
+    transposes or slices a buffer of the arena's size or of one layer's.
+    As ``[L, blocks, 16, heads, 128]`` scanned over as xs/ys the tick
+    sliced each layer's K and V out of the stack, re-laid them, wrote them
+    back and re-laid the whole stack once a tick: seven of its ten longest
+    device operations, over half of the device's time (ROADMAP S15,
+    PERF.md section 6, PR 29)."""
+    compiled, arena, _, _ = _compile_serve_program(one_chip, heads, 3,
+                                                   program)
+    assert not _makes_arena_sized(compiled.as_text(), arena["k"])
+    arena_bytes = sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
+                      for a in jax.tree.leaves(arena))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= arena_bytes
+    assert mem.temp_size_in_bytes < arena_bytes
 
 
 def test_hybrid_tick_rewrites_its_state_in_place_on_v5e(one_chip,
@@ -253,9 +321,8 @@ def test_train_step_holds_the_scope_names():
 
 
 def _arena(cfg, n_blocks, bt):
-    hd = cfg.d_model // cfg.n_heads
     leaf = jax.ShapeDtypeStruct(
-        (cfg.n_layers, n_blocks + 1, bt, cfg.n_heads, hd), cfg.compute_dtype)
+        (cfg.n_layers, n_blocks + 1, bt, cfg.d_model), cfg.compute_dtype)
     return {"k": leaf, "v": leaf}
 
 

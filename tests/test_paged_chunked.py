@@ -12,7 +12,14 @@ arena's dtype, chunk by chunk up to the longest live lane, one program.
   * the bound follows LIVE lanes: the release resets the lane's
     position, so the next tick's ``kv_read`` falls;
   * one tick program whatever the live lengths (no retrace);
-  * a k = 2 scanned tick equals two k = 1 ticks across a chunk edge.
+  * a k = 2 scanned tick equals two k = 1 ticks across a chunk edge;
+  * the arena is ONE buffer a leaf, [L, blocks+1, bt, H*hd], carried
+    through the layer scan and addressed by row (ISSUE 29): a tick
+    writes the rows (layer, block, offset) of its lanes and each
+    layer's trash row and nothing else, a lane on a layer's last block
+    does not reach the next layer's trash, and a run through export,
+    import, admissions, ticks and a preemption is decode_step_slots'
+    tokens, on one device and on the serving mesh.
 
 Reference anchor: none in the reference (one record per route callback,
 dl4j-streaming/.../routes/DL4jServeRouteBuilder.java); provenance is the
@@ -129,7 +136,7 @@ def _tick_inputs(cfg, lanes, pos, seed=0):
     m = cfg.max_len // BT
     hd = cfg.d_model // cfg.n_heads
     rng = np.random.default_rng(seed)
-    shape = (cfg.n_layers, lanes * m + 1, BT, cfg.n_heads, hd)
+    shape = (cfg.n_layers, lanes * m + 1, BT, cfg.n_heads * hd)
     arena = {"k": jnp.asarray(rng.normal(size=shape), cfg.compute_dtype),
              "v": jnp.asarray(rng.normal(size=shape), cfg.compute_dtype)}
     tok = jnp.asarray(rng.integers(0, cfg.vocab_size, lanes), jnp.int32)
@@ -354,3 +361,92 @@ def test_k2_tick_equals_two_k1_ticks_across_a_chunk_edge():
     for leaf in ("k", "v"):
         assert np.array_equal(np.asarray(a1[leaf], np.float32),
                               np.asarray(a2[leaf], np.float32))
+
+
+# ---------------------------------------------------------------------------
+# (g) one buffer, addressed by row: a layer never writes into its neighbour
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("policy", ["strict", "performance"],
+                         ids=["f32", "bf16"])
+def test_a_tick_writes_its_lanes_rows_of_every_layer_and_nothing_else(policy):
+    cfg = _cfg(n_layers=3, dtype_policy=policy)
+    n_blocks, lanes = 6, 3
+    m = cfg.max_len // BT
+    sentinel = 7.0
+    shape = (cfg.n_layers, n_blocks + 1, BT, cfg.d_model)
+    arena = {"k": jnp.full(shape, sentinel, cfg.compute_dtype),
+             "v": jnp.full(shape, sentinel, cfg.compute_dtype)}
+    # lane 0 writes the LAST block of every layer, the row before the next
+    # layer's trash block in the carried buffer; lane 1 a block in the
+    # middle; lane 2 is dead (table of trash, position 0)
+    tables = np.zeros((lanes, m), np.int32)
+    tables[0, :2] = [3, n_blocks]
+    tables[1, :1] = [2]
+    pos = np.asarray([BT + 1, 2, 0], np.int32)
+    tok = jnp.asarray([5, 11, 0], jnp.int32)
+    out, logits = jax.jit(
+        lambda a: paged.paged_decode_step(
+            init_params(cfg), a, tok, jnp.asarray(pos), jnp.asarray(tables),
+            cfg, attention="gather"))(arena)
+    assert np.isfinite(np.asarray(logits)).all()
+    wrote = {(n_blocks, 1), (2, 2), (0, 0)}        # (block, offset) a layer
+    want = {(l, b, t) for l in range(cfg.n_layers) for b, t in wrote}
+    for leaf in ("k", "v"):
+        assert out[leaf].shape == shape and out[leaf].dtype == arena[leaf].dtype
+        moved = np.asarray(out[leaf], np.float32) != sentinel
+        # every element of a written row is new, every other row untouched
+        rows = {tuple(int(i) for i in idx)
+                for idx in np.argwhere(moved.any(axis=-1))}
+        assert rows == want, (leaf, sorted(rows ^ want))
+        assert all(moved[l, b, t].all() for l, b, t in want)
+
+
+def _decoder(kind, lm, **kw):
+    if kind == "mesh":
+        from deeplearning4j_tpu.serving.mesh import MeshPagedDecoder
+
+        return MeshPagedDecoder(lm, devices=2, **kw)
+    return paged.PagedDecoder(lm, **kw)
+
+
+@pytest.mark.parametrize("kind", ["one-device", "mesh"])
+def test_import_admit_tick_preempt_equals_the_fixed_slot_decoder(kind):
+    """Every program that writes the arena, in one run: blocks exported by
+    one decoder and imported by another (the handoff names the heads,
+    [L, n, bt, H, hd]; the arena does not), an admission that hits them,
+    ticks, and a preemption that recomputes: greedy tokens are those of
+    serving/decode.decode_step_slots' fixed-slot pool, byte for byte."""
+    from deeplearning4j_tpu.serving.decode import ContinuousDecoder
+
+    cfg = _cfg(max_len=32)
+    lm = TransformerLM(cfg)
+    hd = cfg.d_model // cfg.n_heads
+    shared = [2, 4, 6, 8, 10, 12, 14, 16, 3]       # two full blocks of 4
+    prompts = (shared + [5], [1, 1, 1, 1], shared + [9, 7])
+    d0 = ContinuousDecoder(lm, slots=1)
+    try:
+        bases = [d0.generate(np.asarray([p]), 18, temperature=0.0)[0]
+                 for p in prompts]
+    finally:
+        d0.stop()
+    src = paged.PagedDecoder(lm, block_tokens=BT, n_blocks=16)
+    try:
+        digests, kb, vb = src.export_prefix(prompts[0], 18)
+    finally:
+        src.stop()
+    assert kb.shape == vb.shape == (cfg.n_layers, 2, BT, cfg.n_heads, hd)
+    # 12 blocks of 4 cannot hold three sequences of 22 to 29 tokens
+    dec = _decoder(kind, lm, block_tokens=BT, n_blocks=12)
+    try:
+        assert dec._arena["k"].shape == (cfg.n_layers, 13, BT, cfg.d_model)
+        assert dec.import_prefix(digests, kb, vb) == 2
+        futs = [dec.submit(list(p), 18, temperature=0.0) for p in prompts]
+        outs = [f.result(timeout=240) for f in futs]
+        assert dec.stats.prefix_hits >= 2
+        assert dec.stats.preemptions >= 1
+    finally:
+        dec.stop()
+    for base, out in zip(bases, outs):
+        np.testing.assert_array_equal(base, out)

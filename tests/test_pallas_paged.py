@@ -150,7 +150,7 @@ class TestPagedDecodeStep:
         s, m = 3, cfg.max_len // bt
         hd = cfg.d_model // cfg.n_heads
         rng = np.random.default_rng(7)
-        shape = (cfg.n_layers, n_blocks + 1, bt, cfg.n_heads, hd)
+        shape = (cfg.n_layers, n_blocks + 1, bt, cfg.n_heads * hd)
         arena = {
             "k": jnp.asarray(rng.standard_normal(shape), cfg.compute_dtype),
             "v": jnp.asarray(rng.standard_normal(shape), cfg.compute_dtype),
@@ -298,7 +298,7 @@ class TestPagedGate:
         lm = tiny_lm()
         cfg = lm.cfg
         hd = cfg.d_model // cfg.n_heads
-        shape = (cfg.n_layers, 5, 8, cfg.n_heads, hd)
+        shape = (cfg.n_layers, 5, 8, cfg.n_heads * hd)
         arena = {"k": jnp.zeros(shape, cfg.compute_dtype),
                  "v": jnp.zeros(shape, cfg.compute_dtype)}
         with pytest.raises(ValueError, match="Only interpret mode"):
