@@ -367,7 +367,7 @@ class MeshPagedDecoder(PagedDecoder):
         # placement is P() for the whole tree — one HBM copy per device,
         # no resharded second tree
         self._infer_params = jax.device_put(
-            self.lm.params, NamedSharding(self.serving_mesh, P()))
+            self._infer_params, NamedSharding(self.serving_mesh, P()))
         super()._start_worker()
 
     def _build_tick(self, k: int):

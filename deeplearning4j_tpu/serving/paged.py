@@ -365,6 +365,31 @@ def decode_body(cfg, attention: Optional[str] = None):
         params, arena, tok, pos, tables, cfg, attention=attention)
 
 
+def serving_view(params, cfg):
+    """The model's parameters as the serving programs read them: the same
+    tree, in which every leaf of ``params["blocks"]`` (what the tick, the
+    prefill and the verify cast to ``cfg.compute_dtype`` where they use
+    it, ``a.astype(cdt)``) is held already cast, by ONE jitted call. A
+    cast of a leaf that has the dtype is nothing, so the programs are the
+    ones they were and the values that reach their products are the same
+    bits: the float32 masters are rounded once here and not by every tick
+    and every admission. Everything else (``embed``, ``pos``, ``lnf_g``,
+    ``lnf_b``: read in float32) is the SAME buffer as in ``params``.
+
+    It adapts on the leaves' dtypes alone: where none differs from the
+    compute dtype (a model that holds its weights in it, models/hybrid.py;
+    a float32 policy) or the tree has no ``blocks``, the view IS
+    ``params``, the same object, and no program runs. A snapshot, as the
+    alias it replaces was: a decoder is built over the weights it will
+    serve, and a model swapped through the registry gets a new decoder."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    blocks = params.get("blocks")
+    if all(a.dtype == cdt for a in jax.tree_util.tree_leaves(blocks)):
+        return params
+    return {**params,
+            "blocks": jax.jit(lambda b: lowprec.cast_tree(b, cdt))(blocks)}
+
+
 # jitted paged programs shared across decoder instances (the _TICK_CACHE
 # discipline from serving/decode.py): cfg is a frozen dataclass, and the
 # arena/lane shapes are jit trace dimensions, so one compiled program
@@ -746,10 +771,11 @@ class PagedDecoder:
             else envknob.get_int("DL4J_TPU_SERVE_TICK_K", 1)))
         self._refuse_state("scanned ticks (DL4J_TPU_SERVE_TICK_K > 1)",
                            self.tick_k > 1)
-        # every device program reads params through this alias so the
-        # mesh subclass (serving/mesh.py) can swap in a replicated
-        # placement without re-plumbing the call sites
-        self._infer_params = lm.params
+        # every device program reads params through this view (block
+        # leaves held once in the compute dtype; lm.params itself where
+        # they already are), so the mesh subclass (serving/mesh.py) can
+        # swap in a replicated placement without re-plumbing the call sites
+        self._infer_params = serving_view(lm.params, cfg)
         bt = max(1, min(int(block_tokens), cfg.max_len))
         while cfg.max_len % bt:
             bt //= 2
@@ -966,6 +992,21 @@ class PagedDecoder:
                 devices=int(self.mesh_devices)),
             "state_lanes": self.lanes if self.needs.state else 0,
             "state_bytes": self.lanes * self.needs.state_lane_bytes,
+            **self._weights_report(),
+        }
+
+    def _weights_report(self) -> Dict[str, object]:
+        """How the serving programs hold the weights (serving_view): the
+        dtype of the block leaves, and the bytes of the leaves held
+        BESIDE the model's own (0 where the view is ``lm.params``)."""
+        view, own = self._infer_params, self.lm.params
+        beside = [a for a, b in zip(jax.tree_util.tree_leaves(view),
+                                    jax.tree_util.tree_leaves(own))
+                  if a is not b]
+        held = jax.tree_util.tree_leaves(view.get("blocks", view))
+        return {
+            "weights_dtype": "+".join(sorted({a.dtype.name for a in held})),
+            "weights_view_bytes": sum(int(a.nbytes) for a in beside),
         }
 
     # -- client side ------------------------------------------------------

@@ -249,7 +249,7 @@ class SpeculativeDecoder(PagedDecoder):
                     [self._tok[:, None], dtoks[:, :k]], axis=1)
                 self._arena, greedy = _verify_for(
                     self.cfg, self.block_tokens, k)(
-                    self.lm.params, self._arena, jnp.asarray(toks),
+                    self._infer_params, self._arena, jnp.asarray(toks),
                     jnp.asarray(self._pos), jnp.asarray(self._tables))
                 greedy = np.asarray(greedy)        # [lanes, k+1]
         except Exception as e:  # noqa: BLE001 — device boundary

@@ -324,6 +324,21 @@ def test_cache_needs_price_the_arena_and_the_state_pool():
     assert sum(x.size for x in leaves) == ref.param_count(_conf)["total"]
 
 
+@pytest.mark.parametrize("policy,held", [("performance", "bfloat16"),
+                                         ("strict", "float32")])
+def test_the_decoder_serves_the_models_own_weights(policy, held):
+    """The serving view (ISSUE 32) of a model that holds its weights in
+    the compute dtype is the model's own tree: no copy, no program."""
+    _conf, _cfg, lm = _model(policy)
+    dec = _decoder(lm)
+    try:
+        assert dec._infer_params is lm.params
+        cap = dec.kv_capacity()
+    finally:
+        dec.stop()
+    assert (cap["weights_dtype"], cap["weights_view_bytes"]) == (held, 0)
+
+
 def test_no_prefix_lookup_for_a_model_with_recurrent_state():
     _conf, _cfg, lm = _model()
     dec = _decoder(lm)

@@ -80,14 +80,18 @@ def _get(url, path, timeout=60):
 
 
 class TestMeshByteIdentity:
-    def test_coscheduled_equals_solo_greedy_and_sampled(self):
+    @pytest.mark.parametrize("policy", ["strict", "performance"])
+    def test_coscheduled_equals_solo_greedy_and_sampled(self, policy):
         """Sharded tick == solo tick BYTE-identical with greedy and
         temperature-sampled lanes co-resident (the threefry keys are
-        replicated, so sampling is bitwise the same program)."""
+        replicated, so sampling is bitwise the same program). Under the
+        performance policy both serve the view of the weights (block
+        leaves cast once, serving/paged.serving_view), the mesh decoder
+        its replica of it."""
         from deeplearning4j_tpu.serving.mesh import MeshPagedDecoder
         from deeplearning4j_tpu.serving.paged import PagedDecoder
 
-        lm = tiny_lm()
+        lm = tiny_lm(dtype_policy=policy)
         reqs = [([1, 5, 2, 9], dict(temperature=0.0)),
                 ([4, 4, 4], dict(temperature=0.8, seed=7)),
                 ([9, 8, 7, 6, 5], dict(temperature=0.0))]

@@ -565,6 +565,210 @@ class TestArenaSizing:
 
 
 # ---------------------------------------------------------------------------
+# the serving view of the weights (ISSUE 32): block leaves held once in
+# the compute dtype, what is read in float32 shared with the model
+# ---------------------------------------------------------------------------
+
+VIEW_BT, VIEW_BLOCKS, VIEW_LANES = 4, 12, 4
+# a block matrix stacked over the 2 layers, or one layer's slice of it
+# inside the scan, converted from float32 (tiny_lm: d 16, d_ff 32)
+BLOCK_MATRIX_CAST = re.compile(
+    r"convert.*tensor<(2x)?(16x16|16x32|32x16)xf32>\) -> tensor<[0-9x]+xbf16>")
+
+
+def _view_programs(lm):
+    """The admit program, the jitted tick body (for its logits) and the
+    tick of a toy PagedDecoder's shape, with their inputs: one prompt
+    admitted to lane 0, the lanes half greedy half sampled."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.serving import paged
+
+    cfg = lm._run_cfg
+    width = 8
+    rng = np.random.default_rng(5)
+    window = np.zeros((1, width), np.int32)
+    window[0, :6] = rng.integers(1, cfg.vocab_size, 6)
+    write_table = np.zeros((cfg.max_len // VIEW_BT,), np.int32)
+    write_table[:2] = (1, 2)
+    tables = np.zeros((VIEW_LANES, cfg.max_len // VIEW_BT), np.int32)
+    tables[0, :4] = (1, 2, 3, 4)          # lane 0: the admitted prompt
+    tables[2, :4] = (5, 6, 7, 8)          # lane 2: a sampled cold lane
+    pos = np.array([5, 0, 0, 0], np.int32)
+    tok = np.array([window[0, 5], 0, 3, 0], np.int32)
+    temps = np.array([0.0, 0.0, 0.9, 0.7], np.float32)
+    keys = np.stack([paged.seed_key(i) for i in range(VIEW_LANES)])
+
+    def arena():
+        shape = (cfg.n_layers, VIEW_BLOCKS + 1, VIEW_BT, cfg.d_model)
+        return {"k": jnp.zeros(shape, cfg.compute_dtype),
+                "v": jnp.zeros(shape, cfg.compute_dtype)}
+
+    body = jax.jit(lambda p, a, t, ps: paged.paged_decode_step(
+        p, a, t, ps, jnp.asarray(tables), cfg)[1])
+    return dict(
+        admit=paged._paged_admit_for(cfg, width, VIEW_BT),
+        tick=paged._paged_tick_for(cfg, VIEW_BT), body=body, arena=arena,
+        window=window, write_table=write_table, tables=tables, pos=pos,
+        tok=tok, temps=temps, keys=keys)
+
+
+def _view_drive(params, pr, ticks=9):
+    """An admission and ``ticks`` ticks by hand; everything a client or
+    the next program would see, as bytes."""
+    import jax.numpy as jnp
+
+    arena = pr["admit"](params, pr["arena"](), jnp.asarray(pr["window"]),
+                        jnp.asarray(pr["write_table"]))
+    seen = [np.asarray(arena["k"]).tobytes()]
+    tok, pos, keys = pr["tok"], pr["pos"], jnp.asarray(pr["keys"])
+    for _ in range(ticks):
+        logits = pr["body"](params, arena, jnp.asarray(tok),
+                            jnp.asarray(pos))
+        arena, nxt, keys = pr["tick"](
+            params, arena, jnp.asarray(tok), jnp.asarray(pos),
+            jnp.asarray(pr["tables"]), keys, jnp.asarray(pr["temps"]))
+        tok, pos = np.asarray(nxt)[:, 0], pos + 1
+        seen += [np.asarray(logits).tobytes(), tok.tobytes()]
+    return seen + [np.asarray(arena["k"]).tobytes(),
+                   np.asarray(arena["v"]).tobytes(),
+                   np.asarray(keys).tobytes()]
+
+
+class TestServingView:
+    def test_programs_are_bit_equal_on_the_view_and_on_the_masters(self):
+        """(a) admit, 9 ticks, greedy and sampled lanes: logits, tokens,
+        keys and arena byte for byte, the masters cast each time against
+        the view cast once."""
+        from deeplearning4j_tpu.serving.paged import serving_view
+
+        lm = tiny_lm(dtype_policy="performance")
+        pr = _view_programs(lm)
+        view = serving_view(lm.params, lm._run_cfg)
+        assert view is not lm.params
+        assert _view_drive(view, pr) == _view_drive(lm.params, pr)
+
+    def test_view_shares_what_is_read_in_float32_and_casts_the_blocks(self):
+        """(b) embed, pos, lnf_g, lnf_b are lm.params' own buffers; every
+        leaf of blocks is held in the compute dtype, with the values a
+        cast where it is used would give."""
+        import jax
+        import jax.numpy as jnp
+
+        from deeplearning4j_tpu.serving.paged import PagedDecoder
+
+        lm = tiny_lm(dtype_policy="performance")
+        d = PagedDecoder(lm, block_tokens=VIEW_BT, n_blocks=VIEW_BLOCKS)
+        try:
+            view = d._infer_params
+        finally:
+            d.stop()
+        assert set(view) == set(lm.params)
+        for name in ("embed", "pos", "lnf_g", "lnf_b"):
+            assert view[name] is lm.params[name]
+            assert view[name].dtype == jnp.float32
+        masters = lm.params["blocks"]
+        assert set(view["blocks"]) == set(masters)
+        for name, leaf in view["blocks"].items():
+            assert leaf.dtype == jnp.bfloat16, name
+            np.testing.assert_array_equal(
+                np.asarray(leaf.astype(jnp.float32)),
+                np.asarray(masters[name].astype(jnp.bfloat16)
+                           .astype(jnp.float32)))
+            assert masters[name].dtype == jnp.float32  # masters stay
+        assert jax.tree_util.tree_structure(view) == \
+            jax.tree_util.tree_structure(lm.params)
+
+    def test_view_is_the_models_own_tree_under_a_float32_policy(self):
+        """(c) nothing to cast: the same object, no copy, no program."""
+        from deeplearning4j_tpu.serving.paged import PagedDecoder
+
+        lm = tiny_lm()
+        d = PagedDecoder(lm, block_tokens=VIEW_BT, n_blocks=VIEW_BLOCKS)
+        try:
+            assert d._infer_params is lm.params
+            cap = d.kv_capacity()
+        finally:
+            d.stop()
+        assert cap["weights_dtype"] == "float32"
+        assert cap["weights_view_bytes"] == 0
+
+    @pytest.mark.parametrize("program", ["tick", "admit"])
+    def test_no_cast_of_a_block_matrix_is_left_in_the_program(self, program):
+        """(d) lowered on the view, tick and admit convert no block
+        matrix from float32; lowered on lm.params they do, so the test
+        fails if the decoder goes back to handing out the masters."""
+        import jax.numpy as jnp
+
+        from deeplearning4j_tpu.serving.paged import PagedDecoder
+
+        lm = tiny_lm(dtype_policy="performance")
+        d = PagedDecoder(lm, block_tokens=VIEW_BT, n_blocks=VIEW_BLOCKS,
+                         lanes=VIEW_LANES)
+        try:
+            held = d._infer_params
+        finally:
+            d.stop()
+        pr = _view_programs(lm)
+        if program == "tick":
+            rest = (pr["arena"](), jnp.asarray(pr["tok"]),
+                    jnp.asarray(pr["pos"]), jnp.asarray(pr["tables"]),
+                    jnp.asarray(pr["keys"]), jnp.asarray(pr["temps"]))
+        else:
+            rest = (pr["arena"](), jnp.asarray(pr["window"]),
+                    jnp.asarray(pr["write_table"]))
+        text = lambda params: pr[program].lower(params, *rest).as_text()
+        assert BLOCK_MATRIX_CAST.search(text(lm.params))
+        assert not BLOCK_MATRIX_CAST.search(text(held))
+
+    def test_kv_capacity_reports_how_the_weights_are_held(self):
+        """(e) weights_dtype and weights_view_bytes, in kv_capacity and
+        so in what /models prints (engine.kv_report)."""
+        from deeplearning4j_tpu.serving.paged import PagedDecoder
+
+        lm = tiny_lm(dtype_policy="performance")
+        d = PagedDecoder(lm, block_tokens=VIEW_BT, n_blocks=VIEW_BLOCKS)
+        try:
+            cap = d.kv_capacity()
+        finally:
+            d.stop()
+        blocks = lm.params["blocks"]
+        assert cap["weights_dtype"] == "bfloat16"
+        assert cap["weights_view_bytes"] == \
+            sum(a.size for a in blocks.values()) * 2
+        eng = ServingEngine(model=lm, kv_block=VIEW_BT,
+                            kv_blocks=VIEW_BLOCKS).start()
+        try:
+            rep = _get(eng.url, "/models")["kv"]["default@v1"]
+        finally:
+            eng.stop()
+        assert rep["weights_dtype"] == "bfloat16"
+        assert rep["weights_view_bytes"] == cap["weights_view_bytes"]
+
+    def test_served_tokens_are_those_of_the_masters(self):
+        """Through the decoder itself: the tokens a pool serves on the
+        view are the ones it serves with the masters handed back."""
+        from deeplearning4j_tpu.serving.paged import PagedDecoder
+
+        lm = tiny_lm(dtype_policy="performance")
+        reqs = [([1, 5, 2, 9, 3, 3, 7], dict(temperature=0.0)),
+                ([4, 4, 4], dict(temperature=0.8, seed=7))]
+
+        def run(masters):
+            d = PagedDecoder(lm, block_tokens=VIEW_BT, n_blocks=VIEW_BLOCKS)
+            if masters:
+                d._infer_params = lm.params
+            try:
+                futs = [d.submit(p, 9, **kw) for p, kw in reqs]
+                return [f.result(timeout=120).tolist() for f in futs]
+            finally:
+                d.stop()
+
+        assert run(False) == run(True)
+
+
+# ---------------------------------------------------------------------------
 # ledger + bench registration
 # ---------------------------------------------------------------------------
 
