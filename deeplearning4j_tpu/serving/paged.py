@@ -34,11 +34,17 @@ Layout and invariants:
     dtype) and folds each chunk into an online softmax, looping only as
     far as the longest LIVE lane reaches (chunked_attention; the trip
     count is data, so there is one program). No ``[S, max_len, H, hd]``
-    view exists. Per-lane outputs are functions of the gathered VALUES,
-    not the physical block ids, and a chunk wholly past a lane's
-    position is an exact no-op on its running softmax, which is why a
-    request's tokens are byte-invariant to allocation history and pool
-    co-residents (tests/test_serving_paged, tests/test_paged_chunked).
+    view exists. The heads' two products read the gathered chunk as it
+    lies, ``[S, chunk, H*hd]``: a head is a column range of the row, and
+    its product takes that range where it is. A product batched over
+    the head would make the chip's compiler transpose every gathered
+    chunk first (it wants a batch axis outermost), as the arena itself
+    was once re-laid for the scatter. Per-lane outputs are functions of
+    the gathered VALUES, not the physical block ids, and a chunk wholly
+    past a lane's position is an exact no-op on its running softmax,
+    which is why a request's tokens are byte-invariant to allocation
+    history and pool co-residents (tests/test_serving_paged,
+    tests/test_paged_chunked).
     The masked-attention math is decode_step_slots' up to the order of
     the float32 softmax sums.
   * prefix cache: full prompt blocks strictly BELOW a request's first
@@ -194,24 +200,43 @@ def _exact_rows(x):
 
 def chunked_attention(q, ck, cv, tables, pos, scale=None):
     """Masked single-query attention over the block arena, chunk by
-    chunk up to the longest lane: q [S, H, hd], ck/cv [B, bt, Hkv, hd]
-    or, a token's heads side by side, [B, bt, Hkv * hd] (block 0 =
-    trash), tables [S, m] int32, pos [S] int32 (every entry >= 0) ->
-    att [S, H, hd] float32. ``Hkv`` divides ``H``: KV head j
-    serves query heads g*j .. g*j+g-1 (g = H // Hkv; 1 is full
-    multi-head attention, whose program is what it was), their rows
-    side by side in one product against the head's blocks as stored.
-    ``scale`` multiplies the scores (None: 1/sqrt(hd)).
+    chunk up to the longest lane: q [S, H, hd], ck/cv [B, bt, Hkv * hd],
+    a token's heads side by side as the arena stores them (or
+    [B, bt, Hkv, hd], viewed so; block 0 = trash), tables [S, m] int32,
+    pos [S] int32 (every entry >= 0) -> att [S, H, hd] float32. ``Hkv``
+    divides ``H``: KV head j serves query heads g*j .. g*j+g-1
+    (g = H // Hkv; 1 is full multi-head attention), their rows side by
+    side in one product. ``scale`` multiplies the scores (None:
+    1/sqrt(hd)).
 
     Each pass gathers ``c`` table columns of K and V in the arena's
-    dtype, takes the scores against the query's exact rows with float32
-    accumulation, masks ``t <= pos`` and folds the chunk into a running
-    (max, denominator, accumulator) in float32: the online softmax of
-    ops/pallas_paged.py. The probabilities stay float32 (_exact_rows
-    again). K and V are read as stored (a float32 arena at full
-    precision); nothing of ``max_len`` width exists in any dtype. The
-    trip count is data (``max(pos) // chunk + 1``, a ``while``), so one
-    program serves every live length.
+    dtype, ``[S, chunk, Hkv * hd]``, takes the scores against the query's
+    exact rows with float32 accumulation, masks ``t <= pos`` and folds
+    the chunk into a running (max, denominator, accumulator) in float32:
+    the online softmax of ops/pallas_paged.py. The probabilities stay
+    float32 (_exact_rows again). K and V are read as stored (a float32
+    arena at full precision); nothing of ``max_len`` width exists in any
+    dtype. The trip count is data (``max(pos) // chunk + 1``, a
+    ``while``), so one program serves every live length.
+
+    Both products read the chunk in the layout the gather leaves it in.
+    A head is a column range of the row: KV head j's two products take
+    columns ``j*hd .. j*hd+hd-1`` of every gathered row, a static slice
+    that the chip's compiler reads in place, against that head's rows
+    ``[S, R, hd]`` (scores) and ``[S, R, chunk]`` (values), ``R`` the 3
+    exact rows of each of its g query heads padded with zero rows to
+    whole sublane tiles of 8 (8 for full multi-head attention, 16 for
+    g = 4). One form for both sides and for every ``(H, Hkv, hd)``. The
+    heads are NOT a batch axis of one product
+    (``einsum("nhrd,nthd->nhrt")`` on the chunk viewed ``[S, chunk, Hkv,
+    hd]``): the TPU's compiler wants a batch axis outermost and re-laid
+    every gathered chunk to ``[S, Hkv, chunk, hd]`` before each product,
+    a third of the serve cell's device time (PERF.md section 6, PR 35;
+    one wide product a side with the heads' rows laid block-diagonally
+    reads the chunk as stored too and measured slower on the chip, at
+    both head shapes). A head's products never see another head's
+    columns, so a K or V that is not a number in one head of a visible
+    token stays in that head, as it did.
 
     A lane's output does not depend on the trip count: a chunk wholly
     past ``pos`` leaves the running max where it was, so ``corr`` is
@@ -221,7 +246,15 @@ def chunked_attention(q, ck, cv, tables, pos, scale=None):
     first pass on."""
     s, n_heads, hd = q.shape
     bt = ck.shape[1]
-    kv_heads = ck.shape[2] if ck.ndim == 4 else ck.shape[2] // hd
+    # a token's heads side by side, however the caller names them
+    ck = ck.reshape(ck.shape[0], bt, -1)
+    cv = cv.reshape(cv.shape[0], bt, -1)
+    width = ck.shape[2]
+    kv_heads = width // hd
+    group = n_heads // kv_heads
+    # a KV head's rows in its products: 3 exact rows for each of its
+    # query heads, padded with zero rows to whole sublane tiles of 8
+    n_rows = -(-3 * group // 8) * 8
     chunk = _chunk_tokens(bt, tables.shape[1])
     c = chunk // bt
     # a table whose width c does not divide reads trash past its end,
@@ -229,36 +262,46 @@ def chunked_attention(q, ck, cv, tables, pos, scale=None):
     tables = jnp.pad(tables, ((0, 0), (0, -tables.shape[1] % c)))
     if scale is None:
         scale = 1.0 / float(np.sqrt(hd))
-    q_rows = _exact_rows(q).astype(ck.dtype)
     t_in = jnp.arange(chunk)[None, :]                 # [1, chunk]
 
-    def rows_dot(spec, rows, gathered):
-        # rows [S, H, 3, x]: a KV head's query heads stand as further
-        # rows of its one product (a reshape to the shape it has is no
-        # operation: full multi-head attention traces as it did).
-        # HIGHEST touches float32 operands only (a float32 arena)
-        rows = rows.reshape(s, kv_heads, -1, rows.shape[-1])
-        out = jnp.einsum(spec, rows, gathered,
-                         precision=lax.Precision.HIGHEST,
-                         preferred_element_type=jnp.float32)
-        return out.reshape(s, n_heads, 3, out.shape[-1]).sum(axis=2)
+    def head_rows(x, dtype):
+        # x [S, H, n] -> [S, Hkv, n_rows, n]: a KV head's query heads
+        # stand as further rows of its one product
+        rows = _exact_rows(x).astype(dtype).reshape(
+            s, kv_heads, 3 * group, -1)
+        return jnp.pad(rows, ((0, 0), (0, 0), (0, n_rows - 3 * group),
+                              (0, 0)))
+
+    def heads_dot(spec, rows, gathered):
+        # rows [S, Hkv, n_rows, x] against the chunk as gathered,
+        # [S, chunk, Hkv * hd]: KV head j's product reads its columns
+        # j*hd .. j*hd+hd-1 of the rows where they lie. HIGHEST touches
+        # float32 operands only (a float32 arena)
+        out = jnp.stack([
+            jnp.einsum(spec, rows[:, j], gathered[:, :, j * hd:(j + 1) * hd],
+                       precision=lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+            for j in range(kv_heads)], axis=1)
+        return out[:, :, :3 * group].reshape(s, n_heads, 3, -1).sum(axis=2)
+
+    q_rows = head_rows(q, ck.dtype)
 
     def fold(j, carry):
         m, l, acc = carry
         cols = lax.dynamic_slice_in_dim(tables, j * c, c, axis=1)
         with jax.named_scope("tick.gather_kv"):
-            kg = ck[cols].reshape(s, chunk, kv_heads, hd)
-            vg = cv[cols].reshape(s, chunk, kv_heads, hd)
+            kg = ck[cols].reshape(s, chunk, width)
+            vg = cv[cols].reshape(s, chunk, width)
         with jax.named_scope("tick.attend"):
-            sc = rows_dot("nhrd,nthd->nhrt", q_rows, kg) * scale
+            sc = heads_dot("nrd,ntd->nrt", q_rows, kg) * scale
             visible = j * chunk + t_in <= pos[:, None]    # [S, chunk]
             sc = jnp.where(visible[:, None, :], sc, -jnp.inf)
             m_new = jnp.maximum(m, jnp.max(sc, axis=-1))  # [S, H], finite
             p = jnp.exp(sc - m_new[..., None])
             corr = jnp.exp(m - m_new)
             l = l * corr + jnp.sum(p, axis=-1)
-            acc = acc * corr[..., None] + rows_dot(
-                "nhrt,nthd->nhrd", _exact_rows(p).astype(cv.dtype), vg)
+            acc = acc * corr[..., None] + heads_dot(
+                "nrt,ntd->nrd", head_rows(p, cv.dtype), vg)
         return m_new, l, acc
 
     init = (jnp.full((s, n_heads), -jnp.inf, jnp.float32),

@@ -141,28 +141,63 @@ def _compile_serve_program(one_chip, heads, n_layers, program="tick"):
     return compiled, arena, lanes, bt
 
 
+def _outside_fusions(text):
+    """The instructions a compiled program runs one by one: every line of
+    every computation but the fused ones, whose instructions are one
+    operation's insides and yield no buffer of their own."""
+    keep, out = True, []
+    for ln in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", ln)
+        if m:
+            keep = not m.group(1).startswith("fused_computation")
+        elif keep:
+            out.append(ln)
+    return out
+
+
 @pytest.mark.parametrize("heads", [12, 16])
 def test_tick_attention_reads_the_arena_as_stored_on_v5e(one_chip, heads,
                                                          no_compile_cache):
     """The serve cell's tick, two layers of it: the chip's compiler gives
-    both attention products to the matrix unit and makes no float32 copy
-    of a gathered chunk. A one-row product it rewrites as
-    multiply-and-reduce over such a copy, which was 38% of the tick
-    (PERF.md section 6, PR 27): paged._exact_rows is what keeps it from
-    that, and this is what watches it."""
+    the attention products to the matrix unit, makes no float32 copy of a
+    gathered chunk and no copy of it in another order. A one-row product
+    it rewrites as multiply-and-reduce over a float32 copy, which was 38%
+    of the tick (PERF.md section 6, PR 27): paged._exact_rows is what
+    keeps it from that. A product batched over the head it feeds from a
+    transposed copy of the chunk, ``bf16[40,128,12,128]``, which was 35%
+    of the device's time (PERF.md section 6, PR 35): a product a head over
+    its own columns of the chunk as gathered is what keeps it from that.
+    This is what watches both."""
     from deeplearning4j_tpu.serving import paged
 
     compiled, _, lanes, bt = _compile_serve_program(one_chip, heads, 2)
     text = compiled.as_text()
     cols = paged.ATTN_CHUNK_COLS
     chunk = lanes * cols * bt * heads * 128
-    assert f"bf16[{lanes * cols},{bt},{heads * 128}]" in text  # the gathers
+    gathered = f"bf16[{lanes * cols},{bt},{heads * 128}]"
+    assert gathered in text                                   # the gathers
     sizes = {int(np.prod([int(d) for d in dims.split(",")]))
              for dims in re.findall(r"f32\[([\d,]+)\]", text)}
     assert chunk not in sizes and chunk // 2 not in sizes
     products = [ln for ln in text.splitlines()
                 if " convolution(" in ln and "tick.attend" in ln]
-    assert len(products) == 2, products
+    assert len(products) == 2 * heads, products
+    # whatever yields a buffer of a chunk's size, or of one head's columns
+    # of it, outside a fusion: the two gathers in the shape they gather to,
+    # and views of them (a bitcast moves nothing). No copy, transpose or
+    # re-laying reshape, of the whole chunk or head by head
+    made = []
+    for ln in _outside_fusions(text):
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (bf16\[([\d,]+)\])\S* "
+                     r"([a-z][\w\-]*)\(", ln)
+        if m and (int(np.prod([int(d) for d in m.group(2).split(",")]))
+                  == chunk or m.group(2) in (f"{lanes},{cols * bt},128",
+                                             f"{lanes * cols},{bt},128")):
+            made.append((m.group(3), m.group(1)))
+    assert {op for op, _ in made} <= {"fusion", "bitcast",
+                                      "get-tuple-element"}, made
+    assert {shape for op, shape in made if op == "fusion"} == {gathered}, made
+    assert f"bf16[{lanes},{cols * bt},{heads},128]" not in text
 
 
 def _makes_arena_sized(text, arena):

@@ -9,6 +9,11 @@ arena's dtype, chunk by chunk up to the longest live lane, one program.
     past a lane's position is an exact no-op);
   * the lowered tick holds no float32 array of the gathered window's
     size and no gather wider than one chunk;
+  * both products read the gathered chunk as stored (ISSUE 35): in the
+    lowered tick's loop the chunk is [S, chunk, Hkv*hd] and nothing else,
+    a head is a column range of that row, its rows padded to whole
+    sublane tiles, and a head's output keeps its bits whatever the other
+    heads' columns hold;
   * the bound follows LIVE lanes: the release resets the lane's
     position, so the next tick's ``kv_read`` falls;
   * one tick program whatever the live lengths (no retrace);
@@ -61,13 +66,17 @@ def _dense_tables(lanes, m):
 
 def _oracle(q, ck, cv, tables, pos):
     """Dense masked softmax attention in float32 over the gathered window,
-    with numpy: the parent's gather path, one lane at a time."""
+    with numpy: the parent's gather path, one lane at a time. Fewer KV
+    heads than query heads (the arena's row says how many): KV head j
+    serves query heads g*j .. g*j+g-1."""
     q, ck, cv = (np.asarray(a, np.float32) for a in (q, ck, cv))
     s, n_heads, hd = q.shape
+    kv_heads = int(np.prod(ck.shape[2:])) // hd
     out = np.zeros((s, n_heads, hd), np.float32)
     for i in range(s):
-        k = ck[tables[i]].reshape(-1, n_heads, hd)[:pos[i] + 1]
-        v = cv[tables[i]].reshape(-1, n_heads, hd)[:pos[i] + 1]
+        k = ck[tables[i]].reshape(-1, kv_heads, hd)[:pos[i] + 1]
+        v = cv[tables[i]].reshape(-1, kv_heads, hd)[:pos[i] + 1]
+        k, v = (np.repeat(a, n_heads // kv_heads, axis=1) for a in (k, v))
         sc = np.einsum("hd,thd->ht", q[i], k) / np.sqrt(hd)
         p = np.exp(sc - sc.max(-1, keepdims=True))
         p /= p.sum(-1, keepdims=True)
@@ -146,9 +155,10 @@ def _tick_inputs(cfg, lanes, pos, seed=0):
 
 def _pr27_chunked_attention(q, ck, cv, tables, pos):
     """paged.chunked_attention as PR 27 left it, kept here letter for
-    letter: full multi-head attention only, scores over sqrt(hd). What the
-    tick of a GPT-2-shaped model has to stay, bit for bit, now that the
-    function also serves grouped heads (ISSUE 28)."""
+    letter: full multi-head attention only, scores over sqrt(hd), the
+    heads a batch axis of both products. The oracle for the logits of a
+    GPT-2-shaped model's tick, whatever paged.chunked_attention does to
+    tell the heads apart (ISSUE 28, ISSUE 35)."""
     from jax import lax
 
     s, n_heads, hd = q.shape
@@ -212,13 +222,14 @@ def test_lane_logits_bit_equal_alone_and_beside_a_longer_lane(shape,
     beside = np.asarray(step(arena, both))
     assert np.array_equal(alone[0], beside[0])
     assert not np.array_equal(alone[1], beside[1])
-    # and the tick is bit for bit what it was before chunked_attention
-    # learned grouped heads: the same program text, the same logits
-    text = jax.jit(tick).lower(arena, both).as_text()
+    # and the tick's logits are those of PR 27's body, the oracle: the
+    # heads are told apart by their columns and no longer by a batch axis
+    # (ISSUE 35), so the program text differs and the float32 sums behind a
+    # logit may round in another order
     monkeypatch.setattr(paged, "chunked_attention", _pr27_chunked_attention)
     was = jax.jit(lambda a, p: tick(a, p))
-    assert was.lower(arena, both).as_text() == text
-    assert np.array_equal(np.asarray(was(arena, both)), beside)
+    np.testing.assert_allclose(np.asarray(was(arena, both)), beside,
+                               rtol=0, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -255,6 +266,165 @@ def test_lowered_tick_holds_no_window_wide_array():
         r"stablehlo\.gather.*-> tensor<((?:\d+x)+)\w+>", text)]
     assert one_chunk in gathered
     assert max(gathered) == one_chunk < window
+
+
+# ---------------------------------------------------------------------------
+# (c') both products read the gathered chunk as stored (ISSUE 35)
+# ---------------------------------------------------------------------------
+
+
+def _hybrid_tick_text(lanes, heads, kv_heads, hd):
+    """The lowered tick of a hybrid model with one attention layer of the
+    given heads (shapes only: nothing is computed)."""
+    from deeplearning4j_tpu.models import hybrid
+    from deeplearning4j_tpu.ops import memory as opsmem
+
+    cfg = hybrid.HybridConfig(
+        vocab_size=64, d_model=heads * hd, n_heads=heads,
+        n_kv_heads=kv_heads, d_ff=32, layer_types=("mamba", "attention"),
+        max_len=MAX_LEN, ssm_heads=2, ssm_head_dim=8, ssm_state=8)
+    arg = jax.ShapeDtypeStruct
+    params = jax.tree.map(lambda sh: arg(sh, jnp.bfloat16),
+                          hybrid.param_shapes(cfg),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    needs = opsmem.cache_needs(cfg)
+    kv = arg((lanes * (MAX_LEN // BT) + 1, BT, kv_heads * hd), jnp.bfloat16)
+    arena = {"k": (kv,), "v": (kv,)}
+    for leaf in needs.state:
+        arena[leaf.name] = tuple(arg((lanes,) + leaf.shape, leaf.dtype)
+                                 for _ in range(leaf.layers))
+    tick = paged._paged_tick_for(cfg, BT)
+    paged._PAGED_TICK_CACHE.pop((cfg, BT, "gather", 1), None)
+    return tick.lower(
+        params, arena, arg((lanes,), jnp.int32), arg((lanes,), jnp.int32),
+        arg((lanes, MAX_LEN // BT), jnp.int32), arg((lanes, 2), jnp.uint32),
+        arg((lanes,), jnp.float32)).as_text()
+
+
+def _dense_tick_text(lanes, heads, hd):
+    cfg = _cfg(d_model=heads * hd, n_heads=heads, n_layers=1, d_ff=64,
+               dtype_policy="performance")
+    params, arena, tok, pos, tables = _tick_inputs(cfg, lanes, [0] * lanes)
+    tick = paged._paged_tick_for(cfg, BT)
+    paged._PAGED_TICK_CACHE.pop((cfg, BT, "gather", 1), None)
+    return tick.lower(params, arena, tok, pos, tables,
+                      jnp.zeros((lanes, 2), jnp.uint32),
+                      jnp.zeros((lanes,), jnp.float32)).as_text()
+
+
+@pytest.mark.parametrize("heads,kv_heads,hd,rows",
+                         [(12, 12, 128, 8), (32, 8, 64, 16)],
+                         ids=["590m-heads", "granite-heads"])
+def test_lowered_tick_reads_the_gathered_chunk_as_stored(heads, kv_heads, hd,
+                                                         rows):
+    """The serve cell's heads (12 of 128) and the hybrid cell's (32 over 8
+    of 64), bfloat16: in the lowered tick the gathered chunk exists as
+    [S, chunk, Hkv*hd] and in no other arrangement. No tensor names the
+    head as an axis of it, nothing transposes it, and every product that
+    reads K or V takes a KV head's own columns of that row, sliced where
+    they lie (a product batched over the head made the chip's compiler
+    re-lay each chunk before it: a third of the serve cell's device time,
+    PERF.md section 6, PR 35)."""
+    lanes = 3
+    text = _dense_tick_text(lanes, heads, hd) if heads == kv_heads \
+        else _hybrid_tick_text(lanes, heads, kv_heads, hd)
+    width = kv_heads * hd
+    row = f"{lanes}x{CHUNK}x{width}xbf16"
+    assert f"tensor<{row}>" in text
+    # the head is nowhere an axis of a chunk: not [S, chunk, Hkv, hd] and
+    # not any order of those four
+    for dims in re.findall(r"tensor<((?:\d+x)+)bf16>", text):
+        shape = [int(d) for d in dims.split("x") if d]
+        assert not (len(shape) >= 4 and int(np.prod(shape))
+                    == lanes * CHUNK * width and hd in shape
+                    and kv_heads in shape[:-1] and CHUNK in shape), dims
+    # nothing transposes a chunk or a head's columns of it
+    moved = [ln for ln in text.splitlines() if "stablehlo.transpose" in ln
+             and (f"x{CHUNK}x{width}x" in ln or f"x{CHUNK}x{hd}xbf16" in ln)]
+    assert not moved, moved
+    # what the products read of K and V: a KV head's columns of the row as
+    # stored, a static slice with the chunk's own leading axes
+    cols = f"{lanes}x{CHUNK}x{hd}xbf16"
+    slices = re.findall(
+        rf"stablehlo\.slice .*\(tensor<{row}>\) -> tensor<{cols}>", text)
+    assert len(slices) == 2 * kv_heads
+    # against that head's rows: 3 a query head, padded to whole tiles of 8
+    products = [ln for ln in text.splitlines()
+                if "stablehlo.dot_general" in ln and f"tensor<{cols}>" in ln]
+    assert len(products) == 2 * kv_heads
+    assert all(f"tensor<{lanes}x{rows}x" in ln for ln in products)
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(12, 12), (32, 8), (2, 2),
+                                            (16, 16), (6, 2)])
+@pytest.mark.parametrize("kv_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_padded_rows_of_every_head_layout_equal_the_dense_oracle(
+        heads, kv_heads, kv_dtype):
+    """A KV head's rows in its products are 3 exact rows for each of its
+    query heads, padded with zero rows to whole tiles of 8: 8 for full
+    multi-head attention, 16 for four query heads a KV head, 16 for three
+    (9 rows). The padding reaches no output, whatever the layout."""
+    lanes, hd = 3, 8
+    m = MAX_LEN // BT
+    rng = np.random.default_rng(heads * 100 + kv_heads)
+    tables = _dense_tables(lanes, m)
+    shape = (lanes * m + 1, BT, kv_heads * hd)
+    ck = rng.normal(size=shape).astype(np.float32)
+    cv = rng.normal(size=shape).astype(np.float32)
+    ck[0] = cv[0] = 1e4
+    pos = np.asarray([CHUNK - 1, 2 * CHUNK + 3, 0], np.int32)
+    for i in range(lanes):
+        tables[i, pos[i] // BT + 1:] = 0
+    q = jnp.asarray(rng.normal(size=(lanes, heads, hd)), jnp.float32)
+    ck, cv = jnp.asarray(ck, kv_dtype), jnp.asarray(cv, kv_dtype)
+    fn = jax.jit(paged.chunked_attention)
+    got = fn(q, ck, cv, jnp.asarray(tables), jnp.asarray(pos))
+    np.testing.assert_allclose(np.asarray(got),
+                               _oracle(q, ck, cv, tables, pos),
+                               rtol=1e-6, atol=1e-6)
+    rows = {1: 8, 3: 16, 4: 16}[heads // kv_heads]
+    text = fn.lower(q, ck, cv, jnp.asarray(tables),
+                    jnp.asarray(pos)).as_text()
+    products = [ln for ln in text.splitlines()
+                if "stablehlo.dot_general" in ln]
+    assert products and all(f"tensor<{lanes}x{rows}x" in ln
+                            for ln in products), products[:2]
+
+
+@pytest.mark.parametrize("heads,kv_heads,hd", [(12, 12, 128), (32, 8, 64),
+                                               (2, 2, 8)])
+def test_a_heads_output_keeps_its_bits_whatever_other_heads_columns_hold(
+        heads, kv_heads, hd):
+    """KV head j's products read columns j*hd .. j*hd+hd-1 of a row and no
+    others: overwrite every OTHER head's K and V columns with other finite
+    values (large ones) and the outputs of head j's query heads are the
+    same bits."""
+    lanes, bt, m = 2, 16, 16
+    group = heads // kv_heads
+    rng = np.random.default_rng(5)
+    tables = jnp.asarray(
+        (1 + np.arange(lanes * m, dtype=np.int32)).reshape(lanes, m))
+    shape = (lanes * m + 1, bt, kv_heads * hd)
+    ck = rng.normal(size=shape).astype(np.float32)
+    cv = rng.normal(size=shape).astype(np.float32)
+    pos = jnp.asarray([paged.ATTN_CHUNK_COLS * bt + 3, 17], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(lanes, heads, hd)), jnp.bfloat16)
+    fn = jax.jit(paged.chunked_attention)
+    base = np.asarray(fn(q, jnp.asarray(ck, jnp.bfloat16),
+                         jnp.asarray(cv, jnp.bfloat16), tables, pos))
+    for j in (0, kv_heads - 1):
+        own = np.zeros(kv_heads * hd, bool)
+        own[j * hd:(j + 1) * hd] = True
+        k2 = np.where(own, ck, 300.0 * rng.normal(size=shape))
+        v2 = np.where(own, cv, -77.0 + rng.normal(size=shape))
+        got = np.asarray(fn(q, jnp.asarray(k2, jnp.bfloat16),
+                            jnp.asarray(v2, jnp.bfloat16), tables, pos))
+        mine = slice(j * group, (j + 1) * group)
+        assert np.array_equal(got[:, mine], base[:, mine])
+        others = np.ones(heads, bool)
+        others[mine] = False
+        assert not np.array_equal(got[:, others], base[:, others])
 
 
 # ---------------------------------------------------------------------------
