@@ -35,6 +35,11 @@ def _check(checks: Checks, name: str, value: float, limits: Dict[str, Any]
                                and value <= limit)}
 
 
+def exact(checks: Checks, name: str, count: int) -> None:
+    """A count that has to be nought: the limit is 0."""
+    checks[name] = {"value": float(count), "limit": 0.0, "ok": count == 0}
+
+
 def norm_gap(prog: Dict[str, float], ref: Dict[str, float],
              leaves: List[str]) -> Dict[str, float]:
     """For each leaf the gap between the program's norm and the
@@ -96,8 +101,7 @@ def serve_checks(gaps: List[float], malformed: int,
     checks["logit_gap"]["tokens"] = n
     # an answer that says the wrong thing: not the tokens asked for, or a
     # token outside the vocabulary. Exact, so the limit is 0.
-    checks["malformed_answers"] = {"value": float(malformed), "limit": 0.0,
-                                   "ok": malformed == 0}
+    exact(checks, "malformed_answers", malformed)
     return checks
 
 

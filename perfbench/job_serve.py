@@ -6,35 +6,87 @@ cell's traffic mix and the seed.
 Set-up makes the weights from the seed in one jitted call, starts the engine
 and sends one short request at every prompt length a prefill shape can start
 at, so that every program the window will use is compiled (or found in the
-cache) before it. After the window the clients stop sending, every request in
-flight is waited for, the engine is stopped and freed, and the reference runs
-once over a sample of the finished greedy requests.
+cache) before it; it ends where the clients start, and says where it went
+(`setup`: imports and weights, the engine's start, the warm-up). The clients
+then run for the cell's `warm_in_seconds` before the window opens: their
+opening burst, every client's first request at one instant, is the load
+generator's start and no deployment's, so it is in no tail (perfbench/
+window.py says what belongs to the window). After the window the clients
+stop sending, every request in flight is waited for, the engine is stopped
+and freed, and the reference runs once over a sample of the finished greedy
+requests.
+
+One job for every served model: `Served` says how the cell's model is built
+and judged. This file's is the GPT-2-shaped TransformerLM;
+perfbench/job_serve_hybrid.py hands `run` another.
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import re
 import time
-from typing import Any, Dict, List
+from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
 from perfbench import compare, harness, loadgen, reference, trace_reduce, \
-    traffic
+    traffic, window
+from perfbench.compile_log import CompileLog
+from perfbench.window import DRAIN_S
 
-DRAIN_S = 60.0          # how long past the close an answer is waited for
 WARM_S = 1000.0         # a cold first run compiles every shape in warm-up
 TICK_SAMPLES = re.compile(
     r"^dl4j_dispatch_(decode_ticks|decode_tokens)(?:_total)?\{[^}]*\}\s+(\S+)",
     re.M)
 
 
+@dataclasses.dataclass
+class Served:
+    """How a cell's model is built and judged. `init(conf, key, **weights)`
+    makes the weights (the plain reference's own function: the program makes
+    none); `model_of(params)` is the program's model on them; `gaps(cell,
+    params, sample)` runs the reference over `sample`, (prompt, served
+    tokens) pairs, and returns every served token's gap; `after_drain(
+    engine)`, where the served tokens cannot tell all that the configuration
+    states, reads further numbers off the engine once no request is in
+    flight, each compared under the limit of its name."""
+    init: Callable[..., Any]
+    model_of: Callable[[Any], Any]
+    gaps: Callable[[Dict[str, Any], Any, List[Any]], List[float]]
+    after_drain: Optional[Callable[[Any], Dict[str, float]]] = None
+
+
+def dense(cell: Dict[str, Any]) -> Served:
+    from deeplearning4j_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+
+    cfg = TransformerConfig(**harness.program_config(cell["conf"]))
+    return Served(reference.init_params,
+                  lambda params: TransformerLM.from_state(cfg, params),
+                  dense_gaps)
+
+
+def dense_gaps(cell: Dict[str, Any], params, sample) -> List[float]:
+    width = reference_width(cell["mix"])
+    gaps: List[float] = []
+    for prompt, served in sample:
+        gaps += reference.serve_gaps(cell["conf"], params, prompt, served,
+                                     width).tolist()
+    return gaps
+
+
 def _counters(engine) -> Dict[str, float]:
-    """The program's own counts: the serving ledger as /metrics gives it,
-    and the decoder's tick ledger from the central registry's exposition."""
+    """The program's own counts: the serving ledger that /metrics shows
+    (the ledger itself: `engine.metrics()` also asks the device for its
+    memory, a call that waits behind what runs there, and this is read at
+    the window's edges under load), and the decoder's tick ledger from the
+    central registry's exposition."""
     from deeplearning4j_tpu.obs import registry as obs_registry
 
-    serving = engine.metrics()["serving"]
+    serving = engine.stats.snapshot()
     out = {k: float(serving[k]) for k in
            ("prefix_hits", "prefix_lookups", "generated_tokens",
             "preemptions", "errors", "timeouts", "rejected_429")}
@@ -82,94 +134,116 @@ def reference_width(mix: Dict[str, Any]) -> int:
     return -(-longest // 64) * 64
 
 
-def _p95(values: List[float]) -> float:
-    return float(np.percentile(np.asarray(values, np.float64), 95))
+def _ticks(lo: float, hi: float) -> List[Dict[str, Any]]:
+    """The decoder's finished ticks that started in `[lo, hi)`, from the
+    program's tracer (none where its spans are off)."""
+    from deeplearning4j_tpu.obs import trace as obs_trace
+
+    return [s for s in obs_trace.tracer().spans("serve.batch")
+            if s["attrs"].get("kind") == "decode.paged"
+            and s["duration_s"] is not None and lo <= s["t_mono"] < hi]
+
+
+def measure(engine, cell: Dict[str, Any], clients, seconds: float,
+            trace: bool) -> Dict[str, Any]:
+    """The clients' whole life on a warmed engine: start, warm-in, the
+    window `[t0, t1)`, the traced tail where asked for, the drain. The
+    program's counters and spans are read from `t0` on."""
+    from deeplearning4j_tpu.obs import trace as obs_trace
+
+    # spans of the program's own tracer: on in the traced run only, so
+    # that the end-to-end run pays nothing for them
+    obs_trace.set_enabled(True if trace else None)
+    loop = loadgen.ClosedLoop(engine.port, clients)
+    t_clients = loop.start()
+    loop.pump(t_clients + float(cell["warm_in_seconds"]), send_new=True)
+    obs_trace.tracer().clear()
+    c0 = _counters(engine)
+    t0 = time.perf_counter()      # whatever the two lines above took is out
+    loop.pump(t0 + seconds, send_new=True)
+    t1 = time.perf_counter()
+    c1 = _counters(engine)
+    traced = None
+    if trace:
+        logdir = harness.trace_dir()
+        trace_reduce.start(logdir)
+        ta = time.perf_counter()
+        loop.pump(ta + float(cell.get("trace_seconds", 3)), send_new=True)
+        tb = time.perf_counter()
+        trace_reduce.stop()
+        traced = {"window_s": tb - ta, "logdir": logdir,
+                  "ticks": _ticks(ta, tb)}
+    loop.pump(time.perf_counter() + DRAIN_S, send_new=False)
+    loop.close()
+    return {"requests": loop.requests, "t_clients": t_clients, "t0": t0,
+            "t1": t1, "counters": {k: c1[k] - c0[k] for k in c0},
+            "spans": _ticks(t0, t1), "traced": traced}
+
+
+def sample_of(greedy: List[Any], n: int, seed: int) -> List[Any]:
+    """`n` of the finished greedy requests, drawn from the seed, the
+    longest among them."""
+    if not greedy:
+        return []
+    greedy = sorted(greedy,
+                    key=lambda r: -(len(r.spec["tokens"]) + len(r.tokens)))
+    n = min(n, len(greedy))
+    if n <= 1:
+        return greedy[:1]
+    rng = np.random.default_rng([int(seed), 11])
+    return [greedy[0]] + [greedy[i] for i in sorted(
+        1 + rng.choice(len(greedy) - 1, n - 1, replace=False))]
 
 
 def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
-        t_start: float, chips: int) -> Dict[str, Any]:
+        t_start: float, chips: int, served: Optional[Served] = None
+        ) -> Dict[str, Any]:
     import jax
 
-    from deeplearning4j_tpu.models.transformer import (
-        TransformerConfig,
-        TransformerLM,
-    )
     from deeplearning4j_tpu.obs import trace as obs_trace
     from deeplearning4j_tpu.serving.engine import ServingEngine
 
+    t_enter = time.perf_counter()
+    served = served or dense(cell)
     conf, mix = cell["conf"], cell["mix"]
     vocab = conf["vocab_size"]
-    cfg = TransformerConfig(**harness.program_config(conf))
-    key = harness.seed_key(seed)
-    make = jax.jit(lambda k: reference.init_params(
-        conf, k, **cell.get("weights", {})))
-    lm = TransformerLM.from_state(cfg, make(key))
-    engine = ServingEngine(model=lm, port=0, **cell.get("engine", {})).start()
-    try:
-        harness.say(f"set-up: engine up at {time.perf_counter() - t_start:.1f} s")
-        _warm(engine.port, mix, vocab, seed, t_start)
-        clients = traffic.chat_requests(mix, vocab, seed,
-                                        int(cell.get("per_client", 16)))
-        # spans of the program's own tracer: on in the traced run only, so
-        # that the end-to-end run pays nothing for them
-        obs_trace.set_enabled(True if trace else None)
-        obs_trace.tracer().clear()
-        loop = loadgen.ClosedLoop(engine.port, clients)
-        c0 = _counters(engine)
-        setup_s = time.perf_counter() - t_start
-        t0 = loop.start()
-        loop.pump(t0 + seconds, send_new=True)
-        t1 = time.perf_counter()
-        c1 = _counters(engine)
-        spans = [s for s in obs_trace.tracer().spans("serve.batch")
-                 if s["attrs"].get("kind") == "decode.paged"
-                 and s["duration_s"] is not None and t0 <= s["t_mono"] < t1]
-        traced = None
-        if trace:
-            logdir = harness.trace_dir()
-            trace_reduce.start(logdir)
-            ta = time.perf_counter()
-            loop.pump(ta + float(cell.get("trace_seconds", 3)),
-                      send_new=True)
-            traced = {"window_s": time.perf_counter() - ta,
-                      "logdir": logdir}
-            trace_reduce.stop()
-        loop.pump(time.perf_counter() + DRAIN_S, send_new=False)
-        loop.close()
-        kv = engine.kv_report()
-    finally:
-        obs_trace.set_enabled(None)
-        engine.stop(drain=True)
+    make = jax.jit(lambda k: served.init(conf, k, **cell.get("weights", {})))
+    with CompileLog() as compiles:
+        key = harness.seed_key(seed)
+        lm = served.model_of(jax.block_until_ready(make(key)))
+        t_weights = time.perf_counter()
+        engine = ServingEngine(model=lm, port=0,
+                               **cell.get("engine", {})).start()
+        try:
+            clients = traffic.chat_requests(mix, vocab, seed,
+                                            int(cell.get("per_client", 16)))
+            t_engine = time.perf_counter()
+            harness.say(f"set-up: engine up at {t_engine - t_start:.1f} s")
+            _warm(engine.port, mix, vocab, seed, t_start)
+            m = measure(engine, cell, clients, seconds, trace)
+            kv = engine.kv_report()
+            more = served.after_drain(engine) if served.after_drain else {}
+        finally:
+            obs_trace.set_enabled(None)
+            engine.stop(drain=True)
     peak = harness.memory_peak_bytes(chips)
+    t_clients, t0, t1 = m["t_clients"], m["t0"], m["t1"]
+    # where set-up went (`start_s`: the process, its imports and the look
+    # for the chip, before this job was called; it is part of the next);
+    # `programs` says how many executables set-up built, how many of them
+    # the persistent cache held, and the seconds they took
+    setup = {"start_s": t_enter - t_start,
+             "imports_weights_s": t_weights - t_start,
+             "engine_s": t_engine - t_weights,
+             "warm_s": t_clients - t_engine,
+             "programs": compiles.between(0.0, t_clients)}
 
     # ---- the window, as the clients saw it -------------------------------
-    mine = [r for r in loop.requests if t0 <= r.t_send < t1]
-    failed = [r for r in mine if not r.ok]
-    worst_ms = 1e3 * (seconds + DRAIN_S)
-    ttft = [1e3 * (r.token_times[0] - r.t_send)
-            if r.ok and r.token_times else worst_ms for r in mine]
-    arrivals = prefill_tokens = 0
-    gap_ms: List[float] = []
-    pairs = 0.0
-    for r in loop.requests:
-        n_p = len(r.spec["tokens"])
-        times = r.token_times
-        if times and t0 <= times[0] < t1:
-            prefill_tokens += n_p
-            pairs += n_p * (n_p + 1) / 2
-        for i, t in enumerate(times):
-            if t0 <= t < t1:
-                arrivals += 1
-                pairs += n_p + i
-                if i > 0:
-                    gap_ms.append(1e3 * (t - times[i - 1]))
-    window = {"seconds": t1 - t0, "requests": len(mine),
-              "prefill_tokens": prefill_tokens,
-              "decode_tokens": arrivals, "attended_pairs": pairs,
-              "ttft_p50_ms": float(np.median(ttft)) if ttft else None,
-              "gap_p50_ms": float(np.median(gap_ms)) if gap_ms else None,
-              "gaps": len(gap_ms)}
-    counters = {k: c1[k] - c0[k] for k in c0}
+    seen = window.read(m["requests"], t0, t1, seconds)
+    mine, failed = seen["mine"], seen["failed"]
+    built = {"compiles": compiles.between(t0, t1)["programs"],
+             "compiles_warm_in": compiles.between(t_clients, t0)["programs"]}
+    seen["window"].update(warm_in_s=t0 - t_clients, **built)
 
     # ---- correct ----------------------------------------------------------
     del engine, lm
@@ -179,41 +253,32 @@ def run(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
         len(r.tokens) != r.spec["n_new"]
         or any(not (isinstance(t, int) and 0 <= t < vocab)
                for t in r.tokens))]
-    greedy = [r for r in mine if r.ok and r.spec["temperature"] == 0.0
-              and r not in malformed]
+    picked = sample_of([r for r in mine if r.ok
+                        and r.spec["temperature"] == 0.0
+                        and r not in malformed],
+                       int(cell.get("compare_requests", 8)), seed)
+    sample = [(r.spec["tokens"], r.tokens) for r in picked]
     gaps: List[float] = []
-    sample: List[Any] = []
-    if greedy:
-        rng = np.random.default_rng([int(seed), 11])
-        greedy.sort(key=lambda r: -(len(r.spec["tokens"]) + len(r.tokens)))
-        n = min(int(cell.get("compare_requests", 8)), len(greedy))
-        sample = [greedy[0]] + [greedy[i] for i in sorted(
-            1 + rng.choice(len(greedy) - 1, n - 1, replace=False))] \
-            if n > 1 else greedy[:1]
-        width = reference_width(mix)
+    if sample:
         t_ref = time.perf_counter()
-        params = make(key)
-        for r in sample:
-            gaps += reference.serve_gaps(conf, params, r.spec["tokens"],
-                                         r.tokens, width).tolist()
-        del params
+        gaps = served.gaps(cell, make(key), sample)
         harness.say(f"reference: {len(sample)} requests, {len(gaps)} tokens "
                     f"in {time.perf_counter() - t_ref:.1f} s")
-    checks = compare.serve_checks(gaps, len(malformed),
-                                  cell.get("limits", {}))
-    checks["unanswered"] = {"value": float(len(unanswered)), "limit": 0.0,
-                            "ok": not unanswered}
+    limits = cell.get("limits", {})
+    checks = compare.serve_checks(gaps, len(malformed), limits)
+    compare.exact(checks, "unanswered", len(unanswered))
+    # a program built once the clients run is work that set-up left out:
+    # in the warm-in it is in no metric, in the window it is in every one
+    compare.exact(checks, "compiles_in_window", sum(built.values()))
+    for name, value in more.items():
+        compare._check(checks, name, value, limits)
     for r in failed[:3]:
         harness.say(f"failed request: {r.error}")
     return {
         "attempted": len(mine), "failed": len(failed),
-        "end_to_end": {
-            "serve_tokens_per_s": arrivals / (t1 - t0),
-            "ttft_p95_ms": _p95(ttft) if ttft else worst_ms,
-            "gap_p95_ms": _p95(gap_ms) if gap_ms else worst_ms,
-            "setup_s": setup_s,
-        },
-        "window": window, "counters": counters, "spans": spans,
-        "traced": traced, "memory_peak_bytes": peak, "checks": checks,
-        "kv": kv, "sample": [(r.spec["tokens"], r.tokens) for r in sample],
+        "end_to_end": dict(seen["end_to_end"], setup_s=t_clients - t_start),
+        "window": seen["window"], "setup": setup, "t0": t0,
+        "counters": m["counters"], "spans": m["spans"],
+        "traced": m["traced"], "memory_peak_bytes": peak, "checks": checks,
+        "kv": kv, "sample": sample,
     }

@@ -85,6 +85,11 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
 
     result["correct"] = compare.all_ok(out["checks"])
     result["jax"] = jax.__version__
+    # what the job read of its window and of its set-up, as it read it: a
+    # serve job's every candidate for a tail, where set-up went
+    for key in ("window", "setup"):
+        if key in out:
+            result[key] = out[key]
     result["checks"] = out["checks"]       # comes last in the line
     harness.print_checks(out["checks"])
     return result

@@ -50,10 +50,10 @@ def window(ctx: Dict[str, Any]) -> Optional[float]:
     or None where the ring cannot be trusted to hold every span up to it:
     the window has no tick, or the ring dropped spans since the job cleared
     it (a median over a truncated window is worse than none), or the tracer
-    keeps no such count (a program from before these spans). The window has
-    no lower edge to cut at: the job clears the ring as the window opens,
-    and the opening burst's queue waits and admissions, which start before
-    the first tick does, belong to it."""
+    keeps no such count (a program from before these spans). The lower edge
+    is the window's own (`ctx["t0"]`, where the job clears the ring): a queue
+    wait or an admission that starts before the window's first tick belongs
+    to it, one that started in the warm-in does not."""
     from deeplearning4j_tpu.obs import trace as obs_trace
 
     ticks = ctx.get("spans") or []
@@ -70,8 +70,9 @@ def window_spans(ctx: Dict[str, Any], name: str
     until = window(ctx)
     if until is None:
         return None
+    since = ctx.get("t0", float("-inf"))
     return [s for s in obs_trace.tracer().spans(name)
-            if s["duration_s"] is not None and s["t_mono"] <= until]
+            if s["duration_s"] is not None and since <= s["t_mono"] <= until]
 
 
 def duration_ms(ctx: Dict[str, Any], name: str,

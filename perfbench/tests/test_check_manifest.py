@@ -23,6 +23,39 @@ def test_every_layer_is_one_of_the_five(manifest):
     assert layers <= {"engine", "decoder", "model_step", "kernels", "device"}
 
 
+def test_set_up_says_where_it_went_in_the_serve_cells(manifest):
+    rows = {m["name"]: m for m in manifest["per_layer"]}
+    serve = [w["name"] for w in manifest["workloads"]
+             if w["name"].startswith("serve")]
+    for name in ("setup_build_s.serve", "setup_warm_s.serve"):
+        m = rows[name]
+        assert (m["moves"], m["layer"], m["source"], m["unit"]) == (
+            "setup_s", "engine", "host_clock", "s")
+        assert m["workloads"] == serve
+    build, warm = (harness.load_reader(n).read for n in
+                   ("setup_build_s.serve", "setup_warm_s.serve"))
+    ctx = {"setup": {"imports_weights_s": 9.5, "engine_s": 0.5,
+                     "warm_s": 4.0, "programs": {"programs": 12}}}
+    assert build(ctx) == 10.0 and warm(ctx) == 4.0
+    # a job that stamps nothing (the train job) gives no number
+    assert build({}) is None and warm({}) is None
+
+
+def test_the_serve_cells_say_when_their_window_opens(manifest):
+    for w in manifest["workloads"]:
+        cell = harness.load_cell(w["name"])
+        if cell["job"].startswith("serve"):
+            assert cell["warm_in_seconds"] == 3
+            assert "warm_in_seconds" in cell["assumed"]
+            assert "window after a 3 s warm-in" in w["why"]
+    tails = [m for m in manifest["end_to_end"]
+             if m["name"].startswith("ttft_")]
+    assert len(tails) == 1 and tails[0]["bound"] <= 0.1
+    moved = {m["moves"] for m in manifest["per_layer"]
+             if m["moves"].startswith("ttft_")}
+    assert moved == {tails[0]["name"]}
+
+
 def _broken(manifest, edit):
     m = copy.deepcopy(manifest)
     edit(m)

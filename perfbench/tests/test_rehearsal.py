@@ -72,8 +72,38 @@ def test_serve_cell_end_to_end(manifest, trace):
         assert {"decode_tick_p50_ms.serve", "tokens_per_tick.serve",
                 "prefix_hit_share.serve"} <= set(line["metrics"])
     else:
-        assert set(line["metrics"]) == {"serve_tokens_per_s", "ttft_p95_ms",
+        assert set(line["metrics"]) == {"serve_tokens_per_s", "ttft_p90_ms",
                                         "gap_p95_ms", "setup_s"}
+
+
+def test_a_serve_result_says_where_set_up_went_and_what_the_window_held(
+        manifest):
+    line = json.loads(json.dumps(_run("tiny-serve", manifest, True,
+                                      seconds=2.0)))
+    got, e2e = line["metrics"], line["end_to_end_of_this_run"]
+    # the two parts of set-up are all of it
+    assert got["setup_build_s.serve"]["value"] \
+        + got["setup_warm_s.serve"]["value"] == pytest.approx(e2e["setup_s"])
+    assert set(line["setup"]) == {"start_s", "imports_weights_s", "engine_s",
+                                  "warm_s", "programs"}
+    assert line["setup"]["programs"]["programs"] >= 0
+    w = line["window"]
+    # the clients ran the cell's warm_in_seconds before the window opened,
+    # and nothing was built once they ran
+    # (and the counters' baseline was read, which a busy test run slows)
+    assert 0.5 <= w["warm_in_s"] < 1.0
+    assert w["compiles"] == 0 and w["compiles_warm_in"] == 0
+    assert line["checks"]["compiles_in_window"] == {
+        "value": 0.0, "limit": 0.0, "ok": True}
+    assert line["attempted"] == w["requests"]
+    # every candidate for a tail from the one list, in order
+    assert w["ttft_p50_ms"] <= w["ttft_p90_ms"] <= w["ttft_p95_ms"] \
+        <= w["ttft_p99_ms"]
+    assert w["ttft_p90_ms"] <= w["ttft_slow10_mean_ms"]
+    assert w["gap_p50_ms"] <= w["gap_p95_ms"] <= w["gap_p99_ms"]
+    # the end-to-end metrics of a serve run are the window's own
+    assert e2e["ttft_p90_ms"] == w["ttft_p90_ms"]
+    assert e2e["gap_p95_ms"] == w["gap_p95_ms"]
 
 
 def test_the_toy_manifest_is_sound_but_for_its_files(manifest):
@@ -143,6 +173,25 @@ def test_fault_a_token_altered_where_it_is_produced(manifest, monkeypatch):
     line = _run("tiny-serve", manifest, seconds=2.0)
     assert line["correct"] is False
     assert not line["checks"]["logit_gap"]["ok"]
+
+
+def test_fault_a_prefill_width_the_warm_up_left_out(manifest, monkeypatch):
+    """A warm-up that reaches the shortest prompt's width alone: the others
+    are built once the clients run, which no metric owns up to, and
+    `correct` has to be false by `compiles_in_window`."""
+    from deeplearning4j_tpu.serving import paged
+
+    monkeypatch.setattr(traffic, "warm_lengths", lambda mix: [
+        mix["system_tokens"] + mix["user_tokens"]["min"]])
+    # programs of earlier tests in this process would stand in for set-up's
+    monkeypatch.setattr(paged, "_PAGED_ADMIT_CACHE", {})
+    line = _run("tiny-serve", manifest, seconds=2.0)
+    assert line["correct"] is False
+    built = line["checks"]["compiles_in_window"]
+    assert built["value"] >= 1 and not built["ok"]
+    assert built["value"] == line["window"]["compiles"] \
+        + line["window"]["compiles_warm_in"]
+    assert line["checks"]["logit_gap"]["ok"]
 
 
 def test_fault_an_answer_cut_short(manifest, monkeypatch):

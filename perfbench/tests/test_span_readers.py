@@ -201,15 +201,16 @@ def test_idle_readers_need_device_operations_and_annotations(name):
                          "traced": {"window_s": 3.0}}) is None
 
 
-def test_manifest_lists_the_new_metrics_for_the_serve_cell_only():
+def test_manifest_lists_the_new_metrics_for_serve_cells_only():
     manifest = harness.load_manifest()
     rows = {m["name"]: m for m in manifest["per_layer"]}
     for name in NEW:
-        assert rows[name]["workloads"] == ["serve-590m-chat"]
+        # found by name: later PRs append to the manifest and list further
+        # cells, so neither the place nor the whole list is pinned
+        assert "serve-590m-chat" in rows[name]["workloads"]
+        assert not any(w.startswith("train") for w in rows[name]["workloads"])
         assert os.path.exists(os.path.join(harness.HERE, "metrics",
                                            name + ".py"))
-    # appended, the nine that were there untouched and first
-    assert [m["name"] for m in manifest["per_layer"]][9:] == list(NEW)
 
 
 def test_toy_serve_run_reports_the_span_metrics():

@@ -3,6 +3,7 @@ within their clips, and every seed gets the same sizes in the same order."""
 import os
 
 import numpy as np
+import pytest
 
 from perfbench import harness, traffic
 
@@ -59,5 +60,17 @@ def test_prompts_open_with_one_of_the_system_prompts():
 
 def test_warm_lengths_reach_both_ends():
     ls = traffic.warm_lengths(CHAT)
-    assert ls[0] == 160 and ls[-1] == 992
-    assert all(b - a <= 16 for a, b in zip(ls, ls[1:]))
+    assert ls[0] == 160 and ls[-1] == 992 and ls == sorted(set(ls))
+    # never more than a quarter longer than the last: no rung of a ladder
+    # whose rungs lie a quarter apart or more is stepped over
+    assert all(b <= 1.25 * a for a, b in zip(ls, ls[1:]))
+    assert len(ls) == 12
+
+
+@pytest.mark.parametrize("lo, hi", [(1, 9), (3, 200), (16, 448), (100, 101),
+                                    (7, 7)])
+def test_warm_lengths_step_a_quarter_at_the_most(lo, hi):
+    mix = {"system_tokens": 0, "user_tokens": {"min": lo, "max": hi}}
+    ls = traffic.warm_lengths(mix)
+    assert ls[0] == lo and ls[-1] == hi
+    assert all(a < b <= max(a + 1, 1.25 * a) for a, b in zip(ls, ls[1:]))

@@ -102,8 +102,9 @@ def serve_seed(cell, seed, control: bool, seconds: float):
             cell["conf"], k, **cell.get("weights", {})))(
                 harness.seed_key(seed))
         for lowp in CONTROLS:
-            gaps = job.reference_gaps(cell, reference, params,
-                                      out["sample"], lowp=lowp)
+            gaps = job.reference_gaps(cell, params,
+                                      out["sample"], reference=reference,
+                                      lowp=lowp)
             row["control_" + lowp] = values(
                 compare.serve_checks(gaps, 0, {}))
             row["control_" + lowp]["logits_moved"] = logits_moved(

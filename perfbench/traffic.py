@@ -66,9 +66,20 @@ def chat_requests(mix: Dict[str, Any], vocab: int, seed: int,
     return clients
 
 
-def warm_lengths(mix: Dict[str, Any], step: int = 16) -> List[int]:
-    """Prompt lengths that reach every prefill shape the mix can: from its
-    shortest prompt to its longest, every `step` tokens."""
+def warm_lengths(mix: Dict[str, Any]) -> List[int]:
+    """Prompt lengths that reach every prefill width the mix can: from its
+    shortest prompt to its longest, the next never more than a quarter
+    longer than the last (the step at length n is the largest power of two
+    that is at most n / 4). A program pads a prompt up to the next rung of
+    a ladder of widths; where no rung is less than a quarter above the one
+    below (the program's are powers of two and one and a half times them,
+    so a third at the least) some length here falls on every rung a prompt
+    of the mix can, and few fall on the same: one request a length is what
+    the warm-up costs in every run."""
     lo = mix["system_tokens"] + mix["user_tokens"]["min"]
     hi = mix["system_tokens"] + mix["user_tokens"]["max"]
-    return sorted(set(list(range(lo, hi, step)) + [hi]))
+    out, n = [], lo
+    while n < hi:
+        out.append(n)
+        n += 1 << max(0, (n // 4).bit_length() - 1)
+    return out + [hi]
