@@ -1,19 +1,31 @@
-"""A serve-only hybrid LM: Mamba-2 layers beside grouped-query attention.
-
-The published shape is `granitemoehybrid` without experts (IBM Granite
-4.0-H): a per-layer pattern of two mixer kinds under one residual stream,
-RMS normalisation, a gated SiLU feed-forward without biases, no positional
-encoding, four scalar multipliers (embedding, residual, attention, logits).
+"""A serve-only LM block with mixers and feed-forwards of several kinds
+under one residual stream: Mamba-2 layers beside grouped-query attention
+(`granitemoehybrid` without experts, IBM Granite 4.0-H), and attention whose
+layers differ in their own data, window and rotary positions, over routed
+experts (`smallthinker`, PowerInfer SmallThinker). A layer names its mixer
+(``layer_types``: ``mamba``, or ``attention`` with ``rope`` and ``window`` as
+that layer's data) and the model its feed-forward (``ffn``: ``gated`` with
+its activation, or ``experts``); RMS normalisation throughout, no biases,
+four scalar multipliers (embedding, residual, attention, logits; 1 where a
+family has none), the head tied to the embedding or a matrix of its own.
 With ``u`` a layer's normalised input:
 
-  * every layer: ``h += r * Mixer(RMSNorm(h))`` then
-    ``h += r * W_down(silu(g) * v)`` with ``[g | v] = W_up RMSNorm(h)``;
-    logits ``RMSNorm(h) E^T / logits_scaling``, head tied to the embedding
-    ``h0 = embedding_multiplier * E[tok]``.
-  * attention: ``q = W_q u`` as ``n_heads`` heads, ``k, v`` as
-    ``n_kv_heads`` heads (KV head j serves query heads g*j .. g*j+g-1), no
-    bias, no rotary; scores ``attention_multiplier * q k^T``, causal,
-    softmax in float32.
+  * every layer: ``h += r * Mixer(u)``, ``u = RMSNorm(h)``, then the
+    feed-forward on ``x = RMSNorm(h)``: gated, ``h += r * W_down(act(g) *
+    v)`` with ``[g | v] = W_up x``; or routed, ``h += r * sum over e in I
+    of w_e W_down,e (act(W_gate,e x) * W_up,e x)`` where ``I`` are the
+    ``moe_top_k`` largest router logits ``W_r u`` (float32; the router reads
+    the layer's normalised input BEFORE the mixer) and ``w`` the softmax
+    over the chosen: dropless, every expert held here
+    (parallel/expert_parallel.dropless_experts). Logits ``W_head
+    RMSNorm(h) / logits_scaling``, ``h0 = embedding_multiplier * E[tok]``.
+  * attention: ``q = W_q u`` as ``n_heads`` heads of ``head_dim``, ``k, v``
+    as ``n_kv_heads`` heads (KV head j serves query heads g*j .. g*j+g-1),
+    no bias; where the layer has ``rope``, rotary positions on q and k over
+    the whole head (theta ``rope_theta``, the two halves of a head paired)
+    before K is stored; scores ``attention_multiplier * q k^T``, causal,
+    and in a layer with a ``window`` w only ``t - w < s <= t``; softmax in
+    float32.
   * Mamba-2 (one group): ``[z | xBC | dt] = W_in u``; ``xBC =
     silu(conv(xBC))``, a causal depthwise convolution of width ``ssm_conv``
     with bias; ``xBC -> x [H, P], B [N], C [N]``; ``delta = softplus(dt +
@@ -24,15 +36,28 @@ With ``u`` a layer's normalised input:
 Two computations of the recurrence, one result: the admission prefill runs
 it in chunks (``ssm_chunk``; inside a chunk a masked matrix product, between
 chunks the carried ``S``), the decode tick one step on the lane's stored
-``S`` and its last ``ssm_conv - 1`` rows of ``xBC``.
+``S`` and its last ``ssm_conv - 1`` rows of ``xBC``. Two of attention too:
+the admission attends a whole prompt by blocks of query rows over chunks of
+keys (``attention_full``: no ``[H, T, T]`` scores at 8,192 positions, a
+window layer reads its band), the tick one query a lane over the lane's
+blocks (serving/paged.chunked_attention, with a lower bound a lane in a
+window layer). And two of the expert layer, by the rows: every expert on
+every lane in one batched product in the tick, rows sorted by expert through
+grouped products in the admission.
 
 What is held where. Weights once, in the config's compute dtype (bfloat16
 under ``dtype_policy="performance"``): no float32 masters, no optimizer
 state, so ``fit`` refuses. Leaves are stacked by kind on a leading layer
-axis (``params["mamba"]``, ``params["attn"]``, ``params["mlp"]``). Per
+axis (``params["mamba"]``, ``params["attn"]``, ``params["mlp"]`` or
+``params["moe"]``), but for the experts' two matrices, ONE BUFFER A LAYER:
+a layer sliced out of a stack is copied for the grouped kernel. Per
 request the model tells the paged decoder (``cache_needs``) that it keeps
 keys and values for its attention layers only, with ``n_kv_heads`` heads,
-and two state leaves a lane: ``ssm`` ``[H, P, N]`` float32 and ``conv``
+in one KV group a distinct window (the layers that see every position, the
+layers of each window: a pool and a per-lane table each, a window group's
+lane holding only the blocks its window reaches),
+and two state leaves a lane where it has Mamba layers: ``ssm`` ``[H, P, N]``
+float32 and ``conv``
 ``[ssm_conv - 1, conv_dim]`` in the compute dtype, ONE BUFFER A LAYER
 (``[lanes, ...]`` each) so that the tick rewrites each in place and nothing
 restacks 4 GB of state. They ride in the decoder's arena pytree beside
@@ -48,16 +73,20 @@ BEFORE it: ``n_state = keep - 1`` real tokens feed the state, every later
 position of the bucket carries ``delta = 0`` and feeds nothing into ``S``
 or the conv tail.
 
-Activations: the residual stream, the norms, ``delta``, the decay and ``S``
+Activations: the residual stream, the norms, the router's logits and
+weights, the rotary angles, ``delta``, the decay and ``S``
 are float32; every matrix product reads its activation in the weights'
 dtype and accumulates in float32; ``xBC`` is rounded to the compute dtype
 where it leaves the projection, which is what the stored tail holds.
 
 Reference anchor: none in the DL4J 0.4 reference, whose recurrent layers
-are LSTM and GRU cells (nn/layers/recurrent/GravesLSTM.java); provenance
-is Dao & Gu, "Transformers are SSMs" (Mamba-2 and its chunked dual form)
-and the published `granitemoehybrid` config.json. The plain float32 form
-of the same equations is perfbench/reference_granite.py.
+are LSTM and GRU cells (nn/layers/recurrent/GravesLSTM.java) and which has
+neither attention nor experts; provenance
+is Dao & Gu, "Transformers are SSMs" (Mamba-2 and its chunked dual form),
+Su et al. (rotary positions), Beltagy et al. (sliding windows), Gale et
+al. (dropless experts) and the published `granitemoehybrid` and
+`smallthinker` config.json. The plain float32 forms of the same equations
+are perfbench/reference_granite.py and perfbench/reference_smallthinker.py.
 """
 from __future__ import annotations
 
@@ -71,10 +100,12 @@ import numpy as np
 from jax import lax
 
 from deeplearning4j_tpu.ops import dispatch
-from deeplearning4j_tpu.ops.memory import CacheNeeds, StateLeaf
+from deeplearning4j_tpu.ops.memory import CacheNeeds, KVGroup, StateLeaf
 
 Params = Dict[str, Any]
 MAMBA, ATTENTION = "mamba", "attention"
+GATED, EXPERTS = "gated", "experts"
+ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,23 +131,69 @@ class HybridConfig:
     logits_scaling: float = 8.0
     rms_eps: float = 1e-5
     dtype_policy: str = "performance"   # "strict": float32 weights (tests)
-    moe_experts: int = 0                # none: the decode pools ask
+    # an attention layer's own data, one entry an ATTENTION layer in the
+    # model's order (empty: none has it): rotary positions on q and k
+    # (theta ``rope_theta``, the two halves of a head paired), and how many
+    # of the newest positions it sees, the token's own among them (0: all)
+    attn_head_dim: int = 0              # 0: d_model // n_heads
+    rope: Tuple[bool, ...] = ()
+    rope_theta: float = 10000.0
+    window: Tuple[int, ...] = ()
+    # the admission's attention multiplies in float32 (True), or reads q and
+    # the probabilities in the arena's dtype as K and V are stored (False)
+    attn_exact: bool = True
+    # the feed-forward of every layer: GATED (``d_ff`` wide, W_up = gate |
+    # up) or EXPERTS (``moe_experts`` routed ones of width ``d_ff``, the
+    # ``moe_top_k`` largest router logits a token, softmax over the chosen,
+    # dropless: parallel/expert_parallel.dropless_experts; the router reads
+    # the layer's normalised input before the mixer); ``ffn_act`` gates both
+    ffn: str = "gated"
+    ffn_act: str = "silu"
+    moe_experts: int = 0
+    moe_top_k: int = 0
+    tie_head: bool = True               # logits against the embedding
+
+    # what the decode pools ask of an expert layer: no row is dropped and a
+    # row's output is its own, whoever shares its batch
+    moe_dropless = True
 
     def __post_init__(self):
         bad = [t for t in self.layer_types if t not in (MAMBA, ATTENTION)]
         if bad or not self.layer_types:
             raise ValueError(f"layer_types must name {MAMBA!r} or "
                              f"{ATTENTION!r} for every layer, got {bad}")
-        if self.d_model % self.n_heads or self.n_heads % self.n_kv_heads:
+        if (not self.attn_head_dim and self.d_model % self.n_heads) \
+                or self.n_heads % self.n_kv_heads:
             raise ValueError(
                 f"d_model {self.d_model} / n_heads {self.n_heads} / "
                 f"n_kv_heads {self.n_kv_heads} do not divide")
+        for name in ("rope", "window"):
+            if getattr(self, name) and \
+                    len(getattr(self, name)) != self.n_attention:
+                raise ValueError(f"{name} has one entry an attention layer "
+                                 f"({self.n_attention}), or none")
+        if self.ffn not in (GATED, EXPERTS) or self.ffn_act not in ACTS:
+            raise ValueError(f"ffn {self.ffn!r} / ffn_act {self.ffn_act!r}: "
+                             f"one of {(GATED, EXPERTS)} / {sorted(ACTS)}")
+        if self.ffn == EXPERTS and self.n_mamba:
+            raise ValueError("routed experts beside Mamba layers: not "
+                             "computed (the Mamba runs loop over layers "
+                             "by index, an expert layer's buffers are its "
+                             "own)")
+        if (self.ffn == EXPERTS) != bool(self.moe_experts) or \
+                not 0 <= self.moe_top_k <= self.moe_experts:
+            raise ValueError(
+                f"ffn {self.ffn!r} with {self.moe_experts} experts, top "
+                f"{self.moe_top_k}")
 
     @classmethod
     def from_published(cls, conf: Dict[str, Any], *, max_len: int,
                        dtype_policy: str = "performance") -> "HybridConfig":
-        """From a `granitemoehybrid` config.json's own keys. Whatever of
-        the family this module does not compute is refused here."""
+        """From a published config.json's own keys: `granitemoehybrid`
+        (the default) or `smallthinker` (``model_type``). Whatever of a
+        family this module does not compute is refused here."""
+        if conf.get("model_type") == "smallthinker":
+            return cls._from_smallthinker(conf, int(max_len), dtype_policy)
         refused = [
             (conf.get("num_local_experts", 0) != 0, "experts"),
             (conf.get("mamba_n_groups", 1) != 1, "mamba_n_groups != 1"),
@@ -152,6 +229,48 @@ class HybridConfig:
             logits_scaling=float(conf["logits_scaling"]),
             rms_eps=float(conf["rms_norm_eps"]), dtype_policy=dtype_policy)
 
+    @classmethod
+    def _from_smallthinker(cls, conf, max_len, dtype_policy):
+        """`smallthinker`: every layer attention over grouped heads and
+        routed ReGLU experts; ``rope_layout`` and ``sliding_window_layout``
+        say layer by layer which have rotary positions and the window.
+        ``n_layer`` (where the file has it) is how many of the
+        ``num_hidden_layers`` are held here, from layer 0 on."""
+        n = int(conf.get("n_layer", conf["num_hidden_layers"]))
+        ropes = conf["rope_layout"][:n]
+        windows = conf["sliding_window_layout"][:n]
+        refused = [
+            (conf.get("rope_scaling") is not None, "rope_scaling"),
+            (not conf.get("moe_primary_router_apply_softmax", True),
+             "a router without the softmax over the chosen"),
+            (len(ropes) != n or len(windows) != n,
+             "layouts shorter than the layers held"),
+            (max_len > conf["max_position_embeddings"],
+             "a served context past max_position_embeddings"),
+        ]
+        bad = [what for is_bad, what in refused if is_bad]
+        if bad:
+            raise ValueError("HybridLM does not compute: " + ", ".join(bad))
+        return cls(
+            vocab_size=conf["vocab_size"], d_model=conf["hidden_size"],
+            layer_types=(ATTENTION,) * n,
+            n_heads=conf["num_attention_heads"],
+            n_kv_heads=conf["num_key_value_heads"],
+            attn_head_dim=conf["head_dim"],
+            d_ff=conf["moe_ffn_hidden_size"], max_len=max_len,
+            rope=tuple(bool(r) for r in ropes),
+            rope_theta=float(conf["rope_theta"]),
+            window=tuple(int(conf["sliding_window_size"]) if w else 0
+                         for w in windows),
+            attn_exact=False, ffn=EXPERTS, ffn_act="relu",
+            moe_experts=conf["moe_num_primary_experts"],
+            moe_top_k=conf["moe_num_active_primary_experts"],
+            tie_head=bool(conf["tie_word_embeddings"]),
+            embedding_multiplier=1.0, residual_multiplier=1.0,
+            attention_multiplier=float(conf["head_dim"]) ** -0.5,
+            logits_scaling=1.0, rms_eps=float(conf["rms_norm_eps"]),
+            dtype_policy=dtype_policy)
+
     # -- sizes ------------------------------------------------------------
     @property
     def compute_dtype(self):
@@ -172,7 +291,17 @@ class HybridConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.attn_head_dim or self.d_model // self.n_heads
+
+    @property
+    def q_dim(self) -> int:
+        return self.n_heads * self.head_dim
+
+    def rope_of(self, j: int) -> bool:
+        return bool(self.rope) and self.rope[j]
+
+    def window_of(self, j: int) -> int:
+        return self.window[j] if self.window else 0
 
     @property
     def d_inner(self) -> int:
@@ -200,16 +329,26 @@ class HybridConfig:
         return out
 
     # -- what the paged decoder asks (serving/paged.py) -------------------
+    def kv_groups(self) -> Tuple[KVGroup, ...]:
+        """The attention layers by how they page: one group a distinct
+        window (0, every position, first), each with its layers' indices
+        among the attention layers."""
+        sizes = sorted({self.window_of(j) for j in range(self.n_attention)})
+        return tuple(
+            KVGroup(tuple(j for j in range(self.n_attention)
+                          if self.window_of(j) == w), w) for w in sizes)
+
     def cache_needs(self) -> CacheNeeds:
         return CacheNeeds(
             kv_layers=self.n_attention, kv_heads=self.n_kv_heads,
-            head_dim=self.head_dim,
+            head_dim=self.head_dim, groups=self.kv_groups(),
             state=(StateLeaf("ssm", self.n_mamba,
                              (self.ssm_heads, self.ssm_head_dim,
                               self.ssm_state), "float32"),
                    StateLeaf("conv", self.n_mamba,
                              (self.ssm_conv - 1, self.conv_dim),
-                             jnp.dtype(self.compute_dtype).name)),
+                             jnp.dtype(self.compute_dtype).name))
+            if self.n_mamba else (),
             kv_per_layer=True)
 
     def paged_decode_step(self, params, arena, tok, pos, tables):
@@ -217,6 +356,11 @@ class HybridConfig:
 
     def paged_admit(self, params, arena, window, write_table, lane):
         return paged_admit(params, arena, window, write_table, lane, self)
+
+    @property
+    def moe_rows_per_token(self) -> int:
+        """(token, expert) rows a token makes through the expert layers."""
+        return self.moe_top_k * self.n_layers if self.ffn == EXPERTS else 0
 
     def scan_chunks(self, width: int) -> int:
         """Chunks the admission prefill's scan walks at a bucket width."""
@@ -229,23 +373,32 @@ class HybridConfig:
 
 
 def param_shapes(cfg: HybridConfig) -> Params:
-    """Every leaf's shape, stacked by layer kind."""
+    """Every leaf's shape, stacked by layer kind; the experts' matrices
+    (``moe.W_in`` = gate | up, ``moe.W_down``) one buffer a LAYER: the
+    grouped product is a kernel call, and a layer sliced out of a stack is
+    copied for it (0.75 GB a layer at the served size), where a buffer of
+    its own is read in place."""
     d, f, nm, na, L = cfg.d_model, cfg.d_ff, cfg.n_mamba, cfg.n_attention, \
         cfg.n_layers
-    kv = cfg.n_kv_heads * cfg.head_dim
+    kv, qd, e = cfg.n_kv_heads * cfg.head_dim, cfg.q_dim, cfg.moe_experts
+    ffn = {"mlp": {"norm2": (L, d), "W_up": (L, d, 2 * f),
+                   "W_down": (L, f, d)}} if cfg.ffn == GATED else \
+        {"moe": {"norm2": (L, d), "router": (L, d, e),
+                 "W_in": ((e, d, 2 * f),) * L, "W_down": ((e, f, d),) * L}}
+    head = {} if cfg.tie_head else {"head": (cfg.vocab_size, d)}
+    mamba = {"mamba": {
+        "norm1": (nm, d), "W_in": (nm, d, cfg.in_dim),
+        "conv_w": (nm, cfg.ssm_conv, cfg.conv_dim),
+        "conv_b": (nm, cfg.conv_dim), "dt_bias": (nm, cfg.ssm_heads),
+        "A_log": (nm, cfg.ssm_heads), "D": (nm, cfg.ssm_heads),
+        "norm_y": (nm, cfg.d_inner), "W_out": (nm, cfg.d_inner, d)}} \
+        if nm else {}
     return {
-        "embed": (cfg.vocab_size, d), "norm_f": (d,),
-        "mamba": {
-            "norm1": (nm, d), "W_in": (nm, d, cfg.in_dim),
-            "conv_w": (nm, cfg.ssm_conv, cfg.conv_dim),
-            "conv_b": (nm, cfg.conv_dim), "dt_bias": (nm, cfg.ssm_heads),
-            "A_log": (nm, cfg.ssm_heads), "D": (nm, cfg.ssm_heads),
-            "norm_y": (nm, cfg.d_inner), "W_out": (nm, cfg.d_inner, d)},
+        "embed": (cfg.vocab_size, d), "norm_f": (d,), **head, **ffn,
+        **mamba,
         "attn": {
-            "norm1": (na, d), "Wq": (na, d, d), "Wk": (na, d, kv),
-            "Wv": (na, d, kv), "Wo": (na, d, d)},
-        "mlp": {"norm2": (L, d), "W_up": (L, d, 2 * f),
-                "W_down": (L, f, d)},
+            "norm1": (na, d), "Wq": (na, d, qd), "Wk": (na, d, kv),
+            "Wv": (na, d, kv), "Wo": (na, qd, d)},
     }
 
 
@@ -254,7 +407,9 @@ def init_params(cfg: HybridConfig, key) -> Params:
     Matrices Xavier-normal; norm scales 1; ``A_log = log(uniform(1,
     16))``, ``dt_bias`` the inverse softplus of a step log-uniform in
     [1e-3, 1e-1], ``D = 1``, the conv kernel uniform in +-1/sqrt(width)
-    (the Mamba-2 family's convention)."""
+    (the Mamba-2 family's convention). A size for tests and examples: a
+    benchmark cell's weights are the plain reference's to make, leaf by
+    leaf (perfbench/reference_*.py)."""
     shapes = param_shapes(cfg)
     ks = iter(jax.random.split(key, 16))
     f32 = jnp.float32
@@ -264,15 +419,18 @@ def init_params(cfg: HybridConfig, key) -> Params:
         return jax.random.normal(next(ks), shape, f32) * np.float32(std)
 
     ones = lambda shape: jnp.ones(shape, f32)
-    m, a, p = shapes["mamba"], shapes["attn"], shapes["mlp"]
-    dt = jnp.exp(jax.random.uniform(next(ks), m["dt_bias"], f32,
-                                    np.log(1e-3), np.log(1e-1)))
-    bound = 1.0 / np.sqrt(cfg.ssm_conv)
-    out = {
-        "embed": jax.random.normal(next(ks), shapes["embed"], f32)
-        * np.float32(0.02),
-        "norm_f": ones(shapes["norm_f"]),
-        "mamba": {
+    a = shapes["attn"]
+    out = {}
+    if "mamba" in shapes:
+        m = shapes["mamba"]
+        dt = jnp.exp(jax.random.uniform(next(ks), m["dt_bias"], f32,
+                                        np.log(1e-3), np.log(1e-1)))
+        bound = 1.0 / np.sqrt(cfg.ssm_conv)
+    out["embed"] = jax.random.normal(next(ks), shapes["embed"], f32) \
+        * np.float32(0.02)
+    out["norm_f"] = ones(shapes["norm_f"])
+    if "mamba" in shapes:
+        out["mamba"] = {
             "norm1": ones(m["norm1"]), "W_in": xavier(m["W_in"]),
             "conv_w": jax.random.uniform(next(ks), m["conv_w"], f32,
                                          -bound, bound),
@@ -281,13 +439,27 @@ def init_params(cfg: HybridConfig, key) -> Params:
             "A_log": jnp.log(jax.random.uniform(next(ks), m["A_log"], f32,
                                                 1.0, 16.0)),
             "D": ones(m["D"]), "norm_y": ones(m["norm_y"]),
-            "W_out": xavier(m["W_out"])},
-        "attn": {"norm1": ones(a["norm1"]), "Wq": xavier(a["Wq"]),
-                 "Wk": xavier(a["Wk"]), "Wv": xavier(a["Wv"]),
-                 "Wo": xavier(a["Wo"])},
-        "mlp": {"norm2": ones(p["norm2"]), "W_up": xavier(p["W_up"]),
-                "W_down": xavier(p["W_down"])},
-    }
+            "W_out": xavier(m["W_out"])}
+    out["attn"] = {"norm1": ones(a["norm1"]), "Wq": xavier(a["Wq"]),
+                   "Wk": xavier(a["Wk"]), "Wv": xavier(a["Wv"]),
+                   "Wo": xavier(a["Wo"])}
+    if "mlp" in shapes:
+        p = shapes["mlp"]
+        out["mlp"] = {"norm2": ones(p["norm2"]), "W_up": xavier(p["W_up"]),
+                      "W_down": xavier(p["W_down"])}
+    # the leaves of the wider block draw from a stream of their own, so
+    # that the others are what they were
+    ks = iter(jax.random.split(jax.random.fold_in(key, 1),
+                               2 + 2 * cfg.n_layers))
+    if "moe" in shapes:
+        p = shapes["moe"]
+        out["moe"] = {"norm2": ones(p["norm2"]),
+                      "router": xavier(p["router"]),
+                      "W_in": tuple(xavier(one) for one in p["W_in"]),
+                      "W_down": tuple(xavier(one) for one in p["W_down"])}
+    if "head" in shapes:
+        out["head"] = jax.random.normal(next(ks), shapes["head"], f32) \
+            * np.float32(0.02)
     return jax.tree_util.tree_map(lambda x: x.astype(cfg.compute_dtype), out)
 
 
@@ -323,8 +495,37 @@ def _layer(tree, i):
 def _mlp(h, fp, cfg: HybridConfig):
     gv = _mm(_rms(h, fp["norm2"], cfg.rms_eps), fp["W_up"])
     g, v = gv[..., :cfg.d_ff], gv[..., cfg.d_ff:]
-    return h + cfg.residual_multiplier * _mm(jax.nn.silu(g) * v,
+    return h + cfg.residual_multiplier * _mm(ACTS[cfg.ffn_act](g) * v,
                                              fp["W_down"])
+
+
+def _router(u, params, g, cfg: HybridConfig):
+    """Layer g's router logits [T, E] in float32, from the layer's
+    normalised input ``u`` BEFORE its mixer (the tensor the mixer reads);
+    None where the feed-forward is gated."""
+    if cfg.ffn != EXPERTS:
+        return None
+    w = lax.dynamic_index_in_dim(params["moe"]["router"], g, keepdims=False)
+    return jnp.matmul(u, _f32(w), precision=lax.Precision.HIGHEST)
+
+
+def _ffn(h, r, params, g, cfg: HybridConfig, scope: str, live=None):
+    """Layer g's feed-forward on the stream h: gated, or the routed
+    experts on the logits ``r`` that ``_router`` read before the mixer
+    (every expert is held here: one chip, no exchange). Returns (h, how
+    many experts got a row of a ``live`` row; None where gated)."""
+    if cfg.ffn == GATED:
+        return _mlp(h, _layer(params["mlp"], g), cfg), None
+    # imported where a model of this kind is built, not with the module
+    from deeplearning4j_tpu.parallel.expert_parallel import dropless_experts
+
+    ep = params["moe"]
+    x = _rms(h, lax.dynamic_index_in_dim(ep["norm2"], g, keepdims=False),
+             cfg.rms_eps)
+    y, hit = dropless_experts(x, r, ep["W_in"][g], ep["W_down"][g],
+                              top_k=cfg.moe_top_k, act=ACTS[cfg.ffn_act],
+                              live=live, scope=scope)
+    return h + cfg.residual_multiplier * y, hit
 
 
 def _split_in(cfg: HybridConfig, zxbcdt):
@@ -427,31 +628,130 @@ def mamba_chunked(u, n_state, mp, cfg: HybridConfig):
     return _mamba_out(y.reshape(t, cfg.d_inner), z, mp, cfg), ssm, tail
 
 
-def _qkv(u, ap, cfg: HybridConfig):
+def _rope(x, pos, theta: float):
+    """Rotary positions over the whole head, the two halves of a head
+    paired: x [n, H, hd] float32 at positions pos [n]."""
+    half = x.shape[-1] // 2
+    inv = jnp.exp(jnp.arange(half, dtype=jnp.float32)
+                  * np.float32(-np.log(theta) / half))
+    ang = pos.astype(jnp.float32)[:, None, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def _qkv(u, ap, cfg: HybridConfig, j: int = 0, pos=None):
+    """q, k, v of attention layer j (among the attention layers) for rows
+    u [n, d] at positions pos [n]: rotary on q and k where the layer has
+    it."""
     n = u.shape[0]
     q = _mm(u, ap["Wq"]).reshape(n, cfg.n_heads, cfg.head_dim)
     k = _mm(u, ap["Wk"]).reshape(n, cfg.n_kv_heads, cfg.head_dim)
     v = _mm(u, ap["Wv"]).reshape(n, cfg.n_kv_heads, cfg.head_dim)
+    if cfg.rope_of(j):
+        q, k = _rope(q, pos, cfg.rope_theta), _rope(k, pos, cfg.rope_theta)
     return q, k, v
 
 
-def attention_full(u, ap, cfg: HybridConfig, kv_dtype):
-    """Causal grouped-query attention over one sequence u [T, d]; K and V
-    are rounded to the arena's dtype first, which is what a later decode
-    step will read. Returns (out [T, d], k, v [T, Hkv, hd])."""
+# one pass's float32 scores [H, rows, keys] at the most, and the keys a pass
+# reads at the most: a sequence past them is attended by blocks of query
+# rows, each over chunks of keys (both halved from the sequence's length
+# until they fit, so they divide it)
+SCORE_BYTES = 1 << 29
+KEY_CHUNK = 2048
+MIN_ROWS = 128
+
+
+def _attend_tiles(n_heads: int, t: int) -> Tuple[int, int]:
+    """(query rows a block, keys a chunk) for a sequence of t positions."""
+    keys = t
+    while keys > KEY_CHUNK and keys % 2 == 0:
+        keys //= 2
+    rows = t
+    while rows % 2 == 0 and rows > MIN_ROWS and \
+            4 * n_heads * rows * keys > SCORE_BYTES:
+        rows //= 2
+    return rows, keys
+
+
+def attention_full(u, ap, cfg: HybridConfig, kv_dtype, j: int = 0):
+    """Causal grouped-query attention of attention layer j over one
+    sequence u [T, d]; K and V are rounded to the arena's dtype first,
+    which is what a later decode step will read. Returns (out [T, d], k, v
+    [T, Hkv, hd]).
+
+    By blocks of query rows over chunks of keys, folded into a running
+    (max, denominator, accumulator) in float32, where the whole score
+    matrix would pass SCORE_BYTES (28 heads at 8,192 positions: 7.5 GB in
+    float32): a block walks only the chunks its rows can see, from the one
+    position ``start - window + 1`` lies in (a window layer) or the first
+    (a global one) to the one its last row lies in, so a window layer reads
+    its band and a global layer the causal half. A sequence that fits is
+    one block over one chunk: the plain softmax. The products multiply in
+    float32 (``attn_exact``), or read q and the probabilities in the
+    arena's dtype and sum in float32."""
     t = u.shape[0]
-    hk, grp = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+    hk, grp, hd = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim
     hi = lax.Precision.HIGHEST
-    q, k, v = _qkv(u, ap, cfg)
+    window = cfg.window_of(j)
+    q, k, v = _qkv(u, ap, cfg, j, jnp.arange(t))
     k, v = k.astype(kv_dtype), v.astype(kv_dtype)
-    q = q.reshape(t, hk, grp, cfg.head_dim)
-    sc = jnp.einsum("tkgd,skd->kgts", q, _f32(k), precision=hi) \
-        * cfg.attention_multiplier
-    sc = jnp.where(jnp.tril(jnp.ones((t, t), bool))[None, None], sc,
-                   -jnp.inf)
-    p = jax.nn.softmax(sc, axis=-1)
-    att = jnp.einsum("kgts,skd->tkgd", p, _f32(v), precision=hi)
-    return _mm(att.reshape(t, cfg.d_model), ap["Wo"]), k, v
+    # a KV head's products: its g query heads' rows side by side against
+    # its keys, [Hkv, g * rows, keys], the keys innermost (with the scores
+    # laid "kgts" out of one einsum the chip's compiler put the rows
+    # innermost; and a softmax over 8,192 keys at once it took as a window
+    # sliding over them, 0.7 s a layer: PERF.md section 6, PR 37)
+    q = q.reshape(t, hk, grp, hd).transpose(1, 2, 0, 3)   # [Hkv, g, T, hd]
+    if cfg.attn_exact:
+        keys, vals = _f32(k), _f32(v)
+    else:
+        q, keys, vals = q.astype(kv_dtype), k, v
+    keys, vals = keys.transpose(1, 0, 2), vals.transpose(1, 0, 2)
+    rows, span = _attend_tiles(cfg.n_heads, t)
+
+    def block(start):
+        qb = q if rows == t else lax.dynamic_slice_in_dim(q, start, rows, 2)
+        qb = qb.reshape(hk, grp * rows, hd)
+        at = start + jnp.arange(rows)[:, None]
+
+        def fold(c, carry):
+            m, l, acc = carry
+            kb = lax.dynamic_slice_in_dim(keys, c * span, span, 1)
+            vb = lax.dynamic_slice_in_dim(vals, c * span, span, 1)
+            sc = jnp.einsum("kqd,ksd->kqs", qb, kb, precision=hi,
+                            preferred_element_type=jnp.float32) \
+                * cfg.attention_multiplier
+            key_at = c * span + jnp.arange(span)[None]
+            see = key_at <= at
+            if window:
+                see = see & (key_at > at - window)
+            sc = jnp.where(see[None, None],
+                           sc.reshape(hk, grp, rows, span), -jnp.inf)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
+            # a chunk none of whose keys a row sees leaves its max at -inf
+            base = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            p = jnp.exp(sc - base[..., None])
+            corr = jnp.exp(m - base)
+            l = l * corr + jnp.sum(p, axis=-1)
+            pv = jnp.einsum(
+                "kqs,ksd->kqd",
+                p.reshape(hk, grp * rows, span).astype(vb.dtype), vb,
+                precision=hi, preferred_element_type=jnp.float32)
+            return m_new, l, acc * corr[..., None] \
+                + pv.reshape(hk, grp, rows, hd)
+
+        init = (jnp.full((hk, grp, rows), -jnp.inf, jnp.float32),
+                jnp.zeros((hk, grp, rows), jnp.float32),
+                jnp.zeros((hk, grp, rows, hd), jnp.float32))
+        first = jnp.maximum(start - window + 1, 0) // span if window else 0
+        _, l, acc = lax.fori_loop(first, (start + rows - 1) // span + 1,
+                                  fold, init)
+        return (acc / l[..., None]).transpose(2, 0, 1, 3)
+
+    with jax.named_scope("admit.attend"):
+        att = block(0) if rows == t else \
+            lax.map(block, jnp.arange(0, t, rows))
+    return _mm(att.reshape(t, cfg.q_dim), ap["Wo"]), k, v
 
 
 def _embed(params, tok, cfg: HybridConfig):
@@ -460,7 +760,7 @@ def _embed(params, tok, cfg: HybridConfig):
 
 def _head(params, h, cfg: HybridConfig):
     x = _rms(h, params["norm_f"], cfg.rms_eps)
-    e = params["embed"]
+    e = params["embed"] if cfg.tie_head else params["head"]
     return jnp.einsum("...d,vd->...v", x.astype(e.dtype), e,
                       precision=lax.Precision.HIGHEST,
                       preferred_element_type=jnp.float32) \
@@ -505,11 +805,13 @@ def prefill(params, tokens, n_state, cfg: HybridConfig, kv_dtype=None):
         else:
             for i in range(n):
                 ap = _layer(params["attn"], j0 + i)
-                out, k, v = attention_full(
-                    _rms(h, ap["norm1"], cfg.rms_eps), ap, cfg, kv_dtype)
+                u = _rms(h, ap["norm1"], cfg.rms_eps)
+                logits = _router(u, params, g0 + i, cfg)
+                out, k, v = attention_full(u, ap, cfg, kv_dtype, j0 + i)
                 ks.append(k)
                 vs.append(v)
-                h = _mlp(h + r * out, _layer(params["mlp"], g0 + i), cfg)
+                h, _ = _ffn(h + r * out, logits, params, g0 + i, cfg,
+                            "admit.moe")
     empty = jnp.zeros((0, t, cfg.n_kv_heads, cfg.head_dim), kv_dtype)
     return (h, jnp.stack(ks) if ks else empty,
             jnp.stack(vs) if vs else empty, ssm, conv)
@@ -520,7 +822,10 @@ def forward(params, tokens, cfg: HybridConfig):
     the state: no padding)."""
     t = tokens.shape[1]
     one = lambda row: _head(params, prefill(params, row, t, cfg)[0], cfg)
-    return jax.vmap(one)(tokens)
+    # the grouped product of the expert layer has no batched form: its
+    # rows go one sequence after the other
+    return lax.map(one, tokens) if cfg.ffn == EXPERTS \
+        else jax.vmap(one)(tokens)
 
 
 # ---------------------------------------------------------------------------
@@ -537,40 +842,66 @@ def paged_decode_step(params, arena, tok, pos, tables, cfg: HybridConfig):
     lane's blocks through serving/paged.chunked_attention, grouped; Mamba
     layers advance EVERY lane's state one step (a dead lane's is never
     read: admission overwrites it whole). The layers are unrolled: each
-    state buffer is read once and rewritten in place."""
+    state buffer is read once and rewritten in place.
+
+    A model whose attention layers page in several groups (window layers
+    beside global ones: ``cfg.kv_groups``) is handed ``tables`` [G, S, m],
+    a table a group; a window layer's buffers have its group's blocks, it
+    reads through its group's table and sees ``pos - window < t <= pos``.
+    With routed experts the tick also returns how many (layer, expert)
+    pairs got the row of a live lane (one whose write block is not trash):
+    (arena, logits, hit)."""
     from deeplearning4j_tpu.serving.paged import chunked_attention
 
     s = tok.shape[0]
     bt = arena["k"][0].shape[1]
     r = cfg.residual_multiplier
     h = _embed(params, tok, cfg)
-    wb = jnp.take_along_axis(tables, (pos // bt)[:, None], axis=1)[:, 0]
+    groups = cfg.kv_groups()
+    group_of = {j: gi for gi, grp in enumerate(groups)
+                for j in grp.layer_ids}
+    by_group = [tables] if len(groups) == 1 else list(tables)
+    wbs = [jnp.take_along_axis(t, (pos // bt)[:, None], axis=1)[:, 0]
+           for t in by_group]
     off = pos % bt
+    live = wbs[0] != 0
+    hits = jnp.zeros((), jnp.int32)
     ak, av = list(arena["k"]), list(arena["v"])
-    ssm, conv = list(arena["ssm"]), list(arena["conv"])
+    ssm, conv = list(arena.get("ssm", ())), list(arena.get("conv", ()))
     seen = {MAMBA: 0, ATTENTION: 0}
     for g, kind in enumerate(cfg.layer_types):
         j = seen[kind]
         seen[kind] += 1
         if kind == MAMBA:
             mp = _layer(params["mamba"], j)
+            logits = None
             out, ssm[j], conv[j] = mamba_step(
                 _rms(h, mp["norm1"], cfg.rms_eps), ssm[j], conv[j], mp, cfg)
         else:
             ap = _layer(params["attn"], j)
-            q, k1, v1 = _qkv(_rms(h, ap["norm1"], cfg.rms_eps), ap, cfg)
+            u = _rms(h, ap["norm1"], cfg.rms_eps)
+            logits = _router(u, params, g, cfg)
+            q, k1, v1 = _qkv(u, ap, cfg, j, pos)
+            gi, window = group_of[j], cfg.window_of(j)
             with jax.named_scope("tick.scatter"):
-                ak[j] = ak[j].at[wb, off].set(
+                ak[j] = ak[j].at[wbs[gi], off].set(
                     k1.reshape(s, -1).astype(ak[j].dtype))
-                av[j] = av[j].at[wb, off].set(
+                av[j] = av[j].at[wbs[gi], off].set(
                     v1.reshape(s, -1).astype(av[j].dtype))
-            att = chunked_attention(q, ak[j], av[j], tables, pos,
-                                    scale=cfg.attention_multiplier)
-            out = _mm(att.reshape(s, cfg.d_model), ap["Wo"])
-        h = _mlp(h + r * out, _layer(params["mlp"], g), cfg)
-    arena = {"k": tuple(ak), "v": tuple(av), "ssm": tuple(ssm),
-             "conv": tuple(conv)}
-    return arena, _head(params, h, cfg)
+            att = chunked_attention(
+                q, ak[j], av[j], by_group[gi], pos,
+                scale=cfg.attention_multiplier,
+                lo=jnp.maximum(pos - (window - 1), 0) if window else None)
+            out = _mm(att.reshape(s, cfg.q_dim), ap["Wo"])
+        h, hit = _ffn(h + r * out, logits, params, g, cfg, "tick.moe", live)
+        if hit is not None:
+            hits = hits + hit
+    out = {"k": tuple(ak), "v": tuple(av)}
+    if "ssm" in arena:
+        out.update(ssm=tuple(ssm), conv=tuple(conv))
+    if cfg.ffn == EXPERTS:
+        return out, _head(params, h, cfg), hits
+    return out, _head(params, h, cfg)
 
 
 def paged_admit(params, arena, window, write_table, lane,
@@ -579,26 +910,45 @@ def paged_admit(params, arena, window, write_table, lane,
     write_table [m] (shared and beyond-prompt entries point at trash block
     0), lane int32 [2] = (the lane's index, n_state). Scatters the
     prompt's K and V into the lane's private blocks and writes the lane's
-    recurrent state as of position n_state - 1 over whatever the lane held."""
+    recurrent state as of position n_state - 1 over whatever the lane held.
+
+    With several KV groups write_table is [G, m], a row a group. A window
+    group's layers scatter only the ``window / bt + 1`` blocks that the
+    positions ``n_state + 1 - window .. n_state`` lie in (what the lane's
+    first tick can see: the host gave the group blocks for those alone);
+    every other block of the bucket is left where it was computed."""
     t = window.shape[1]
     bt = arena["k"][0].shape[1]
     nb = -(-t // bt)
     with jax.named_scope("admit.prefill"):
         _, ks, vs, ssm, conv = prefill(params, window[0], lane[1], cfg,
                                        arena["k"][0].dtype)
+    groups = cfg.kv_groups()
+    tables = write_table[None] if write_table.ndim == 1 else write_table
     with jax.named_scope("admit.scatter"):
         pad = ((0, 0), (0, nb * bt - t), (0, 0), (0, 0))
         blocks = lambda a: jnp.pad(a, pad).reshape(
             cfg.n_attention, nb, bt, cfg.n_kv_heads * cfg.head_dim)
-        cols = write_table[:nb]
-        out = {"k": tuple(buf.at[cols].set(kb)
-                          for buf, kb in zip(arena["k"], blocks(ks))),
-               "v": tuple(buf.at[cols].set(vb)
-                          for buf, vb in zip(arena["v"], blocks(vs))),
-               "ssm": tuple(buf.at[lane[0]].set(ssm[j])
-                            for j, buf in enumerate(arena["ssm"])),
-               "conv": tuple(buf.at[lane[0]].set(conv[j])
-                             for j, buf in enumerate(arena["conv"]))}
+        kb, vb = blocks(ks), blocks(vs)
+        ak, av = list(arena["k"]), list(arena["v"])
+        for gi, grp in enumerate(groups):
+            n, first = nb, 0
+            if grp.window and grp.window // bt + 1 < nb:
+                n = grp.window // bt + 1
+                first = jnp.clip((lane[1] + 1 - grp.window) // bt, 0, nb - n)
+            cut = lambda a, ax: a if n == nb else \
+                lax.dynamic_slice_in_dim(a, first, n, axis=ax)
+            cols = cut(tables[gi, :nb], 0)
+            for j in grp.layer_ids:
+                ak[j] = ak[j].at[cols].set(cut(kb[j], 0))
+                av[j] = av[j].at[cols].set(cut(vb[j], 0))
+        out = {"k": tuple(ak), "v": tuple(av)}
+        if "ssm" in arena:
+            out.update(
+                ssm=tuple(buf.at[lane[0]].set(ssm[j])
+                          for j, buf in enumerate(arena["ssm"])),
+                conv=tuple(buf.at[lane[0]].set(conv[j])
+                           for j, buf in enumerate(arena["conv"])))
     return out
 
 

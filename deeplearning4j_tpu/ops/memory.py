@@ -352,6 +352,22 @@ class StateLeaf:
 
 
 @dataclasses.dataclass(frozen=True)
+class KVGroup:
+    """Layers that page their keys and values alike: ``layer_ids`` among
+    the model's KV layers (the order of its arena's buffers), and
+    ``window``, how many of the newest positions a layer of the group sees,
+    the token's own among them (0: every earlier position). A group has a
+    block pool and a per-lane table of its own in the paged decoder; a lane
+    of a window group holds only the blocks its window reaches."""
+    layer_ids: Tuple[int, ...]
+    window: int = 0
+
+    @property
+    def layers(self) -> int:
+        return len(self.layer_ids)
+
+
+@dataclasses.dataclass(frozen=True)
 class CacheNeeds:
     """What a served model holds per request on the device, as the paged
     decoder asks it: keys and values for ``kv_layers`` layers of
@@ -368,16 +384,39 @@ class CacheNeeds:
     place), instead of ONE ``[kv_layers, blocks+1, block_tokens,
     kv_heads * head_dim]`` (a tick that scans over layers that are
     alike carries it through the scan and reaches layer ``l`` at rows
-    ``l * (blocks+1) + block``)."""
+    ``l * (blocks+1) + block``).
+
+    ``groups`` says which KV layers page alike (:class:`KVGroup`): one
+    group of every KV layer, no window, unless the model states its own
+    (models/hybrid.py with window layers beside global ones: a group of
+    each, which needs ``kv_per_layer``, since two groups' buffers differ
+    in blocks). Every group's block is ``kv_heads * head_dim`` wide."""
     kv_layers: int
     kv_heads: int
     head_dim: int
     state: Tuple[StateLeaf, ...] = ()
     kv_per_layer: bool = False
+    groups: Tuple[KVGroup, ...] = ()
+
+    def __post_init__(self):
+        if not self.groups:
+            object.__setattr__(self, "groups", (
+                KVGroup(tuple(range(self.kv_layers))),))
+        ids = sorted(i for g in self.groups for i in g.layer_ids)
+        if ids != list(range(self.kv_layers)):
+            raise ValueError(f"the KV groups name layers {ids}, the model "
+                             f"has {self.kv_layers} KV layers")
+        if len(self.groups) > 1 and not self.kv_per_layer:
+            raise ValueError("KV groups of their own pools need one buffer "
+                             "a layer (kv_per_layer)")
 
     @property
     def state_lane_bytes(self) -> int:
         return sum(leaf.lane_bytes for leaf in self.state)
+
+    @property
+    def windowed(self) -> bool:
+        return any(g.window for g in self.groups)
 
 
 def cache_needs(cfg) -> CacheNeeds:
@@ -391,10 +430,11 @@ def cache_needs(cfg) -> CacheNeeds:
 
 
 def kv_block_bytes(cfg, block_tokens: int, dtype=None,
-                   devices: int = 1) -> int:
+                   devices: int = 1, group: Optional[int] = None) -> int:
     """PER-DEVICE bytes of ONE paged KV block across the layers that
-    hold keys and values (:func:`cache_needs`): K and V,
-    [kv_layers, block_tokens, (kv_heads/devices) * head_dim] each, in
+    hold keys and values (:func:`cache_needs`; the layers of KV group
+    ``group`` alone where one is named): K and V,
+    [layers, block_tokens, (kv_heads/devices) * head_dim] each, in
     the arena dtype (serving/paged.py's layout, ``[L, n_blocks+1, bt,
     H*hd]``: a token's heads side by side; bytes by shape, whatever the
     order). ``dtype=None`` resolves
@@ -411,10 +451,26 @@ def kv_block_bytes(cfg, block_tokens: int, dtype=None,
         dtype = lowprec.kv_dtype(cfg)
     devices = max(1, int(devices))
     needs = cache_needs(cfg)
+    layers = needs.kv_layers if group is None \
+        else needs.groups[group].layers
     heads_local = -(-needs.kv_heads // devices)  # ceil: honest off-grid
     itemsize = np.dtype(dtype).itemsize
-    return 2 * needs.kv_layers * int(block_tokens) * heads_local \
+    return 2 * layers * int(block_tokens) * heads_local \
         * needs.head_dim * itemsize
+
+
+def kv_group_blocks(needs: CacheNeeds, n_blocks: int, block_tokens: int,
+                    lanes: int) -> Tuple[int, ...]:
+    """Blocks of each KV group's pool for an arena stated as ``n_blocks``:
+    a group without a window has them all; a window group's pool is
+    derived, what its lanes can hold at the most (``window /
+    block_tokens + 2`` blocks each: the window's reach, the block being
+    written and the one a tick grows into before the oldest is let go),
+    and never more than ``n_blocks``."""
+    return tuple(
+        int(n_blocks) if not g.window else
+        min(int(n_blocks), int(lanes) * (g.window // int(block_tokens) + 2))
+        for g in needs.groups)
 
 
 def kv_arena_blocks(cfg, block_tokens: int, *, params=None,
@@ -446,6 +502,8 @@ def kv_arena_blocks(cfg, block_tokens: int, *, params=None,
     if params is not None:
         budget -= 2.0 * _tree_bytes(params)
     budget -= int(lanes) * cache_needs(cfg).state_lane_bytes
+    # every KV layer priced as if its pool had all the blocks: a window
+    # group's is smaller (kv_group_blocks), so this errs to the safe side
     per_block = kv_block_bytes(cfg, block_tokens, dtype, devices)
     blocks = int(max(0.0, budget) * float(kv_fraction) / per_block)
     floor = cfg.max_len // int(block_tokens) + 1

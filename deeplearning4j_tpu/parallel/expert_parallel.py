@@ -23,6 +23,20 @@ Routing math (capacity C per expert per device-batch):
 Differentiable end-to-end (top_k indices are constant under grad; gate
 values flow through combine), so `jax.grad` gives exact MoE gradients with
 the reverse all-reduce inserted automatically.
+
+Beside it, for serving: ``dropless_experts``, a gated expert layer that
+drops no row. It routes over ALL ``E`` experts (router logits ``[T, E]``,
+the ``top_k`` largest, softmax over the chosen), is told which experts it
+holds (``first``, ``count``; their weights are what it is handed) and
+computes the part of the result those give: the ``T * top_k`` (row, expert)
+pairs are sorted by expert and each expert's rows meet its matrices in one
+grouped product (``jax.lax.ragged_dot``: no ``[T, E, C]`` tensor, no
+capacity). A row's output is a function of that row alone, whoever shares
+its batch, which is what lets the decode pools (serving/paged.py) admit a
+model built on it where they refuse the capacity-routed layer above. On one
+chip a layer holds every expert and there is no exchange; over chips each
+holds a share and the shares' parts add up to the whole layer
+(tests/test_moe_dropless.py).
 """
 
 from __future__ import annotations
@@ -164,6 +178,92 @@ def moe_reference(params: Params, x: jax.Array, *, top_k: int = 2,
     y = expert_mlp(params["W1"], params["b1"], params["W2"], params["b2"],
                    dispatch, combine, xt)
     return y.reshape(orig_shape)
+
+
+# rows up to which every held expert is applied to every row in one batched
+# product (the decode tick: at 32 to 64 rows of 6 pairs nearly every expert
+# is hit whatever the routing, so its matrices are read either way; read
+# once each by one product they ran at 89% of the chip's memory roofline,
+# through the grouped kernel at 67%: PERF.md section 6, PR 37). Past it the
+# E / top_k times more operations would bound the product, and the rows are
+# grouped
+DENSE_ROWS = 128
+
+
+def dropless_experts(x: jax.Array, logits: jax.Array, w_in: jax.Array,
+                     w_down: jax.Array, *, top_k: int, first: int = 0,
+                     act=jax.nn.relu, live=None, scope: str = "moe"
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of a gated, dropless top-k expert layer.
+
+    x [T, d] float32 rows; logits [T, E] float32 router logits over ALL E
+    experts; w_in [count, d, 2f] (gate | up) and w_down [count, f, d] the
+    matrices of experts ``first .. first + count - 1``, the ones held here.
+    Row t takes the ``top_k`` largest of its logits, ``I``, with weights
+    ``softmax(logits[I])``, and gets ``sum over e in I and held of w_e
+    W_down,e (act(W_gate,e x) * W_up,e x)``. Returns (y [T, d] float32, hit:
+    int32, how many held experts got a row of a ``live`` row; ``live`` [T]
+    bool, every row where None).
+
+    Two forms of the same sum, by the rows (static). Up to DENSE_ROWS every
+    held expert meets every row in one batched product and the rows' weights
+    (0 where an expert was not chosen) close the sum. Past it the (row,
+    expert) pairs are sorted by expert (pairs whose expert is held elsewhere
+    sort behind every group), each expert's rows meet its matrices in one
+    grouped product, and the result is unsorted by a gather and summed over
+    ``top_k`` in the pair's own order. Either way the products read a row in
+    the weights' dtype and sum in float32 (HIGHEST with float32 weights
+    only), and nothing of a row's result depends on which other rows stand
+    in the batch. Under ``<scope>_route`` stand the top-k, the weights, the
+    sort and the counts, under ``<scope>_experts`` the products."""
+    t = x.shape[0]
+    count, _, f2 = w_in.shape
+    f = f2 // 2
+    # HIGHEST is for float32 weights (the tests' strict policy); the chip's
+    # grouped kernel takes no precision with bfloat16 operands
+    hi = lax.Precision.HIGHEST if w_in.dtype == jnp.float32 else None
+    with jax.named_scope(scope + "_route"):
+        topv, topi = lax.top_k(logits, top_k)                 # [T, k]
+        gate = jax.nn.softmax(topv, axis=-1)
+        local = topi - first
+        held = (local >= 0) & (local < count)
+        seen = held if live is None else held & live[:, None]
+        # [T, count]: a row's weight for each held expert, 0 where not chosen
+        onto = lambda v: jnp.sum(
+            jnp.where(held[..., None], v[..., None], 0)
+            * jax.nn.one_hot(local, count, dtype=v.dtype), axis=1)
+        hit = jnp.sum(jnp.sum(onto(seen.astype(jnp.int32)), axis=0) > 0,
+                      dtype=jnp.int32)
+    if t <= DENSE_ROWS:
+        with jax.named_scope(scope + "_route"):
+            weight = onto(gate)                               # [T, count]
+        with jax.named_scope(scope + "_experts"):
+            gu = jnp.einsum("td,edf->etf", x.astype(w_in.dtype), w_in,
+                            precision=hi,
+                            preferred_element_type=jnp.float32)
+            mid = act(gu[..., :f]) * gu[..., f:] * weight.T[:, :, None]
+            y = jnp.einsum("etf,efd->td", mid.astype(w_down.dtype), w_down,
+                           precision=hi, preferred_element_type=jnp.float32)
+        return y, hit
+    with jax.named_scope(scope + "_route"):
+        key = jnp.where(held, local, count).reshape(-1)       # [T * k]
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
+        back = jnp.argsort(order)          # where each pair's row went
+    with jax.named_scope(scope + "_experts"):
+        xs = x.astype(w_in.dtype)[order // top_k]             # [T * k, d]
+        gu = lax.ragged_dot(xs, w_in, sizes, precision=hi,
+                            preferred_element_type=jnp.float32)
+        mid = act(gu[:, :f]) * gu[:, f:]
+        out = lax.ragged_dot(mid.astype(w_down.dtype), w_down, sizes,
+                             precision=hi,
+                             preferred_element_type=jnp.float32)
+        # a pair whose expert is held elsewhere lies behind the last group:
+        # no group writes its rows of the products
+        pair = jnp.where(held, gate, 0.0).reshape(-1)
+        y = (jnp.where(held.reshape(-1)[order][:, None], out, 0.0)[back]
+             * pair[:, None]).reshape(t, top_k, -1).sum(axis=1)
+    return y, hit
 
 
 def load_balancing_loss(x: jax.Array, Wg: jax.Array) -> jax.Array:

@@ -240,9 +240,16 @@ class ContinuousDecoder:
                 "the fixed-slot pool (DL4J_TPU_SERVE_KV_BLOCK=0) holds keys "
                 "and values only: not implemented for models with "
                 "recurrent layers")
+        if hasattr(cfg, "paged_decode_step"):
+            raise ValueError(
+                "the fixed-slot pool (DL4J_TPU_SERVE_KV_BLOCK=0) steps the "
+                "GPT-2-shaped TransformerLM alone: a model that brings its "
+                "own tick is served by the paged pool")
         if cfg.moe_experts:
-            raise ValueError("continuous decode does not support MoE "
-                             "(capacity routing is batch-dependent)")
+            raise ValueError(
+                "continuous decode does not serve capacity-routed experts "
+                "(a token dropped past an expert's capacity makes a slot's "
+                "output depend on its batch)")
         self.lm = lm
         self.cfg = cfg
         self.slots = int(slots)

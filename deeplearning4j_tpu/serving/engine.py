@@ -1308,12 +1308,15 @@ class ServingEngine:
             if cfg is not None:
                 if hasattr(decoder, "n_blocks"):
                     # paged arena: +1 is the trash block (serving/paged)
+                    # (a pool a KV group: a window group's is smaller)
                     entry["kv_bytes"] = (
-                        (decoder.n_blocks + 1) * opsmem.kv_block_bytes(
+                        sum((n + 1) * opsmem.kv_block_bytes(
                             cfg, decoder.block_tokens,
                             getattr(decoder, "kv_dtype", None),
                             devices=int(getattr(decoder,
-                                                "mesh_devices", 1)))
+                                                "mesh_devices", 1)),
+                            group=gi)
+                            for gi, n in enumerate(decoder.group_blocks))
                         # a model with recurrent layers: its per-lane
                         # state pool rides in the arena (0 without)
                         + decoder.lanes

@@ -86,7 +86,11 @@ from deeplearning4j_tpu.ops import lowprec
 from deeplearning4j_tpu.parallel.mesh import MODEL_AXIS, device_mesh
 from deeplearning4j_tpu.parallel.tensor_parallel import local_head_columns
 from deeplearning4j_tpu.serving.decode import _sample_step
-from deeplearning4j_tpu.serving.paged import PagedDecoder, chunked_attention
+from deeplearning4j_tpu.serving.paged import (
+    PagedDecoder,
+    chunked_attention,
+    refuse_window,
+)
 
 # the arena's k/v buffers shard on their last axis (dim 3 of
 # [L, n_blocks+1, bt, H*hd]: heads side by side, a device's H/d heads
@@ -329,6 +333,9 @@ class MeshPagedDecoder(PagedDecoder):
                 "the serving mesh (DL4J_TPU_SERVE_MESH) cannot carry the "
                 "per-lane recurrent state this model keeps: not "
                 "implemented for models with recurrent layers")
+        if opsmem.cache_needs(cfg).windowed:
+            raise ValueError(refuse_window(
+                "the serving mesh (DL4J_TPU_SERVE_MESH)"))
         self.serving_mesh = mesh
         nd = int(mesh.shape[MODEL_AXIS])
         if nd < 2:

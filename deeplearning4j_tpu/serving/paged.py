@@ -102,6 +102,25 @@ be shared, state cannot be rebuilt from them), is preempted and
 requeued by recomputing from the window like any other, and is refused
 by the paths that cannot carry its state: scanned ticks, speculation,
 the serving mesh, the prefill/decode handoff, the fixed-slot pool.
+
+A paged cache a layer kind (``CacheNeeds.groups``): layers that page alike
+are a KV group with a block pool (BlockArena) and a per-lane table of its
+own, every group's buffers in the one arena pytree. A model of one kind of
+layer has one group and is served as it was. A group with a ``window`` (the
+window layers of models/hybrid.py beside its global ones) holds for a lane
+only the blocks its window reaches: admission gives it blocks from the one
+position ``keep - window`` lies in, the tick's booking returns the blocks
+that lie wholly behind ``pos - window`` to the group's pool and points their
+table entries at trash (``_trim``), so a lane never holds more than
+``window / block_tokens + 2`` of them; its pool is derived from the stated
+``n_blocks`` (ops/memory.kv_group_blocks), and its layers attend through
+``chunked_attention(..., lo=...)``. Such a model takes no prefix hit and
+counts no lookup (a block shared with another lane cannot be let go as one
+lane's window passes it), is preempted and prefilled again as any other,
+and is refused where state is refused. A model with routed experts is
+served if its expert layer is dropless (a row's output its own, whoever
+shares the tick: parallel/expert_parallel.dropless_experts); the
+capacity-routed layer is refused.
 """
 
 from __future__ import annotations
@@ -198,7 +217,7 @@ def _exact_rows(x):
     return jnp.stack([hi, mid, lo], axis=2)
 
 
-def chunked_attention(q, ck, cv, tables, pos, scale=None):
+def chunked_attention(q, ck, cv, tables, pos, scale=None, lo=None):
     """Masked single-query attention over the block arena, chunk by
     chunk up to the longest lane: q [S, H, hd], ck/cv [B, bt, Hkv * hd],
     a token's heads side by side as the arena stores them (or
@@ -243,7 +262,19 @@ def chunked_attention(q, ck, cv, tables, pos, scale=None):
     exactly 1 and ``p`` exactly 0, and the triple keeps its bits.
     ``-inf`` never meets ``-inf`` in an ``exp``: chunk 0 holds position
     0, which every lane sees, so the running max is finite from the
-    first pass on."""
+    first pass on.
+
+    ``lo`` [S] int32 (a window layer: ``0 <= lo <= pos``) bounds a lane
+    from below: it sees ``lo <= t <= pos``. Lane s then starts at ITS OWN
+    first chunk, ``lo[s] // chunk`` (a per-lane gather of table columns;
+    chunk edges stay at the same fixed global positions), and the loop
+    runs as far as the lane whose span ``pos // chunk - lo // chunk`` is
+    the longest: a window of w positions is read in at most ``w / chunk +
+    2`` passes, however long the lanes are and whoever shares the tick.
+    The first pass of a lane holds its position ``lo``, which it sees, so
+    the running max is finite from the first pass on, as above; a pass
+    past a lane's ``pos`` is the exact no-op it was. Without ``lo`` the
+    function traces what it traced before it took one."""
     s, n_heads, hd = q.shape
     bt = ck.shape[1]
     # a token's heads side by side, however the caller names them
@@ -263,6 +294,7 @@ def chunked_attention(q, ck, cv, tables, pos, scale=None):
     if scale is None:
         scale = 1.0 / float(np.sqrt(hd))
     t_in = jnp.arange(chunk)[None, :]                 # [1, chunk]
+    first = None if lo is None else lo // chunk       # [S] chunks
 
     def head_rows(x, dtype):
         # x [S, H, n] -> [S, Hkv, n_rows, n]: a KV head's query heads
@@ -288,13 +320,24 @@ def chunked_attention(q, ck, cv, tables, pos, scale=None):
 
     def fold(j, carry):
         m, l, acc = carry
-        cols = lax.dynamic_slice_in_dim(tables, j * c, c, axis=1)
+        if lo is None:
+            cols = lax.dynamic_slice_in_dim(tables, j * c, c, axis=1)
+        else:
+            # the lane's own chunk first + j; columns past the table's end
+            # read its last one, at positions past every lane's pos
+            at = (first[:, None] + j) * c + jnp.arange(c)[None, :]
+            cols = jnp.take_along_axis(
+                tables, jnp.minimum(at, tables.shape[1] - 1), axis=1)
         with jax.named_scope("tick.gather_kv"):
             kg = ck[cols].reshape(s, chunk, width)
             vg = cv[cols].reshape(s, chunk, width)
         with jax.named_scope("tick.attend"):
             sc = heads_dot("nrd,ntd->nrt", q_rows, kg) * scale
-            visible = j * chunk + t_in <= pos[:, None]    # [S, chunk]
+            if lo is None:
+                visible = j * chunk + t_in <= pos[:, None]    # [S, chunk]
+            else:
+                t_at = (first[:, None] + j) * chunk + t_in
+                visible = (t_at >= lo[:, None]) & (t_at <= pos[:, None])
             sc = jnp.where(visible[:, None, :], sc, -jnp.inf)
             m_new = jnp.maximum(m, jnp.max(sc, axis=-1))  # [S, H], finite
             p = jnp.exp(sc - m_new[..., None])
@@ -307,7 +350,9 @@ def chunked_attention(q, ck, cv, tables, pos, scale=None):
     init = (jnp.full((s, n_heads), -jnp.inf, jnp.float32),
             jnp.zeros((s, n_heads), jnp.float32),
             jnp.zeros((s, n_heads, hd), jnp.float32))
-    _, l, acc = lax.fori_loop(0, jnp.max(pos) // chunk + 1, fold, init)
+    trips = jnp.max(pos) // chunk + 1 if lo is None \
+        else jnp.max(pos // chunk - first) + 1
+    _, l, acc = lax.fori_loop(0, trips, fold, init)
     return acc / l[..., None]
 
 
@@ -317,6 +362,16 @@ def kv_read_tokens(max_pos: int, block_tokens: int, table_width: int) -> int:
     chunk, as the host counts it for the ``serve.batch`` span."""
     chunk = _chunk_tokens(block_tokens, table_width)
     return (max_pos // chunk + 1) * chunk
+
+
+def kv_read_tokens_window(pos: np.ndarray, window: int, block_tokens: int,
+                          table_width: int) -> int:
+    """The same for a window layer (chunked_attention with ``lo``): every
+    lane at ``pos`` loops from its own first chunk as far as the lane with
+    the longest span of chunks."""
+    chunk = _chunk_tokens(block_tokens, table_width)
+    lo = np.maximum(pos - (window - 1), 0)
+    return int((pos // chunk - lo // chunk).max() + 1) * chunk
 
 
 def paged_decode_step(params, arena, tok, pos, tables,
@@ -457,10 +512,12 @@ def _paged_tick_for(cfg: TransformerConfig, block_tokens: int, k: int = 1):
 
     if k == 1:
         def tick(params, arena, tok, pos, tables, keys, temps):
-            arena, logits = step_body(params, arena, tok, pos, tables)
+            # a body with routed experts returns, beside the logits, how
+            # many experts a live lane's row reached (one int32)
+            arena, logits, *more = step_body(params, arena, tok, pos, tables)
             with jax.named_scope("tick.sample"):
                 nxt, nkeys = _sample_step(logits, keys, temps)
-            return arena, nxt[:, None], nkeys
+            return (arena, nxt[:, None], nkeys, *more)
     else:
         # k scanned steps in ONE dispatch: the per-step body (scatter at
         # pos, gather/attend, sample) is IDENTICAL to the k=1 tick, so
@@ -470,7 +527,8 @@ def _paged_tick_for(cfg: TransformerConfig, block_tokens: int, k: int = 1):
         def tick(params, arena, tok, pos, tables, keys, temps):
             def step(carry, _):
                 arena, tok, pos, keys = carry
-                arena, logits = step_body(params, arena, tok, pos, tables)
+                arena, logits, *_ = step_body(params, arena, tok, pos,
+                                              tables)
                 with jax.named_scope("tick.sample"):
                     nxt, keys = _sample_step(logits, keys, temps)
                 return (arena, nxt, pos + 1, keys), nxt
@@ -497,6 +555,7 @@ def _paged_admit_for(cfg: TransformerConfig, width: int, block_tokens: int):
         # the model's own admission body (models/hybrid.py): one more
         # argument, ``lane`` int32 [2] = (lane index, positions that feed
         # the lane's recurrent state), which it writes beside the blocks
+        # (a model with a window group reads the prompt's length off it)
         def admit(params, arena, window, write_table, lane):
             return own(params, arena, window, write_table, lane)
 
@@ -610,6 +669,13 @@ def seed_key(seed: int) -> np.ndarray:
     seed = int(seed)
     high = (seed >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
     return np.array([high, seed & 0xFFFFFFFF], np.uint32)
+
+
+def refuse_window(what: str) -> str:
+    return (f"{what} cannot carry a KV group with a window (its lanes hold "
+            "only the blocks the window reaches, in a pool and a table of "
+            "the group's own): not implemented for models with window "
+            "layers")
 
 
 class BlockArena:
@@ -752,13 +818,27 @@ class _PendingReq:
         self.trace = trace  # _ReqTrace, or None with tracing off
 
 
+class _Held:
+    """A lane's blocks in one KV group: its table entries ``first ..
+    nxt - 1``, in order. ``first`` moves only in a window group, whose
+    blocks go back to their pool as the window passes them."""
+
+    __slots__ = ("first", "nxt", "blocks")
+
+    def __init__(self, first: int, blocks: List[int]) -> None:
+        self.first = first
+        self.nxt = first + len(blocks)
+        self.blocks = blocks
+
+
 class _Lane:
     __slots__ = ("future", "tokens", "remaining", "deadline", "enqueued",
-                 "temperature", "seed", "slo", "on_token", "blocks",
-                 "n_table", "window", "admit_seq", "trace")
+                 "temperature", "seed", "slo", "on_token", "held",
+                 "window", "admit_seq", "trace")
 
     def __init__(self, req: _PendingReq, blocks: List[int], n_table: int,
-                 window: np.ndarray, admit_seq: int) -> None:
+                 window: np.ndarray, admit_seq: int,
+                 more: Tuple[_Held, ...] = ()) -> None:
         self.future = req.future
         self.tokens = req.tokens
         self.remaining = req.n_new
@@ -768,17 +848,28 @@ class _Lane:
         self.seed = req.seed
         self.slo = req.slo
         self.on_token = req.on_token
-        self.blocks = blocks      # every block this lane holds a ref on
-        self.n_table = n_table    # allocated read-table entries
+        # a KV group each: the blocks this lane holds a ref on and its
+        # allocated read-table entries; the first group's are ``blocks``
+        # and ``n_table``, further groups' come as ``more``
+        self.held = (_Held(n_table - len(blocks), blocks),) + tuple(more)
         self.window = window      # re-based prompt (for preempt requeue)
         self.admit_seq = admit_seq
         self.trace = req.trace
 
+    @property
+    def blocks(self) -> List[int]:
+        return self.held[0].blocks
+
+    @property
+    def n_table(self) -> int:
+        return self.held[0].nxt
+
 
 class PagedDecoder:
-    """Block-pool continuous decode over a TransformerLM (the vLLM/Orca
-    scheduling pair applied to this repo's decode_step —
-    models/transformer.py:710). API-compatible with ContinuousDecoder
+    """Block-pool continuous decode over a TransformerLM, or over a model
+    that brings its own tick and admission (models/hybrid.py), with a pool
+    a KV group (the vLLM/Orca scheduling pair applied to this repo's
+    decode_step — models/transformer.py:710). API-compatible with ContinuousDecoder
     (submit/generate/drain/stop + chaos admission faults + crash
     isolation + dead-worker fast-fail), plus ``slo=`` scheduling classes
     and per-token ``on_token`` streaming callbacks."""
@@ -796,9 +887,12 @@ class PagedDecoder:
         if lm.mesh is not None:
             raise ValueError("paged decode needs a single-device LM "
                              "(mesh-sharded models generate via ring/GSPMD)")
-        if cfg.moe_experts:
-            raise ValueError("paged decode does not support MoE "
-                             "(capacity routing is batch-dependent)")
+        if cfg.moe_experts and not getattr(cfg, "moe_dropless", False):
+            raise ValueError(
+                "paged decode does not serve capacity-routed experts (a "
+                "token dropped past an expert's capacity makes a lane's "
+                "output depend on its batch); a dropless expert layer "
+                "(parallel/expert_parallel.dropless_experts) is served")
         self.lm = lm
         self.cfg = cfg
         # what the model holds per request (ops/memory.cache_needs): the
@@ -847,6 +941,12 @@ class PagedDecoder:
             lanes = max(int(min_lanes),
                         min(64, max(1, self.n_blocks * bt // est_seq)))
         self.lanes = int(lanes)
+        # a pool of blocks a KV group: the stated ``n_blocks`` for a group
+        # that sees every position, a derived size for a window group
+        self.group_blocks = opsmem.kv_group_blocks(
+            self.needs, self.n_blocks, bt, self.lanes)
+        # expert rows a token makes (top_k x expert layers; 0 without)
+        self._moe_rows = int(getattr(cfg, "moe_rows_per_token", 0))
         self.stats = stats if stats is not None else ServingStats()
         self.default_timeout_s = float(default_timeout_s)
         self.queue_cap = int(queue_cap) if queue_cap else None
@@ -856,8 +956,12 @@ class PagedDecoder:
         self._class_map = {c.name: c for c in classes}
         self._default_class = classes[0].name
         self._pending: Dict[str, deque] = {c.name: deque() for c in classes}
+        # a read table a KV group; ``_tables`` is the first group's
+        self._group_tables = [
+            np.zeros((self.lanes, self.table_width), np.int32)
+            for _ in self.needs.groups]
+        self._tables = self._group_tables[0]
         self._reset_arena()
-        self._tables = np.zeros((self.lanes, self.table_width), np.int32)
         self._tok = np.zeros((self.lanes,), np.int32)
         self._pos = np.zeros((self.lanes,), np.int32)
         self._temps = np.ones((self.lanes,), np.float32)
@@ -900,13 +1004,16 @@ class PagedDecoder:
         self._start_worker()
 
     def _refuse_state(self, what: str, asked: bool = True) -> None:
-        """A path that cannot carry per-lane recurrent state refuses a
-        model that keeps one, loudly, where it is asked for."""
+        """A path that cannot carry per-lane recurrent state, or a KV
+        group whose lanes let blocks go as a window passes, refuses a
+        model that has one, loudly, where it is asked for."""
         if asked and self.needs.state:
             raise ValueError(
                 f"{what} cannot carry the recurrent state this model keeps "
                 f"per lane ({', '.join(x.name for x in self.needs.state)}): "
                 "not implemented for models with recurrent layers")
+        if asked and self.needs.windowed:
+            raise ValueError(refuse_window(what))
 
     def _tick_fn(self, k: int):
         fn = self._ticks.get(k)
@@ -948,7 +1055,8 @@ class PagedDecoder:
         no block content is worth keeping — cached prefixes included
         (they would read garbage from a reset arena)."""
         self._arena = self._zero_arena()
-        self._blocks = BlockArena(self.n_blocks)
+        self._pools = [BlockArena(n) for n in self.group_blocks]
+        self._blocks = self._pools[0]
         self._prefix = PrefixCache(self._blocks)
         self.stats.set_kv_blocks(0, self.n_blocks)
 
@@ -971,15 +1079,19 @@ class PagedDecoder:
         and addresses layer ``l`` by row), block 0 of every layer that
         layer's trash; a model that asks for it (``kv_per_layer``: its
         layers differ and are unrolled) gets one ``[n_blocks+1, bt,
-        kv_heads * head_dim]`` a layer."""
+        kv_heads * head_dim]`` a layer, with the blocks of the layer's
+        KV group (``group_blocks``: a window group's pool is smaller)."""
         needs = opsmem.cache_needs(self.cfg)
         layer = (self.n_blocks + 1, self.block_tokens,
                  needs.kv_heads * needs.head_dim)
         zeros = lambda shape: jnp.zeros(shape, self.kv_dtype,
                                         device=self._arena_sharding)
         if needs.kv_per_layer:
-            arena = {"k": tuple(zeros(layer) for _ in range(needs.kv_layers)),
-                     "v": tuple(zeros(layer) for _ in range(needs.kv_layers))}
+            rows = {j: n + 1 for g, n in zip(needs.groups, self.group_blocks)
+                    for j in g.layer_ids}
+            arena = {name: tuple(zeros((rows[j],) + layer[1:])
+                                 for j in range(needs.kv_layers))
+                     for name in ("k", "v")}
         else:
             stacked = (needs.kv_layers,) + layer
             arena = {"k": zeros(stacked), "v": zeros(stacked)}
@@ -1033,6 +1145,15 @@ class PagedDecoder:
             "block_bytes": opsmem.kv_block_bytes(
                 self.cfg, self.block_tokens, self.kv_dtype,
                 devices=int(self.mesh_devices)),
+            # a KV group each: its layers, its window (0: none), its pool
+            "groups": [
+                {"layers": g.layers, "window": g.window,
+                 "blocks": pool.usable, "blocks_in_use": pool.in_use,
+                 "block_bytes": opsmem.kv_block_bytes(
+                     self.cfg, self.block_tokens, self.kv_dtype,
+                     devices=int(self.mesh_devices), group=gi)}
+                for gi, (g, pool) in enumerate(zip(self.needs.groups,
+                                                   self._pools))],
             "state_lanes": self.lanes if self.needs.state else 0,
             "state_bytes": self.lanes * self.needs.state_lane_bytes,
             **self._weights_report(),
@@ -1187,9 +1308,11 @@ class PagedDecoder:
         lane = self._slots[i]
         if lane is None:
             return
-        for b in lane.blocks:
-            self._blocks.decref(b)
-        self._tables[i, :] = 0
+        for pool, table, held in zip(self._pools, self._group_tables,
+                                     lane.held):
+            for b in held.blocks:
+                pool.decref(b)
+            table[i, :] = 0
         # a dead lane attends position 0 of the trash block, as a fresh
         # one does: left at the finished request's last position it
         # would hold up the tick's bound (max over lanes of pos) for as
@@ -1211,6 +1334,25 @@ class PagedDecoder:
             "kv_read": self.lanes * sum(
                 kv_read_tokens(top + j, self.block_tokens, self.table_width)
                 for j in range(k))}
+        if self.needs.windowed:
+            # over the groups, weighted by their layers and divided by the
+            # KV layers: a window layer's lane sees min(pos + 1, window)
+            # positions and loops over its own span of chunks (k is 1)
+            every = self._pos.astype(np.int64)
+            live = read = 0
+            for g in self.needs.groups:
+                if g.window:
+                    live += g.layers * int(
+                        np.minimum(pos + 1, g.window).sum())
+                    read += g.layers * self.lanes * kv_read_tokens_window(
+                        every, g.window, self.block_tokens, self.table_width)
+                else:
+                    live += g.layers * counts["kv_live"]
+                    read += g.layers * counts["kv_read"]
+            counts = {"kv_live": live // self.needs.kv_layers,
+                      "kv_read": read // self.needs.kv_layers}
+        if self._moe_rows:
+            counts["moe_rows"] = len(active) * k * self._moe_rows
         if self.needs.state:
             # live lanes whose recurrent state the tick advances, and the
             # least it moves for them: each lane's ``ssm`` leaf read once
@@ -1261,23 +1403,40 @@ class PagedDecoder:
         admission (possibly lane i itself) on exhaustion. Returns False
         iff lane i was preempted."""
         lane = self._slots[i]
-        while (int(self._pos[i]) + lookahead) // self.block_tokens \
-                >= lane.n_table:
-            b = self._blocks.alloc()
-            if b is None:
-                self._prefix.reclaim(1)
-                b = self._blocks.alloc()
-            if b is None:
-                j = self._youngest_active()
-                self._preempt(j)
-                if j == i:
-                    return False
-                continue
-            lane.blocks.append(b)
-            self._tables[i, lane.n_table] = b
-            lane.n_table += 1
+        reach = (int(self._pos[i]) + lookahead) // self.block_tokens
+        for pool, table, held in zip(self._pools, self._group_tables,
+                                     lane.held):
+            while reach >= held.nxt:
+                b = pool.alloc()
+                if b is None and pool is self._blocks:
+                    self._prefix.reclaim(1)
+                    b = pool.alloc()
+                if b is None:
+                    j = self._youngest_active()
+                    self._preempt(j)
+                    if j == i:
+                        return False
+                    continue
+                held.blocks.append(b)
+                table[i, held.nxt] = b
+                held.nxt += 1
         self.stats.set_kv_blocks(self._blocks.in_use, self.n_blocks)
         return True
+
+    def _trim(self, i: int) -> None:
+        """Lane i's next tick stands at ``_pos[i]``: in a window group the
+        blocks that lie wholly behind ``pos - window`` go back to their
+        pool and their table entries point at trash."""
+        lane = self._slots[i]
+        for g, pool, table, held in zip(self.needs.groups, self._pools,
+                                        self._group_tables, lane.held):
+            if not g.window:
+                continue
+            behind = (int(self._pos[i]) - g.window + 1) // self.block_tokens
+            while held.first < behind:
+                pool.decref(held.blocks.pop(0))
+                table[i, held.first] = 0
+                held.first += 1
 
     def _pick_admission(self):
         """Pop the single next admissible request (highest SLO class
@@ -1326,26 +1485,43 @@ class PagedDecoder:
         wb0 = (keep - 1) // bt        # first write block: always private
         nb_prompt = wb0 + 1
         # a prefix hit restores KV blocks only: a lane whose recurrent
-        # state started after tokens it never saw would be wrong, so a
-        # model with such state takes no hit and counts no lookup
-        hashes = [] if self.needs.state \
+        # state started after tokens it never saw would be wrong, and a
+        # block shared with another lane cannot be let go as one lane's
+        # window passes it, so a model with such state or with a window
+        # group takes no hit and counts no lookup
+        hashes = [] if self.needs.state or self.needs.windowed \
             else PrefixCache.chain_hashes(window, bt, wb0)
         hits = self._prefix.lookup(hashes)
         if hashes:
             self.stats.record_prefix(len(hits), len(hashes))
-        need = nb_prompt - len(hits)
-        if self._blocks.free_count < need:
-            self._prefix.reclaim(need - self._blocks.free_count)
-        if self._blocks.free_count < need:
+        # a further KV group's blocks: from the block that position
+        # keep - window lies in (a window group; the first tick, at
+        # keep - 1, sees no earlier one) to the write block
+        firsts = [max(0, (keep - g.window) // bt) if g.window else 0
+                  for g in self.needs.groups]
+        firsts[0] = max(firsts[0], len(hits))
+        needs = [nb_prompt - f for f in firsts]
+        if self._blocks.free_count < needs[0]:
+            self._prefix.reclaim(needs[0] - self._blocks.free_count)
+        if any(pool.free_count < n for pool, n in zip(self._pools, needs)):
             return None
         for b in hits:
             self._blocks.incref(b)
-        fresh = [self._blocks.alloc() for _ in range(need)]
+        fresh, *more = ([pool.alloc() for _ in range(n)]
+                        for pool, n in zip(self._pools, needs))
         read_table = np.zeros((self.table_width,), np.int32)
         write_table = np.zeros((self.table_width,), np.int32)
         read_table[:len(hits)] = hits
-        read_table[len(hits):nb_prompt] = fresh
-        write_table[len(hits):nb_prompt] = fresh
+        read_table[firsts[0]:nb_prompt] = fresh
+        write_table[firsts[0]:nb_prompt] = fresh
+        writes = [write_table]
+        for gi, blocks in enumerate(more, 1):
+            table = np.zeros((self.table_width,), np.int32)
+            table[firsts[gi]:nb_prompt] = blocks
+            self._group_tables[gi][i, :] = table
+            writes.append(table)
+        if more:
+            write_table = np.stack(writes)
         # cache candidates: private FULL blocks strictly below the write
         # block — they are fully prompt-covered and never written again
         inserts = [(hashes[j], int(read_table[j]))
@@ -1360,8 +1536,9 @@ class PagedDecoder:
                          else seed_key(req.seed))
         self._tables[i, :] = read_table
         self._admit_seq += 1
-        self._slots[i] = _Lane(req, hits + fresh, nb_prompt, window,
-                               self._admit_seq)
+        self._slots[i] = _Lane(
+            req, hits + fresh, nb_prompt, window, self._admit_seq,
+            tuple(_Held(f, b) for f, b in zip(firsts[1:], more)))
         self.stats.set_kv_blocks(self._blocks.in_use, self.n_blocks)
         return buf, width, write_table, inserts
 
@@ -1374,7 +1551,7 @@ class PagedDecoder:
         # many positions feed the lane's state: all but the last prompt
         # token, which the first tick re-consumes (self._pos[i])
         lane = (jnp.asarray([i, self._pos[i]], jnp.int32),) \
-            if self.needs.state else ()
+            if self.needs.state or self.needs.windowed else ()
         self._arena = self._build_admit(width)(
             self._infer_params, self._arena, jnp.asarray(buf),
             jnp.asarray(write_table), *lane)
@@ -1566,7 +1743,8 @@ class PagedDecoder:
             for i in range(self.lanes):
                 self._release_lane(i)
             self._reset_arena()
-            self._tables[:, :] = 0
+            for table in self._group_tables:
+                table[:, :] = 0
             self._cond.notify_all()
         for st in victims:
             if not st.future.done():
@@ -1680,7 +1858,9 @@ class PagedDecoder:
             lane = self._slots[i]
             tr = lane.trace
             if tr is not None:
-                fresh = int(np.count_nonzero(write_table))
+                fresh = int(np.count_nonzero(
+                    write_table if write_table.ndim == 1
+                    else write_table[0]))
                 sp.set_parent(tr.parent)
                 for key, value in (
                         ("rid", tr.rid), ("lane", i),
@@ -1692,6 +1872,9 @@ class PagedDecoder:
                     sp.set_attr(key, value)
                 if self.needs.state:
                     sp.set_attr("scan_chunks", self.cfg.scan_chunks(width))
+                if self._moe_rows:
+                    sp.set_attr("moe_rows",
+                                int(lane.window.size) * self._moe_rows)
             try:
                 if self._chaos is not None:
                     self._chaos.on_admit()
@@ -1786,10 +1969,12 @@ class PagedDecoder:
                                 admit_width_sum=width_sum,
                                 **kv) as sp_tick:
                 with obs_trace.span("serve.tick.stage"):
-                    self._arena, nxt, keys = self._tick_fn(k)(
+                    tables = self._tables if len(self._group_tables) == 1 \
+                        else np.stack(self._group_tables)
+                    self._arena, nxt, keys, *more = self._tick_fn(k)(
                         self._infer_params, self._arena,
                         jnp.asarray(self._tok), jnp.asarray(self._pos),
-                        jnp.asarray(self._tables),
+                        jnp.asarray(tables),
                         jnp.asarray(self._keys),
                         jnp.asarray(self._temps))
                 # the device has its next program: the last tick's tokens
@@ -1797,6 +1982,10 @@ class PagedDecoder:
                 self._deliver()
                 with obs_trace.span("serve.tick.wait"):
                     nxt = np.asarray(nxt)
+                if more and kv:
+                    # the tick is done: its count of the experts that got
+                    # a live lane's row is there with its tokens
+                    sp_tick.set_attr("moe_experts_hit", int(more[0]))
         except Exception as e:  # noqa: BLE001 — device boundary
             self._deliver()     # tokens the last tick gave come first
             self._fail_active_lanes(e)
@@ -1846,6 +2035,9 @@ class PagedDecoder:
                         completions.append(st)
                         self._release_lane(i)
                         break
+                else:
+                    if self.needs.windowed:
+                        self._trim(i)
             follows = self.defer_delivery and (
                 self._total_pending() > 0
                 or any(st is not None for st in self._slots))

@@ -81,6 +81,7 @@ from deeplearning4j_tpu.serving.paged import (
     PagedDecoder,
     attention_path,
     paged_decode_step,
+    refuse_window,
 )
 
 _VERIFY_CACHE: Dict[tuple, object] = {}
@@ -159,6 +160,10 @@ class SpeculativeDecoder(PagedDecoder):
                 "per-lane recurrent state: a rejected draft token would "
                 "have to be taken back out of it; not implemented for "
                 "models with recurrent layers")
+        if opsmem.cache_needs(cfg).windowed or \
+                opsmem.cache_needs(dcfg).windowed:
+            raise ValueError(refuse_window(
+                "speculative decoding (DL4J_TPU_SERVE_SPEC)"))
         if (dcfg.vocab_size != cfg.vocab_size
                 or dcfg.max_len != cfg.max_len):
             raise ValueError(

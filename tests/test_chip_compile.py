@@ -319,6 +319,80 @@ def test_hybrid_tick_rewrites_its_state_in_place_on_v5e(one_chip,
         assert scope in text, scope
 
 
+def _smallthinker_programs(one_chip, width):
+    """One period (global, window, window, window) of the routed-expert
+    serve cell at its published widths (d 2560, 28 heads of 128 over 4 KV
+    heads, 64 experts of 768, window 4,096), 48 lanes at a context of
+    8,192: the compiled tick and the compiled admission at `width`."""
+    from deeplearning4j_tpu.models import hybrid
+    from deeplearning4j_tpu.ops import memory as opsmem
+    from deeplearning4j_tpu.serving import paged
+
+    cfg = hybrid.HybridConfig(
+        vocab_size=4096, d_model=2560, n_heads=28, n_kv_heads=4,
+        attn_head_dim=128, d_ff=768, layer_types=("attention",) * 4,
+        max_len=8192, rope=(False, True, True, True), rope_theta=1.5e6,
+        window=(0, 4096, 4096, 4096), attn_exact=False, ffn="experts",
+        ffn_act="relu", moe_experts=64, moe_top_k=6, tie_head=False,
+        embedding_multiplier=1.0, residual_multiplier=1.0,
+        attention_multiplier=128 ** -0.5, logits_scaling=1.0, rms_eps=1e-6)
+    lanes, n_blocks, bt = 48, 1024, 16
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    params = jax.tree.map(
+        lambda s: arg(s, jnp.bfloat16), hybrid.param_shapes(cfg),
+        is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[0], int))
+    needs = opsmem.cache_needs(cfg)
+    blocks = opsmem.kv_group_blocks(needs, n_blocks, bt, lanes)
+    rows = {j: n + 1 for g, n in zip(needs.groups, blocks)
+            for j in g.layer_ids}
+    arena = {name: tuple(arg((rows[j], bt, 512), jnp.bfloat16)
+                         for j in range(4)) for name in ("k", "v")}
+    m = cfg.max_len // bt
+    with jax.enable_x64(False):
+        tick = paged._paged_tick_for(cfg, bt).lower(
+            params, arena, arg((lanes,), jnp.int32), arg((lanes,), jnp.int32),
+            arg((2, lanes, m), jnp.int32), arg((lanes, 2), jnp.uint32),
+            arg((lanes,), jnp.float32)).compile()
+        admit = paged._paged_admit_for(cfg, width, bt).lower(
+            params, arena, arg((1, width), jnp.int32), arg((2, m), jnp.int32),
+            arg((2,), jnp.int32)).compile()
+    paged._PAGED_TICK_CACHE.pop((cfg, bt, "gather", 1), None)
+    paged._PAGED_ADMIT_CACHE.pop((cfg, width, bt), None)
+    return tick, admit, arena
+
+
+def test_smallthinker_programs_read_the_experts_in_place_on_v5e(
+        one_chip, no_compile_cache):
+    """The tick and an admission of 2,048 positions compile for a v5e; no
+    program copies, slices or transposes a buffer of an expert matrix's
+    shape (a layer's 64 experts are one buffer of their own: sliced out of
+    a stack they were copied for the grouped kernel, 0.75 GB a layer), nor
+    one of the arena's; the arena is donated and aliased whole; the tick
+    takes the batched product (no grouped kernel), the admission the
+    grouped one; the admission's attention goes by query blocks (nothing of
+    [28, 2048, 2048] scores) and a window layer's keys by the band."""
+    tick, admit, arena = _smallthinker_programs(one_chip, 2048)
+    donated = sum(int(np.prod(a.shape)) * 2 for a in jax.tree.leaves(arena))
+    for name, compiled in (("tick", tick), ("admit", admit)):
+        text = compiled.as_text()
+        entry = text[text.index("\nENTRY "):]
+        assert not re.findall(r" = bf16\[64,(?:2560,1536|768,2560)\]\S* "
+                              r"(?:copy|transpose|slice|dynamic-slice)\(",
+                              entry), name
+        assert not re.findall(r" = bf16\[1025,16,512\]\S* "
+                              r"(?:copy|transpose)\(", entry), name
+        assert compiled.memory_analysis().alias_size_in_bytes >= donated
+    grouped = 'custom_call_target="tpu_custom_call"'
+    assert grouped not in tick.as_text()
+    assert grouped in admit.as_text()
+    # 2,048 rows x 6 pairs through the grouped kernel, the window's whole
+    # band inside the sequence: one block of query rows, keys 0 .. 2,047
+    assert "12288,2560]" in admit.as_text()
+    assert admit.memory_analysis().temp_size_in_bytes < 1.2e9
+    assert tick.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
 # ---------------------------------------------------------------------------
 # 2. the scopes, in the lowered programs
 # ---------------------------------------------------------------------------
@@ -379,6 +453,40 @@ def test_tick_and_admit_programs_hold_the_scope_names():
         params, arena, i32(1, 16), i32(m)).as_text(debug_info=True)
     missing = [s for s in ADMIT_SCOPES if not _has_scope(admit, s)]
     assert not missing, missing
+
+
+def test_smallthinker_tick_and_admit_hold_the_five_new_scopes():
+    """A tiny model of routed experts and window layers: the tick's and the
+    admission's lowered text holds the scopes of the expert layer and of
+    the admission's attention, beside the ones every tick has."""
+    from deeplearning4j_tpu.models import hybrid
+    from deeplearning4j_tpu.serving import paged
+
+    cfg = hybrid.HybridConfig(
+        vocab_size=64, d_model=32, n_heads=4, n_kv_heads=2, attn_head_dim=16,
+        d_ff=16, layer_types=("attention",) * 2, max_len=32,
+        rope=(False, True), window=(0, 8), ffn="experts", ffn_act="relu",
+        moe_experts=4, moe_top_k=2, tie_head=False, dtype_policy="strict")
+    bt, lanes, m = 4, 3, 8
+    params = jax.eval_shape(
+        lambda: hybrid.init_params(cfg, jax.random.PRNGKey(0)))
+    layer = lambda n: jax.ShapeDtypeStruct((n + 1, bt, 32), jnp.float32)
+    arena = {"k": (layer(16), layer(9)), "v": (layer(16), layer(9))}
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    tick = paged._paged_tick_for(cfg, bt).lower(
+        params, arena, i32(lanes), i32(lanes), i32(2, lanes, m),
+        jax.ShapeDtypeStruct((lanes, 2), jnp.uint32),
+        jax.ShapeDtypeStruct((lanes,), jnp.float32)).as_text(debug_info=True)
+    for scope in TICK_SCOPES + ("tick.moe_route", "tick.moe_experts"):
+        assert _has_scope(tick, scope), scope
+    admit = paged._paged_admit_for(cfg, 16, bt).lower(
+        params, arena, i32(1, 16), i32(2, m), i32(2)).as_text(
+        debug_info=True)
+    for scope in ("admit.prefill", "admit.scatter", "admit.attend",
+                  "admit.moe_route", "admit.moe_experts"):
+        assert _has_scope(admit, scope), scope
+    paged._PAGED_TICK_CACHE.pop((cfg, bt, "gather", 1), None)
+    paged._PAGED_ADMIT_CACHE.pop((cfg, 16, bt), None)
 
 
 @pytest.mark.parametrize("which", ["flash_bwd", "flash_ext_bwd"])

@@ -620,3 +620,111 @@ def test_import_admit_tick_preempt_equals_the_fixed_slot_decoder(kind):
         dec.stop()
     for base, out in zip(bases, outs):
         np.testing.assert_array_equal(base, out)
+
+
+# ---------------------------------------------------------------------------
+# (h) a lower bound a lane: the window layers of models/hybrid.py (ISSUE 37)
+# ---------------------------------------------------------------------------
+
+
+def _window_case(pos, window, kv_heads=2, n_heads=4, hd=8, seed=13,
+                 freed=True):
+    """Lanes at `pos` that each see the newest `window` positions; the
+    blocks wholly behind a lane's window are trash in its table, as the
+    decoder leaves them once it has let them go."""
+    lanes, m = len(pos), MAX_LEN // BT
+    rng = np.random.default_rng(seed)
+    tables = _dense_tables(lanes, m)
+    shape = (lanes * m + 1, BT, kv_heads * hd)
+    ck = rng.normal(size=shape).astype(np.float32)
+    cv = rng.normal(size=shape).astype(np.float32)
+    ck[0] = cv[0] = 1e4
+    pos = np.asarray(pos, np.int32)
+    lo = np.maximum(pos - (window - 1), 0).astype(np.int32)
+    for i in range(lanes):
+        tables[i, pos[i] // BT + 1:] = 0
+        if freed:
+            tables[i, :lo[i] // BT] = 0
+    q = rng.normal(size=(lanes, n_heads, hd)).astype(np.float32)
+    return q, ck, cv, tables, pos, lo
+
+
+def _window_oracle(q, ck, cv, tables, pos, lo):
+    """The masked dense product, lane by lane: positions lo .. pos alone."""
+    s, n_heads, hd = q.shape
+    kv_heads = ck.shape[2] // hd
+    out = np.zeros((s, n_heads, hd), np.float32)
+    for i in range(s):
+        k = ck[tables[i]].reshape(-1, kv_heads, hd)[lo[i]:pos[i] + 1]
+        v = cv[tables[i]].reshape(-1, kv_heads, hd)[lo[i]:pos[i] + 1]
+        k, v = (np.repeat(a, n_heads // kv_heads, axis=1) for a in (k, v))
+        sc = np.einsum("hd,thd->ht", q[i], k) / np.sqrt(hd)
+        p = np.exp(sc - sc.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        out[i] = np.einsum("ht,thd->hd", p, v)
+    return out
+
+
+@pytest.mark.parametrize("pos, window", [
+    ([0, 5, CHUNK - 1], 16),                      # every lo in chunk 0
+    ([CHUNK + 3, 3 * CHUNK + 5, 5], 16),          # lo in chunks 0, 2 and 0
+    ([MAX_LEN - 1, 2 * CHUNK, CHUNK], CHUNK),     # a window of one chunk
+    ([MAX_LEN - 1, 4 * CHUNK + 1, 0], 2 * CHUNK + 4),
+], ids=["inside_chunk_0", "lo_in_different_chunks", "window_of_a_chunk",
+        "window_over_chunk_edges"])
+def test_chunked_attention_with_a_lower_bound_equals_the_masked_product(
+        pos, window):
+    q, ck, cv, tables, pos, lo = _window_case(pos, window)
+    got = jax.jit(paged.chunked_attention)(
+        jnp.asarray(q), jnp.asarray(ck), jnp.asarray(cv),
+        jnp.asarray(tables), jnp.asarray(pos), lo=jnp.asarray(lo))
+    assert np.isfinite(np.asarray(got)).all()    # no -inf met -inf
+    np.testing.assert_allclose(
+        np.asarray(got), _window_oracle(q, ck, cv, tables, pos, lo),
+        rtol=1e-6, atol=1e-6)
+
+
+def test_a_lower_bound_of_zero_is_bit_equal_to_none():
+    """`lo = 0` walks the same chunks in the same order as no bound: the
+    same bits. Without a bound the function is what it was, so the dense
+    and the hybrid ticks' programs are too."""
+    q, ck, cv, tables, pos, _lo = _window_case(
+        [3, CHUNK + 7, 3 * CHUNK], MAX_LEN, freed=False)
+    args = tuple(jnp.asarray(a) for a in (q, ck, cv, tables, pos))
+    plain = jax.jit(paged.chunked_attention)(*args)
+    bounded = jax.jit(paged.chunked_attention)(
+        *args, lo=jnp.zeros((3,), jnp.int32))
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(bounded))
+    np.testing.assert_allclose(
+        np.asarray(plain),
+        _oracle(q, ck, cv, tables, pos), rtol=1e-6, atol=1e-6)
+    text = jax.jit(paged.chunked_attention).lower(*args).as_text()
+    assert "gather" in text and text.count("stablehlo.while") == 1
+
+
+def test_a_window_lane_keeps_its_bits_beside_a_longer_lane():
+    """A lane's passes start at its own first chunk and end past its own
+    position with exact no-ops: alone or beside a lane four chunks longer
+    (whose span of chunks sets the trip count), the same bits."""
+    window = 16
+    q, ck, cv, tables, pos, lo = _window_case(
+        [CHUNK + 3, 5 * CHUNK + 9], window)
+    both = jax.jit(paged.chunked_attention)(
+        *(jnp.asarray(a) for a in (q, ck, cv, tables, pos)),
+        lo=jnp.asarray(lo))
+    alone = jax.jit(paged.chunked_attention)(
+        *(jnp.asarray(a[:1]) for a in (q,)), jnp.asarray(ck),
+        jnp.asarray(cv), jnp.asarray(tables[:1]), jnp.asarray(pos[:1]),
+        lo=jnp.asarray(lo[:1]))
+    np.testing.assert_array_equal(np.asarray(both[0]), np.asarray(alone[0]))
+
+
+def test_kv_read_tokens_window_is_the_programs_trip_count():
+    m = MAX_LEN // BT
+    pos = np.array([CHUNK + 3, 3 * CHUNK + 5, 5], np.int64)
+    # window 16: lane 0 spans chunks 0..1, lane 1 chunk 3 alone (its lo,
+    # 3 * CHUNK - 10, lies in chunk 2: chunks 2..3), lane 2 chunk 0
+    assert paged.kv_read_tokens_window(pos, 16, BT, m) == 2 * CHUNK
+    assert paged.kv_read_tokens_window(pos, MAX_LEN, BT, m) \
+        == paged.kv_read_tokens(int(pos.max()), BT, m)
+    assert paged.kv_read_tokens_window(np.array([0, 0]), 16, BT, m) == CHUNK
