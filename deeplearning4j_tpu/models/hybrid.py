@@ -39,9 +39,10 @@ chunks the carried ``S``), the decode tick one step on the lane's stored
 ``S`` and its last ``ssm_conv - 1`` rows of ``xBC``. Two of attention too:
 the admission attends a whole prompt by blocks of query rows over chunks of
 keys (``attention_full``: no ``[H, T, T]`` scores at 8,192 positions, a
-window layer reads its band), the tick one query a lane over the lane's
-blocks (serving/paged.chunked_attention, with a lower bound a lane in a
-window layer). And two of the expert layer, by the rows: every expert on
+window layer reads its band; with bf16 products and Pallas on, one kernel
+that writes no score at all, ops/pallas_prefill.py), the tick one query a
+lane over the lane's blocks (serving/paged.chunked_attention, with a lower
+bound a lane in a window layer). And two of the expert layer, by the rows: every expert on
 every lane in one batched product in the tick, rows sorted by expert through
 grouped products in the admission.
 
@@ -366,6 +367,19 @@ class HybridConfig:
         """Chunks the admission prefill's scan walks at a bucket width."""
         return width // _chunk_len(self, width)
 
+    def admit_attend(self, width: int) -> str:
+        """Which attention the admission runs at a bucket width: ``kernel``
+        (ops/pallas_prefill.py) where Pallas is on, the products read bf16
+        (not ``attn_exact``) and its tiles divide the width; ``xla`` (the
+        by-blocks path of ``attention_full``) otherwise."""
+        from deeplearning4j_tpu.ops import pallas_prefill
+        from deeplearning4j_tpu.ops.pallas_kernels import pallas_enabled
+
+        fits = pallas_prefill.fits(width, self.n_heads // self.n_kv_heads,
+                                   self.head_dim)
+        return "kernel" if not self.attn_exact and fits and \
+            pallas_enabled() else "xla"
+
 
 # ---------------------------------------------------------------------------
 # weights
@@ -689,13 +703,24 @@ def attention_full(u, ap, cfg: HybridConfig, kv_dtype, j: int = 0):
     its band and a global layer the causal half. A sequence that fits is
     one block over one chunk: the plain softmax. The products multiply in
     float32 (``attn_exact``), or read q and the probabilities in the
-    arena's dtype and sum in float32."""
+    arena's dtype and sum in float32. Where ``cfg.admit_attend`` says
+    ``kernel`` the same arithmetic runs in one Pallas call
+    (ops/pallas_prefill.py) that writes no score to HBM."""
     t = u.shape[0]
     hk, grp, hd = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim
     hi = lax.Precision.HIGHEST
     window = cfg.window_of(j)
     q, k, v = _qkv(u, ap, cfg, j, jnp.arange(t))
     k, v = k.astype(kv_dtype), v.astype(kv_dtype)
+    if cfg.admit_attend(t) == "kernel":
+        from deeplearning4j_tpu.ops.pallas_prefill import prefill_attention
+
+        with jax.named_scope("admit.attend"):
+            att = prefill_attention(
+                q.reshape(t, cfg.q_dim).astype(kv_dtype), k.reshape(t, -1),
+                v.reshape(t, -1), head_dim=hd,
+                scale=cfg.attention_multiplier, window=window)
+        return _mm(att, ap["Wo"]), k, v
     # a KV head's products: its g query heads' rows side by side against
     # its keys, [Hkv, g * rows, keys], the keys innermost (with the scores
     # laid "kgts" out of one einsum the chip's compiler put the rows

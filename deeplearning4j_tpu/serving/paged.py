@@ -1872,6 +1872,8 @@ class PagedDecoder:
                     sp.set_attr(key, value)
                 if self.needs.state:
                     sp.set_attr("scan_chunks", self.cfg.scan_chunks(width))
+                if hasattr(self.cfg, "admit_attend"):
+                    sp.set_attr("attend", self.cfg.admit_attend(width))
                 if self._moe_rows:
                     sp.set_attr("moe_rows",
                                 int(lane.window.size) * self._moe_rows)

@@ -319,14 +319,13 @@ def test_hybrid_tick_rewrites_its_state_in_place_on_v5e(one_chip,
         assert scope in text, scope
 
 
-def _smallthinker_programs(one_chip, width):
+def _smallthinker_period(one_chip):
     """One period (global, window, window, window) of the routed-expert
     serve cell at its published widths (d 2560, 28 heads of 128 over 4 KV
     heads, 64 experts of 768, window 4,096), 48 lanes at a context of
-    8,192: the compiled tick and the compiled admission at `width`."""
+    8,192: (cfg, params, arena, arg) as shapes on the described chip."""
     from deeplearning4j_tpu.models import hybrid
     from deeplearning4j_tpu.ops import memory as opsmem
-    from deeplearning4j_tpu.serving import paged
 
     cfg = hybrid.HybridConfig(
         vocab_size=4096, d_model=2560, n_heads=28, n_kv_heads=4,
@@ -348,18 +347,36 @@ def _smallthinker_programs(one_chip, width):
             for j in g.layer_ids}
     arena = {name: tuple(arg((rows[j], bt, 512), jnp.bfloat16)
                          for j in range(4)) for name in ("k", "v")}
-    m = cfg.max_len // bt
+    return cfg, params, arena, arg
+
+
+def _smallthinker_admit(one_chip, width):
+    """The period's admission at `width`, compiled for the described chip."""
+    from deeplearning4j_tpu.serving import paged
+
+    cfg, params, arena, arg = _smallthinker_period(one_chip)
+    bt, m = 16, cfg.max_len // 16
     with jax.enable_x64(False):
-        tick = paged._paged_tick_for(cfg, bt).lower(
-            params, arena, arg((lanes,), jnp.int32), arg((lanes,), jnp.int32),
-            arg((2, lanes, m), jnp.int32), arg((lanes, 2), jnp.uint32),
-            arg((lanes,), jnp.float32)).compile()
         admit = paged._paged_admit_for(cfg, width, bt).lower(
             params, arena, arg((1, width), jnp.int32), arg((2, m), jnp.int32),
             arg((2,), jnp.int32)).compile()
-    paged._PAGED_TICK_CACHE.pop((cfg, bt, "gather", 1), None)
     paged._PAGED_ADMIT_CACHE.pop((cfg, width, bt), None)
-    return tick, admit, arena
+    return admit
+
+
+def _smallthinker_programs(one_chip, width):
+    """The period's compiled tick and its compiled admission at `width`."""
+    from deeplearning4j_tpu.serving import paged
+
+    cfg, params, arena, arg = _smallthinker_period(one_chip)
+    lanes, bt = 48, 16
+    with jax.enable_x64(False):
+        tick = paged._paged_tick_for(cfg, bt).lower(
+            params, arena, arg((lanes,), jnp.int32), arg((lanes,), jnp.int32),
+            arg((2, lanes, cfg.max_len // bt), jnp.int32),
+            arg((lanes, 2), jnp.uint32), arg((lanes,), jnp.float32)).compile()
+    paged._PAGED_TICK_CACHE.pop((cfg, bt, "gather", 1), None)
+    return tick, _smallthinker_admit(one_chip, width), arena
 
 
 def test_smallthinker_programs_read_the_experts_in_place_on_v5e(
@@ -391,6 +408,77 @@ def test_smallthinker_programs_read_the_experts_in_place_on_v5e(
     assert "12288,2560]" in admit.as_text()
     assert admit.memory_analysis().temp_size_in_bytes < 1.2e9
     assert tick.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+def test_smallthinker_admission_attends_in_the_kernel_on_v5e(
+        one_chip, no_compile_cache, monkeypatch):
+    """The period admitted at 8,192 with Pallas on (the chip's default):
+    its global layer and the window layers each call the attention kernel
+    under its own name, as a Mosaic call (the last layer's attention feeds
+    nothing the admission keeps, so the compiler drops it with Pallas on or
+    off: 1 + 2 calls); no float32 buffer of the plain path's pass of scores
+    ([4 KV heads, 7 x 2,048 rows, 2,048 keys], hybrid._attend_tiles)
+    remains; the temporaries fall below the plain path's, 1,318,196,224
+    against 1,334,432,768 bytes (the grouped expert product's 49,152 rows
+    set the peak either way)."""
+    monkeypatch.setenv("DL4J_TPU_PALLAS", "force")
+    kernel = _smallthinker_admit(one_chip, 8192)
+    monkeypatch.setenv("DL4J_TPU_PALLAS", "0")
+    plain = _smallthinker_admit(one_chip, 8192)
+    text, plain_text = kernel.as_text(), plain.as_text()
+    called = sorted(m.group(1) for m in (
+        re.match(r"\s*(?:ROOT )?%(prefill_attn(?:_w\d+)?)(?:\.\d+)? = ", ln)
+        for ln in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in ln) if m)
+    assert called == ["prefill_attn"] + ["prefill_attn_w4096"] * 2, called
+    assert "prefill_attn" not in plain_text
+    scores = "f32[4,14336,2048]"
+    assert scores in plain_text and scores not in text
+    temp = kernel.memory_analysis().temp_size_in_bytes
+    plain_temp = plain.memory_analysis().temp_size_in_bytes
+    assert temp < plain_temp < 1.4e9, (temp, plain_temp)
+
+
+# granite's admission at its widest bucket (512: prompts to 448), lowered
+# with Pallas on: its products are float32 (``attn_exact``), so it keeps the
+# plain path, and the text is the parent's of PR 38 to the byte. A later
+# change to that program updates the digest, saying why.
+GRANITE_ADMIT_512 = \
+    "e4ca69d3b6e2e6355639b7cfa8883317fc7d86e3524ca53e71d125fa7b76e251"
+
+
+def test_granite_admission_lowers_as_before(monkeypatch):
+    import hashlib
+
+    from deeplearning4j_tpu.models import hybrid
+    from deeplearning4j_tpu.ops import memory as opsmem
+    from deeplearning4j_tpu.serving import paged
+    from perfbench import harness
+
+    monkeypatch.setenv("DL4J_TPU_PALLAS", "force")
+    conf = harness.load_json(os.path.join(
+        harness.HERE, "configs", "granite-4.0-h-micro.json"))
+    cfg = hybrid.HybridConfig.from_published(conf, max_len=2048)
+    assert cfg.attn_exact and cfg.admit_attend(512) == "xla"
+    lanes, blocks, bt, width = 64, 4096, 16, 512
+    sd = jax.ShapeDtypeStruct
+    params = jax.tree.map(
+        lambda s: sd(s, jnp.bfloat16), hybrid.param_shapes(cfg),
+        is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[0], int))
+    needs = opsmem.cache_needs(cfg)
+    kv = sd((blocks + 1, bt, needs.kv_heads * needs.head_dim), jnp.bfloat16)
+    arena = {"k": (kv,) * cfg.n_attention, "v": (kv,) * cfg.n_attention}
+    for leaf in needs.state:
+        arena[leaf.name] = tuple(sd((lanes,) + leaf.shape, leaf.dtype)
+                                 for _ in range(leaf.layers))
+    i32 = lambda *shape: sd(shape, jnp.int32)
+    with jax.enable_x64(False):
+        text = paged._paged_admit_for(cfg, width, bt).lower(
+            params, arena, i32(1, width), i32(cfg.max_len // bt),
+            i32(2)).as_text()
+    paged._PAGED_ADMIT_CACHE.pop((cfg, width, bt), None)
+    assert "tpu_custom_call" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == GRANITE_ADMIT_512
 
 
 # ---------------------------------------------------------------------------

@@ -409,6 +409,7 @@ def test_the_tick_span_counts_state_lanes_and_bytes():
     assert all(t["attrs"]["ssm_lanes"] == 1
                and t["attrs"]["ssm_state_bytes"] == 2 * lane for t in ticks)
     assert [a["attrs"]["scan_chunks"] for a in admits] == [12 // 4]
+    assert [a["attrs"]["attend"] for a in admits] == ["xla"]
 
 
 def test_the_state_pool_is_read_as_the_last_tick_left_it():

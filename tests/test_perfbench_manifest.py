@@ -69,9 +69,11 @@ def test_the_routed_expert_cell_is_entered_as_the_issue_names_it(manifest):
         manifest, CELL, "per_layer", sorted(e2e))}
     only_here = {m["name"] for m in manifest["per_layer"]
                  if m.get("workloads") == [CELL]}
+    # PR 38 added the admission attention kernel's roofline
     assert only_here == {"step_mfu.serve_moe",
                          "moe_experts_roofline.serve_moe",
-                         "moe_experts_ms_per_tick.serve_moe"}
+                         "moe_experts_ms_per_tick.serve_moe",
+                         "prefill_attn_roofline.serve_moe"}
     assert mine == only_here | {
         "tokens_per_tick.serve", "device_idle.serve",
         "tick_host_p50_ms.serve", "idle_in_admit.serve",

@@ -485,6 +485,8 @@ def test_the_spans_count_expert_rows_and_positions_over_groups(strict):
         obs_trace.set_enabled(None)
     assert len(ticks) == 6 and len(admits) == 1
     assert admits[0]["attrs"]["moe_rows"] == 30 * 2 * 8
+    # no Pallas on the CPU: the admission attends in plain XLA, and says so
+    assert admits[0]["attrs"]["attend"] == "xla"
     assert admits[0]["attrs"]["lookup_blocks"] == 7
     chunk = 8 * BT
     for i, t in enumerate(ticks):
