@@ -567,9 +567,13 @@ class ServingEngine:
                                  rows=1, kind="generate_stream")
         on_token = q.put
         if sp is not obs_trace.NULL_SPAN:
+            # the first token's time and the newest's: what the span
+            # lasts past the last one is the stream's own tail
             def on_token(t):
+                now = time.perf_counter() - sp.start
                 if "ttft_s" not in sp.attrs:
-                    sp.set_attr("ttft_s", time.perf_counter() - sp.start)
+                    sp.set_attr("ttft_s", now)
+                sp.set_attr("last_token_s", now)
                 q.put(t)
         try:
             fut = decoder.submit(prompt, int(n_new),

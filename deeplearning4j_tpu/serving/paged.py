@@ -1971,23 +1971,31 @@ class PagedDecoder:
                                 admit_width_sum=width_sum,
                                 **kv) as sp_tick:
                 with obs_trace.span("serve.tick.stage"):
-                    tables = self._tables if len(self._group_tables) == 1 \
-                        else np.stack(self._group_tables)
-                    self._arena, nxt, keys, *more = self._tick_fn(k)(
-                        self._infer_params, self._arena,
-                        jnp.asarray(self._tok), jnp.asarray(self._pos),
-                        jnp.asarray(tables),
-                        jnp.asarray(self._keys),
-                        jnp.asarray(self._temps))
+                    # the inputs' build and upload, then the dispatch
+                    # alone, inside which the program goes onto the
+                    # device's queue
+                    with obs_trace.span("serve.tick.upload"):
+                        tables = self._tables \
+                            if len(self._group_tables) == 1 \
+                            else np.stack(self._group_tables)
+                        inputs = (jnp.asarray(self._tok),
+                                  jnp.asarray(self._pos),
+                                  jnp.asarray(tables),
+                                  jnp.asarray(self._keys),
+                                  jnp.asarray(self._temps))
+                    with obs_trace.span("serve.tick.dispatch"):
+                        self._arena, nxt, keys, *more = self._tick_fn(k)(
+                            self._infer_params, self._arena, *inputs)
+                    del inputs
                 # the device has its next program: the last tick's tokens
                 # go to their clients under it
                 self._deliver()
                 with obs_trace.span("serve.tick.wait"):
                     nxt = np.asarray(nxt)
-                if more and kv:
-                    # the tick is done: its count of the experts that got
-                    # a live lane's row is there with its tokens
-                    sp_tick.set_attr("moe_experts_hit", int(more[0]))
+                    if more and kv:
+                        # the tick is done: its count of the experts that
+                        # got a live lane's row is there with its tokens
+                        sp_tick.set_attr("moe_experts_hit", int(more[0]))
         except Exception as e:  # noqa: BLE001 — device boundary
             self._deliver()     # tokens the last tick gave come first
             self._fail_active_lanes(e)
@@ -2010,7 +2018,8 @@ class PagedDecoder:
         the decoder does not defer, else once the next program is on it:
         waking one streaming thread a lane costs the worker the
         interpreter for milliseconds, which the device then idles."""
-        self._keys = np.array(keys)  # writable copy (admits write rows)
+        with obs_trace.span("serve.tick.read_keys"):
+            self._keys = np.array(keys)  # writable copy (admits write rows)
         self.dispatch_stats.decode_ticks += 1
         self.dispatch_stats.decode_tokens += len(active) * k
         callbacks = []

@@ -472,7 +472,9 @@ def test_profiler_host_plane_holds_the_span_names(obs_on, lm, tmp_path):
             for line in plane.lines:
                 names.update(ev.name for ev in line.events)
     for want in ("serve.admit", "serve.admit.dispatch", "serve.batch",
-                 "serve.tick.stage", "serve.tick.wait", "serve.tick.emit"):
+                 "serve.tick.stage", "serve.tick.wait", "serve.tick.emit",
+                 "serve.tick.upload", "serve.tick.dispatch",
+                 "serve.tick.read_keys"):
         assert want in names, (want, sorted(n for n in names
                                             if n.startswith("serve")))
     # a wait recorded after the fact and a span that crosses threads are
@@ -504,3 +506,68 @@ def test_delivery_runs_under_the_next_dispatch(obs_on, lm):
     last_emit = by_start(obs_on.spans("serve.tick.emit"))[-1]
     assert last_emit["attrs"]["tick"] == ticks[-1]["span_id"]
     assert delivers[-1]["parent_id"] == last_emit["span_id"]
+
+
+def test_stage_is_upload_then_dispatch_and_keys_are_read_in_emit(obs_on, lm):
+    """Every tick's ``serve.tick.stage`` holds exactly one
+    ``serve.tick.upload`` (the inputs built and sent) and then one
+    ``serve.tick.dispatch`` (the jitted call alone, whose end is where the
+    program is on the device's queue); the keys' readback
+    ``serve.tick.read_keys`` lies inside the tick's ``serve.tick.emit``."""
+    engine = ServingEngine(model=lm)
+    try:
+        decoder = engine._decoder_for(engine.registry.get(None, None))
+        futs = [decoder.submit([3 + i, 1, 4], 3 + i, temperature=0.0)
+                for i in range(3)]
+        for f in futs:
+            f.result(timeout=240)
+    finally:
+        engine.stop()
+    ticks = [s for s in obs_on.spans("serve.batch")
+             if s["attrs"]["kind"] == "decode.paged"]
+    stages = {s["span_id"]: s for s in obs_on.spans("serve.tick.stage")}
+    assert len(stages) == len(ticks) >= 5
+    assert {s["parent_id"] for s in stages.values()} == \
+        {t["span_id"] for t in ticks}
+    children = {sid: [] for sid in stages}
+    for name in ("serve.tick.upload", "serve.tick.dispatch"):
+        for s in obs_on.spans(name):
+            children[s["parent_id"]].append(s)
+    for sid, kids in children.items():
+        kids.sort(key=lambda s: s["t_mono"])
+        assert [k["name"] for k in kids] == ["serve.tick.upload",
+                                             "serve.tick.dispatch"]
+        up, disp = kids
+        assert _inside(up, stages[sid]) and _inside(disp, stages[sid])
+        assert _end(up) <= disp["t_mono"] + EPS
+    emits = {s["span_id"]: s for s in obs_on.spans("serve.tick.emit")}
+    reads = obs_on.spans("serve.tick.read_keys")
+    assert len(reads) == len(emits) == len(ticks)
+    for r in reads:
+        assert _inside(r, emits[r["parent_id"]])
+
+
+def test_streamed_request_times_its_last_token(obs_on, lm):
+    """``last_token_s`` is the newest token's time from the request's
+    open, set where ``ttft_s`` is: the stream ends after it, so the span
+    outlasts it by the stream's own tail."""
+    engine = ServingEngine(model=lm)
+    try:
+        assert len(_stream(engine, [2, 7, 1, 8], 6)) == 6
+        assert len(_stream(engine, [2, 8], 1)) == 1
+    finally:
+        engine.stop()
+    reqs = sorted(obs_on.spans("serve.request"), key=lambda s: s["t_mono"])
+    assert len(reqs) == 2
+    for req in reqs:
+        a = req["attrs"]
+        assert 0 < a["ttft_s"] <= a["last_token_s"] <= req["duration_s"] + EPS
+    many, one = reqs
+    assert many["attrs"]["ttft_s"] < many["attrs"]["last_token_s"]
+    assert one["attrs"]["ttft_s"] == one["attrs"]["last_token_s"]
+    # the last token came from the request's last tick
+    ticks = [s for s in obs_on.spans("serve.batch")
+             if s["attrs"]["kind"] == "decode.paged"
+             and _inside(s, many)]
+    assert many["t_mono"] + many["attrs"]["last_token_s"] >= \
+        max(_end(t) for t in ticks) - EPS

@@ -501,6 +501,39 @@ def test_the_spans_count_expert_rows_and_positions_over_groups(strict):
         assert a["kv_read"] == reads // 8
 
 
+def test_tracing_on_and_off_serve_the_same_tokens(strict):
+    """The tick's expert count is read inside ``serve.tick.wait``, after
+    its tokens, and only with tracing on: the tokens, greedy and sampled,
+    are the same bytes either way."""
+    _conf, _cfg, lm = strict
+    prompts = [_tokens(n, seed=n) for n in (5, 19, 30)]
+
+    def run():
+        dec = paged.PagedDecoder(lm, block_tokens=BT, n_blocks=64, lanes=4)
+        try:
+            futs = [dec.submit(p, 5, temperature=t, seed=7)
+                    for p, t in zip(prompts, (0.0, 0.9, 0.0))]
+            return [np.asarray(f.result(timeout=240), np.int32).tobytes()
+                    for f in futs]
+        finally:
+            dec.stop()
+
+    obs_trace.set_enabled(False)
+    try:
+        obs_trace.tracer().clear()
+        off = run()
+        assert obs_trace.tracer().spans() == []
+        obs_trace.set_enabled(True)
+        on = run()
+        ticks = [s for s in obs_trace.tracer().spans("serve.batch")
+                 if s["attrs"].get("kind") == "decode.paged"]
+    finally:
+        obs_trace.set_enabled(None)
+        obs_trace.tracer().clear()
+    assert on == off
+    assert ticks and all("moe_experts_hit" in t["attrs"] for t in ticks)
+
+
 def test_the_engine_reports_and_prices_each_groups_pool(strict):
     """Through `ServingEngine(model=...)`: `/models`' KV report carries the
     groups, and the HBM report prices each group's pool at its own blocks
