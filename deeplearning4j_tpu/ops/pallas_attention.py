@@ -22,6 +22,13 @@ Scope & fallback policy (mirrors ops/pallas_kernels.py):
     log-sum-exp, and the custom_vjp recomputes probabilities K-block by
     K-block (lax.scan), so neither pass ever materializes the [T, T]
     score matrix;
+  - the forward multiplies q, k, v in their own dtype and sums both
+    products in float32 (bf16 inputs: bf16 products on the MXU, the
+    probabilities rounded to bf16 before the value product, as the dense
+    path does; float32 inputs: float32 products); its tiles are the largest
+    of 512 / 256 / 128 that divides T (_fwd_tiles), and only the key tiles
+    that cross the diagonal build a causal mask. The backward and the
+    extended kernel keep 128-tiles and float32 products;
   - causal and full attention; key padding masks run through the EXTENDED
     kernel (_flash_ext: additive key bias + traced visibility offset),
     which also powers the ring's local block product
@@ -46,14 +53,27 @@ from deeplearning4j_tpu.ops.pallas_kernels import pallas_enabled
 
 _BLOCK_Q = 128
 _BLOCK_K = 128
+# the forward kernel's tile sizes, tried largest first: the largest that
+# divides T is taken, for q and for keys (_fwd_tiles)
+_FWD_TILES = (512, 256, 128)
 # K + V resident per (batch, head): 2 * T * D floats; budget well under the
 # ~16MB/core VMEM, leaving room for the double-buffered q/o blocks + scratch.
 _KV_BUDGET_FLOATS = 1_500_000
+# a masked score: finite, so exp(masked - max) is 0 and no exp(-inf - -inf)
+# can arise (-0.7 x float32 max, as in JAX's own TPU flash kernel)
+_MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 
 
 def flash_fits(t: int, d: int) -> bool:
     return (t % _BLOCK_Q == 0 and t % _BLOCK_K == 0
             and 2 * t * d <= _KV_BUDGET_FLOATS)
+
+
+def _fwd_tiles(t: int):
+    """(block_q, block_k) of the forward kernel for T positions (T a
+    multiple of 128): the largest tile of _FWD_TILES that divides T."""
+    b = next(b for b in _FWD_TILES if t % b == 0)
+    return b, b
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
@@ -62,51 +82,63 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
     q_ref/o_ref: [1, Bq, D]; k_ref/v_ref: [1, T, D]; lse_ref: [1, 8, Bq]
     (log-sum-exp of each row's scores, broadcast over an 8-sublane padding
     dim for Mosaic block alignment — the residual the blocked backward
-    needs to recompute softmax probabilities without the running max)."""
-    q = q_ref[0].astype(jnp.float32) * scale          # [Bq, D]
+    needs to recompute softmax probabilities without the running max).
+
+    Both products take their operands in the input dtype and sum in
+    float32; the scale, the running max, the denominator, the exponentials
+    and the accumulator are float32, and the probabilities are rounded to
+    v's dtype before the value product. Causal: the key tiles wholly at or
+    below the diagonal run without a mask; only the tiles that cross it
+    build one. Every row sees key 0 in the first tile, so the running max
+    is finite from the first tile on."""
+    q = q_ref[0]                                      # [Bq, D]
     bq, d = q.shape
     t = k_ref.shape[1]
-    qi = pl.program_id(1) * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    start = pl.program_id(1) * bq
 
     m0 = jnp.full((bq,), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
     a0 = jnp.zeros((bq, d), jnp.float32)
 
-    def body(j, carry):
+    def body(j, carry, masked):
         m, l, acc = carry
-        k = k_ref[0, pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [Bq, Bk]
-        if causal:
-            ki = j * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-            s = jnp.where(qi >= ki, s, -jnp.inf)
-        blk_max = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m, blk_max)
-        # a fully-masked block leaves m_new at -inf on no row in the causal
-        # case (the diagonal is always visible); guard anyway for the loop
-        # iterations before any visible key
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - m_safe[:, None])
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
-        corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
+        first = pl.multiple_of(j * block_k, block_k)
+        k = k_ref[0, pl.dslice(first, block_k), :]
+        v = v_ref[0, pl.dslice(first, block_k), :]
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        if masked:
+            qi = start + lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
+            ki = first + lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
+            s = jnp.where(qi >= ki, s, _MASK_VALUE)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[:, None])
+        corr = jnp.exp(m - m_new)
         l = l * corr + p.sum(-1)
-        acc = acc * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
+        acc = acc * corr[:, None] + lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         return m_new, l, acc
 
+    carry = (m0, l0, a0)
     if causal:
-        # keys strictly after this q block's last row never contribute
-        n_blocks = (pl.program_id(1) * bq + bq + block_k - 1) // block_k
+        # tiles whose last key is at or before this q block's first row see
+        # no mask; the rest up to the block's last row cross the diagonal
+        n_below = (start + 1) // block_k
+        n_blocks = (start + bq + block_k - 1) // block_k
+        carry = lax.fori_loop(0, n_below,
+                              functools.partial(body, masked=False), carry)
+        carry = lax.fori_loop(n_below, n_blocks,
+                              functools.partial(body, masked=True), carry)
     else:
-        n_blocks = t // block_k
-    m, l, acc = lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
-    l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
+        carry = lax.fori_loop(0, t // block_k,
+                              functools.partial(body, masked=False), carry)
+    m, l, acc = carry
+    o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
     # lse block is [1, 8, Bq]: Mosaic requires the last two block dims be
     # (8, 128)-aligned, so the scalar-per-row lse is broadcast across an
     # 8-sublane dim the caller slices back off
-    lse_ref[0] = jnp.broadcast_to(
-        (m_safe_final(m) + jnp.log(l_safe))[None, :], (8, l.shape[0]))
+    lse_ref[0] = jnp.broadcast_to((m + jnp.log(l))[None, :], (8, bq))
 
 
 def m_safe_final(m):
@@ -124,19 +156,19 @@ def _flash_raw(q, k, v, *, causal: bool, interpret: bool):
             f"flash attention needs T divisible by {max(_BLOCK_Q, _BLOCK_K)}; "
             f"got T={t} (use attention_auto for automatic dense fallback)")
     scale = 1.0 / (d ** 0.5)
-    grid = (b, t // _BLOCK_Q)
+    bq, bk = _fwd_tiles(t)
     return pl.pallas_call(
         functools.partial(_flash_kernel, causal=causal, scale=scale,
-                          block_k=_BLOCK_K),
-        grid=grid,
+                          block_k=bk),
+        grid=(b, t // bq),
         in_specs=[
-            pl.BlockSpec((1, _BLOCK_Q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, _BLOCK_Q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, 8, _BLOCK_Q), lambda b, i: (b, 0, i)),
+            pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, 8, bq), lambda b, i: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, t, d), q.dtype),

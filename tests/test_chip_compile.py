@@ -103,6 +103,60 @@ def test_flash_forward_compiles_for_v5e_under_its_name(one_chip, rows,
     assert "flash_fwd/pallas_call" in calls[0]
 
 
+def _mosaic_text(lowered_text: str) -> str:
+    """The Mosaic module of the one kernel in a lowered program, as MLIR
+    text: the custom call carries it as base64 bytecode in its
+    backend_config, in dialects this context has not registered."""
+    import base64
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    bodies = re.findall(r"body\\22: \\22([A-Za-z0-9+/=]+)\\22", lowered_text)
+    assert len(bodies) == 1, len(bodies)
+    ctx = jax_mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        return str(ir.Module.parse(base64.b64decode(bodies[0])))
+
+
+# the 590M serve cell's batch-1 admission: 1 x 12 heads at its prompt widths
+@pytest.mark.parametrize("width", [256, 512, 1024])
+def test_flash_forward_multiplies_bf16_into_f32_on_v5e(one_chip, width,
+                                                       no_compile_cache):
+    """The forward compiles for the chip at the admission's widths under
+    its name, and both of its matrix products take bf16 operands (q and k;
+    the probabilities and v) and sum in float32: the tiles the width picks,
+    never a float32 copy of an operand."""
+    from deeplearning4j_tpu.ops import pallas_attention as pa
+
+    assert pa.flash_fits(width, 128)
+    bq, bk = pa._fwd_tiles(width)
+    x = jax.ShapeDtypeStruct((12, width, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    with jax.enable_x64(False):
+        lowered = jax.jit(
+            lambda q, k, v: pa._flash_raw(q, k, v, causal=True,
+                                          interpret=False)
+        ).lower(x, x, x)
+        text = lowered.compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    assert re.match(r"\s*(ROOT )?%flash_fwd(\.\d+)? = ", calls[0]), calls[0]
+    assert f"bf16[12,{width},128]" in calls[0]
+    products = sorted(
+        m.group(1) for m in (re.search(r"tpu\.matmul.*\} : \((.*)$", ln)
+                             for ln in _mosaic_text(lowered.as_text())
+                             .splitlines()) if m)
+    qk = (f"vector<{bq}x128xbf16>, vector<{bk}x128xbf16>, "
+          f"vector<{bq}x{bk}xf32>) -> vector<{bq}x{bk}xf32>")
+    pv = (f"vector<{bq}x{bk}xbf16>, vector<{bk}x128xbf16>, "
+          f"vector<{bq}x128xf32>) -> vector<{bq}x128xf32>")
+    # the tiles below the diagonal and the ones across it: one pair each
+    assert products == sorted([qk, pv] * 2), products
+
+
 def _compile_serve_program(one_chip, heads, n_layers, program="tick"):
     """The serve cell's tick, or its admission at width 512, compiled for
     the described chip at the cell's shape but for the depth: 40 lanes,
