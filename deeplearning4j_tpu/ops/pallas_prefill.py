@@ -58,6 +58,12 @@ KEY_BLOCKS = (1024, 512, 256, 128)
 QUERY_BLOCKS = (256, 128, 64)
 # the flags of a step (bits)
 FIRST, LAST, MASKED = 1, 2, 4
+# the scratch (rows x head_dim) the default 16 MiB of scoped VMEM holds
+# beside the blocks and the scores: ROWS_MOST rows of 128. A wider head
+# (256: 8 heads a KV head at 256 query rows, 22.7 MiB with keys of 1,024 in
+# the compile for a v5e) asks for more, which the chip's 128 MiB hold
+SCRATCH_MOST = ROWS_MOST * 128
+WIDE_VMEM = 64 << 20
 
 
 def tiles(t: int, group: int) -> Tuple[Optional[int], Optional[int]]:
@@ -203,7 +209,9 @@ def prefill_attention(q, k, v, *, head_dim: int, scale: float,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=None if rows * head_dim <= SCRATCH_MOST
+            else WIDE_VMEM),
         cost_estimate=pl.CostEstimate(
             flops=4 * pairs * head_dim * hk * group,
             transcendentals=pairs * hk * group,

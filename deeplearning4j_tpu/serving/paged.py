@@ -983,6 +983,9 @@ class PagedDecoder:
         # widths: attributes of the tick's span, which waits them out
         self._admits = 0
         self._admit_width_sum = 0
+        # and, for a model that holds a share of its experts, each one's
+        # count of (row, held expert) pairs (device scalars; spans on only)
+        self._admit_pairs: List[jax.Array] = []
         self.peak_active = 0
         # decoder-owned dispatch ledger (TransformerLM carries only
         # memory_stats): decode_ticks / decode_tokens surface the
@@ -1353,16 +1356,18 @@ class PagedDecoder:
                       "kv_read": read // self.needs.kv_layers}
         if self._moe_rows:
             counts["moe_rows"] = len(active) * k * self._moe_rows
-        if self.needs.state:
+        for leaf in self.needs.state:
             # live lanes whose recurrent state the tick advances, and the
-            # least it moves for them: each lane's ``ssm`` leaf read once
-            # and written once a step (the leaf of that name alone: the
-            # conv tail beside it is a hundredth of it, and the reader of
-            # these bytes times the events of the ssm leaf's shape)
-            counts["ssm_lanes"] = len(active) * k
-            counts["ssm_state_bytes"] = 2 * counts["ssm_lanes"] * sum(
-                leaf.lane_bytes for leaf in self.needs.state
-                if leaf.name == "ssm")
+            # least it moves for them: each lane's ``ssm`` (Mamba) or
+            # ``gdn`` (delta rule) leaf read once and written once a step
+            # (the leaf of that name alone: the conv tail beside it is a
+            # hundredth of it, and the reader of these bytes times the
+            # events of that leaf's shape)
+            if leaf.name in ("ssm", "gdn"):
+                lanes = len(active) * k
+                counts[leaf.name + "_lanes"] = lanes
+                counts[leaf.name + "_state_bytes"] = \
+                    2 * lanes * leaf.lane_bytes
         return counts
 
     def _youngest_active(self) -> Optional[int]:
@@ -1552,9 +1557,17 @@ class PagedDecoder:
         # token, which the first tick re-consumes (self._pos[i])
         lane = (jnp.asarray([i, self._pos[i]], jnp.int32),) \
             if self.needs.state or self.needs.windowed else ()
-        self._arena = self._build_admit(width)(
+        out = self._build_admit(width)(
             self._infer_params, self._arena, jnp.asarray(buf),
             jnp.asarray(write_table), *lane)
+        if isinstance(out, tuple):
+            # a model that holds a share of its experts also counts the
+            # prompt's (row, held expert) pairs: read where the next
+            # tick's tokens are, and only while spans are on
+            out, pairs = out
+            if obs_trace.obs_enabled():
+                self._admit_pairs.append(pairs)
+        self._arena = out
 
     # -- prefill/decode disaggregation ------------------------------------
     def export_prefix(self, prompt, n_new: int):
@@ -1996,6 +2009,13 @@ class PagedDecoder:
                         # the tick is done: its count of the experts that
                         # got a live lane's row is there with its tokens
                         sp_tick.set_attr("moe_experts_hit", int(more[0]))
+                    if len(more) > 1 and kv:
+                        # and of the (live lane, held expert) pairs, and
+                        # the pairs of the prefills that ran ahead of it
+                        sp_tick.set_attr("moe_pairs_held", int(more[1]))
+                        sp_tick.set_attr("admit_moe_pairs_held", sum(
+                            int(p) for p in self._admit_pairs))
+                    self._admit_pairs.clear()
         except Exception as e:  # noqa: BLE001 — device boundary
             self._deliver()     # tokens the last tick gave come first
             self._fail_active_lanes(e)

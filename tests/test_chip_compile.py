@@ -493,6 +493,144 @@ def test_smallthinker_admission_attends_in_the_kernel_on_v5e(
     assert temp < plain_temp < 1.4e9, (temp, plain_temp)
 
 
+def _qwen3_next_stage(one_chip, lanes=64, n_blocks=16384, bt=16):
+    """The GDN serve cell's whole stage at its published widths (8 layers,
+    d 2048, 16 GDN key heads and 32 value heads of 128, 16 query heads of
+    256 over 2 KV heads, 128 of 512 experts of 512 held, a shared expert,
+    37,984 vocabulary rows) with its arena (16,384 blocks, 64 lanes of GDN
+    state): (cfg, params, arena, arg) as shapes on the described chip."""
+    from deeplearning4j_tpu.models import hybrid
+    from deeplearning4j_tpu.ops import memory as opsmem
+    from perfbench import harness
+
+    conf = harness.load_json(os.path.join(
+        harness.HERE, "configs", "qwen3-next-80b-a3b-stage8-ep4.json"))
+    cfg = hybrid.HybridConfig.from_published(conf, max_len=16384)
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    params = jax.tree.map(
+        lambda s: arg(s, jnp.bfloat16), hybrid.param_shapes(cfg),
+        is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[0], int))
+    needs = opsmem.cache_needs(cfg)
+    kv = (n_blocks + 1, bt, needs.kv_heads * needs.head_dim)
+    arena = {name: tuple(arg(kv, jnp.bfloat16) for _ in range(2))
+             for name in ("k", "v")}
+    for leaf in needs.state:
+        arena[leaf.name] = tuple(arg((lanes,) + leaf.shape, leaf.dtype)
+                                 for _ in range(leaf.layers))
+    return cfg, params, arena, arg
+
+
+def _tree_bytes(tree):
+    return sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
+               for a in jax.tree.leaves(tree))
+
+
+def test_qwen3_next_tick_fits_and_rewrites_its_state_in_place_on_v5e(
+        one_chip, no_compile_cache):
+    """The GDN cell's tick at 64 lanes compiles for a v5e beside 7.33 GB of
+    weights and its 1.9 GB arena: the arena (KV blocks and every layer's
+    GDN state and conv tail) is donated and aliased whole, nothing at the
+    top level copies a buffer of the state's shape, the held experts take
+    the batched product (no grouped kernel), and its temporaries stay
+    under 0.5 GB."""
+    from deeplearning4j_tpu.serving import paged
+
+    cfg, params, arena, arg = _qwen3_next_stage(one_chip)
+    lanes, bt = 64, 16
+    assert _tree_bytes(params) == 2 * 3_667_251_328
+    with jax.enable_x64(False):
+        tick = paged._paged_tick_for(cfg, bt).lower(
+            params, arena, arg((lanes,), jnp.int32), arg((lanes,), jnp.int32),
+            arg((lanes, cfg.max_len // bt), jnp.int32),
+            arg((lanes, 2), jnp.uint32), arg((lanes,), jnp.float32)).compile()
+    paged._PAGED_TICK_CACHE.pop((cfg, bt, "gather", 1), None)
+    mem = tick.memory_analysis()
+    assert mem.alias_size_in_bytes >= _tree_bytes(arena) > 1.8e9
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10e9
+    text = tick.as_text()
+    entry = text[text.index("\nENTRY "):]
+    assert not re.findall(r" = f32\[64,32,128,128\]\S* (?:copy|transpose)\(",
+                          entry)
+    assert 'custom_call_target="tpu_custom_call"' not in text
+
+
+def test_qwen3_next_admission_at_16384_fits_beside_the_arena_on_v5e(
+        one_chip, no_compile_cache, monkeypatch):
+    """The widest admission (16,384 positions, one prompt) compiles for a
+    v5e: its attention layers call the Pallas kernel at 16 heads over 2 of
+    256, the held experts take the grouped kernel, and the weights, the
+    arena and the program's temporaries together stay under the chip's
+    16.9 GB with a gigabyte to spare."""
+    from deeplearning4j_tpu.serving import paged
+
+    monkeypatch.setenv("DL4J_TPU_PALLAS", "force")   # the chip's default
+    cfg, params, arena, arg = _qwen3_next_stage(one_chip)
+    bt, width = 16, 16384
+    assert cfg.admit_attend(width) == "kernel"
+    with jax.enable_x64(False):
+        admit = paged._paged_admit_for(cfg, width, bt).lower(
+            params, arena, arg((1, width), jnp.int32),
+            arg((cfg.max_len // bt,), jnp.int32),
+            arg((2,), jnp.int32)).compile()
+    paged._PAGED_ADMIT_CACHE.pop((cfg, width, bt), None)
+    mem = admit.memory_analysis()
+    assert mem.alias_size_in_bytes >= _tree_bytes(arena)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.9e9, \
+        mem.temp_size_in_bytes
+    text = admit.as_text()
+    called = [m.group(1) for m in (
+        re.match(r"\s*(?:ROOT )?%(prefill_attn)(?:\.\d+)? = ", ln)
+        for ln in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in ln) if m]
+    assert called, "no attention kernel in the admission"
+    assert "163840,2048]" in text      # 16,384 rows x 10 pairs, grouped
+
+
+def test_qwen3_next_tick_and_admit_hold_the_new_scopes():
+    """A tiny GDN model: the tick's and the admission's lowered text holds
+    the delta rule's and the shared expert's scopes beside the expert
+    layer's and every tick's."""
+    from deeplearning4j_tpu.models import hybrid
+    from deeplearning4j_tpu.serving import paged
+
+    cfg = hybrid.HybridConfig(
+        vocab_size=64, d_model=32, n_heads=4, n_kv_heads=2, attn_head_dim=16,
+        d_ff=8, layer_types=("linear_attention", "attention"), max_len=32,
+        rope=(True,), rope_dims=8, attn_gate=True, qk_norm=True,
+        norm_offset=True, attn_exact=False, ffn="experts", ffn_act="silu",
+        moe_experts=8, moe_top_k=2, moe_held=4, shared_expert=8,
+        router_after_mixer=True, tie_head=False, gdn_k_heads=2,
+        gdn_v_heads=4, gdn_k_dim=8, gdn_v_dim=8, gdn_chunk=8,
+        dtype_policy="strict")
+    bt, lanes, m = 4, 3, 8
+    params = jax.eval_shape(
+        lambda: hybrid.init_params(cfg, jax.random.PRNGKey(0)))
+    layer = jax.ShapeDtypeStruct((17, bt, 32), jnp.float32)
+    arena = {"k": (layer,), "v": (layer,),
+             "gdn": (jax.ShapeDtypeStruct((lanes, 4, 8, 8), jnp.float32),),
+             "gdn_conv": (jax.ShapeDtypeStruct((lanes, 3, 64),
+                                               jnp.float32),)}
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    tick = paged._paged_tick_for(cfg, bt).lower(
+        params, arena, i32(lanes), i32(lanes), i32(lanes, m),
+        jax.ShapeDtypeStruct((lanes, 2), jnp.uint32),
+        jax.ShapeDtypeStruct((lanes,), jnp.float32)).as_text(debug_info=True)
+    for scope in TICK_SCOPES + ("tick.moe_route", "tick.moe_experts",
+                                "tick.gdn_conv", "tick.gdn_step",
+                                "tick.shared_expert"):
+        assert _has_scope(tick, scope), scope
+    admit = paged._paged_admit_for(cfg, 16, bt).lower(
+        params, arena, i32(1, 16), i32(m), i32(2)).as_text(debug_info=True)
+    for scope in ("admit.prefill", "admit.scatter", "admit.attend",
+                  "admit.moe_route", "admit.moe_experts", "admit.gdn_scan",
+                  "admit.shared_expert"):
+        assert _has_scope(admit, scope), scope
+    paged._PAGED_TICK_CACHE.pop((cfg, bt, "gather", 1), None)
+    paged._PAGED_ADMIT_CACHE.pop((cfg, 16, bt), None)
+
+
 # granite's admission at its widest bucket (512: prompts to 448), lowered
 # with Pallas on: its products are float32 (``attn_exact``), so it keeps the
 # plain path, and the text is the parent's of PR 38 to the byte. A later

@@ -154,3 +154,40 @@ def test_the_kernel_engages_by_pallas_and_the_stated_precision(monkeypatch):
     assert not pp.fits(512, 4, 64) and not pp.fits(40, 7, HD)
     assert pp.name() == "prefill_attn" and pp.name(4096) == \
         "prefill_attn_w4096"
+
+
+@pytest.mark.parametrize("t", [768, 2048], ids=["768", "2048"])
+def test_the_kernel_at_heads_of_256_equals_the_plain_path(interpreted, t):
+    """The GDN cell's attention layer (16 query heads of 256 over 2 KV
+    heads, 8 a KV head; q | gate out of W_q, q and k normalised a head,
+    rotary on 64 of 256 dims): the kernel's wide scratch takes the larger
+    VMEM limit, and the layer's output equals the plain path's within one
+    bf16 rounding."""
+    cfg = hybrid.HybridConfig(
+        vocab_size=64, d_model=256, n_heads=16, n_kv_heads=2,
+        attn_head_dim=256, d_ff=16, layer_types=("attention",),
+        max_len=16384, rope=(True,), rope_dims=64, rope_theta=1e7,
+        attn_exact=False, attn_gate=True, qk_norm=True, norm_offset=True,
+        ffn="experts", ffn_act="silu", moe_experts=4, moe_top_k=2,
+        tie_head=False, attention_multiplier=256 ** -0.5)
+    assert cfg.admit_attend(t) == "kernel"
+    bq, bk = pp.tiles(t, 8)
+    assert 8 * bq * 256 > pp.SCRATCH_MOST
+    ks = jax.random.split(jax.random.PRNGKey(t), 6)
+    w = lambda k, shape, gain: (jax.random.normal(k, shape) * gain
+                                * shape[0] ** -0.5).astype(jnp.bfloat16)
+    ap = {"Wq": w(ks[0], (256, 2 * 16 * 256), 3.0),
+          "Wk": w(ks[1], (256, 2 * 256), 3.0),
+          "Wv": w(ks[2], (256, 2 * 256), 1.0),
+          "Wo": w(ks[3], (16 * 256, 256), 1.0),
+          "q_norm": (0.1 * jax.random.normal(ks[4], (256,))).astype(
+              jnp.bfloat16),
+          "k_norm": jnp.zeros((256,), jnp.bfloat16)}
+    u = jax.random.normal(ks[5], (t, 256), jnp.float32)
+    got, _gk, _gv = hybrid.attention_full(u, ap, cfg, jnp.bfloat16)
+    with pallas_disabled():
+        want, _wk, _wv = hybrid.attention_full(u, ap, cfg, jnp.bfloat16)
+    got, want = np.asarray(got), np.asarray(want)
+    assert 0.3 < np.abs(want).max() < 10
+    np.testing.assert_allclose(got, want, atol=TOL * np.abs(want).max(),
+                               rtol=0)

@@ -1,7 +1,7 @@
 """The gate sees the benchmark's manifest (ISSUE 37; left over from PR 36):
 `perfbench/check_manifest.check` on the repo's own BENCHMARK.json, every
-per-layer metric's reader file, every cell's files, and what ISSUE 37 added
-(the fifth configuration and its one cell) as its own entries say it.
+per-layer metric's reader file, every cell's files, and the fifth and sixth
+configurations and their one cell each as their own entries say them.
 
 Nothing here runs a cell or touches a device.
 
@@ -25,8 +25,8 @@ def manifest():
 
 def test_the_repos_manifest_is_sound(manifest):
     assert check_manifest.check(manifest) == []
-    assert len(manifest["workloads"]) == 5
-    assert [w["chips"] for w in manifest["workloads"]] == [1] * 5
+    assert len(manifest["workloads"]) == 6
+    assert [w["chips"] for w in manifest["workloads"]] == [1] * 6
 
 
 def test_every_per_layer_metric_has_a_reader(manifest):
@@ -52,14 +52,14 @@ def test_every_cell_has_its_files_and_its_job(manifest):
 
 
 def test_the_routed_expert_cell_is_entered_as_the_issue_names_it(manifest):
-    config = manifest["configs"][-1]
-    assert config["name"] == CONFIG and config["reduced"] == ["n_layer"]
+    config = [c for c in manifest["configs"] if c["name"] == CONFIG][0]
+    assert config["reduced"] == ["n_layer"]
     assert config["source"] == ("https://huggingface.co/PowerInfer/"
                                 "SmallThinker-21BA3B-Instruct/blob/main/"
                                 "config.json")
-    cell = manifest["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
-        == (CELL, CONFIG, "chat-mixed-8k", 1)
+    cell = [w for w in manifest["workloads"] if w["name"] == CELL][0]
+    assert (cell["config"], cell["traffic"], cell["chips"]) \
+        == (CONFIG, "chat-mixed-8k", 1)
     e2e = {m["name"] for m in harness.cell_metrics(manifest, CELL,
                                                    "end_to_end")}
     # `ttft_p90_ms` is not listed: its six runs spread over half its bound
@@ -124,3 +124,76 @@ def test_the_configuration_holds_every_published_key_and_the_cut():
     assert mix["output_tokens"] == {"distribution": "lognormal",
                                     "median": 192, "sigma": 0.7,
                                     "min": 64, "max": 512}
+
+
+GDN_CELL = "serve-qwen3-next-stage8-longdoc"
+GDN_CONFIG = "qwen3-next-80b-a3b-stage8-ep4"
+
+
+def test_the_gdn_cell_is_entered_as_its_configuration_states_it(manifest):
+    config, cell = manifest["configs"][-1], manifest["workloads"][-1]
+    assert config["name"] == GDN_CONFIG
+    assert config["reduced"] == ["n_layer", "num_experts", "vocab_size"]
+    assert config["source"] == ("https://huggingface.co/Qwen/"
+                                "Qwen3-Next-80B-A3B-Instruct/blob/main/"
+                                "config.json")
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
+        == (GDN_CELL, GDN_CONFIG, "longdoc-16k", 1)
+    e2e = {m["name"] for m in harness.cell_metrics(manifest, GDN_CELL,
+                                                   "end_to_end")}
+    assert {"serve_tokens_per_s", "setup_s"} <= e2e
+    mine = {m["name"] for m in harness.cell_metrics(
+        manifest, GDN_CELL, "per_layer", sorted(e2e))}
+    only_here = {m["name"] for m in manifest["per_layer"]
+                 if m.get("workloads") == [GDN_CELL]}
+    assert only_here == {"step_mfu.serve_gdn", "gdn_step_roofline.serve_gdn",
+                         "gdn_step_ms_per_tick.serve_gdn",
+                         "moe_experts_roofline.serve_gdn"}
+    # and the serve cells' span and idle readers, as in the routed-expert
+    # cell
+    routed = {m["name"] for m in harness.cell_metrics(
+        manifest, CELL, "per_layer", ["serve_tokens_per_s", "setup_s"])}
+    shared = {m for m in routed if m.endswith(".serve")}
+    assert shared <= mine and not mine & {m for m in routed
+                                          if m.endswith(".serve_moe")}
+
+
+def test_the_gdn_configuration_holds_every_published_key_and_the_cut():
+    """Every number of the catalog row's config under its own key, as
+    published, but the three keys the cut changes, which `reduced` names
+    and `published` states."""
+    conf = harness.load_cell(GDN_CELL)["conf"]
+    published = {
+        "decoder_sparse_step": 1, "full_attention_interval": 4,
+        "head_dim": 256, "hidden_act": "silu", "hidden_size": 2048,
+        "intermediate_size": 5120, "linear_conv_kernel_dim": 4,
+        "linear_key_head_dim": 128, "linear_num_key_heads": 16,
+        "linear_num_value_heads": 32, "linear_value_head_dim": 128,
+        "max_position_embeddings": 262144, "mlp_only_layers": [],
+        "model_type": "qwen3_next", "moe_intermediate_size": 512,
+        "norm_topk_prob": True, "num_attention_heads": 16,
+        "num_experts_per_tok": 10, "num_hidden_layers": 48,
+        "num_key_value_heads": 2, "partial_rotary_factor": 0.25,
+        "rms_norm_eps": 1e-06, "rope_scaling": None, "rope_theta": 10000000,
+        "shared_expert_intermediate_size": 512, "tie_word_embeddings": False,
+        "use_sliding_window": False}
+    assert {k: conf[k] for k in published} == published
+    assert (conf["n_layer"], conf["num_experts"], conf["vocab_size"]) \
+        == (8, 128, 37_984)
+    assert conf["published"] == {"num_hidden_layers": 48,
+                                 "num_experts": 512, "vocab_size": 151_936}
+    assert (conf["router_num_experts"], conf["expert_first"]) == (512, 0)
+    assert conf["reduced"] == ["n_layer", "num_experts", "vocab_size"]
+    # by hand: 6 GDN and 2 attention layers, a quarter of the vocabulary
+    gdn = 2048 * 12288 + 2048 * 64 + 4 * 8192 + 32 + 32 + 128 + 4096 * 2048
+    attn = 2048 * 8192 + 2 * 2048 * 512 + 4096 * 2048 + 2 * 256
+    ffn = 2048 * 512 + 3 * 2048 * 512 + 2048 + 2 * 2048
+    experts = 128 * 3 * 2048 * 512
+    total = 6 * (gdn + ffn + experts) + 2 * (attn + ffn + experts) \
+        + 2 * 37_984 * 2048 + 2048
+    assert total == 3_667_251_328 == conf["parameters"]["total"]
+    mix = harness.load_cell(GDN_CELL)["mix"]
+    assert (mix["clients"], mix["system_tokens"], mix["greedy_share"]) \
+        == (32, 0, 0.5)
+    assert (mix["user_tokens"]["median"], mix["user_tokens"]["max"],
+            mix["output_tokens"]["max"]) == (4096, 15360, 1024)

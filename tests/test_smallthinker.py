@@ -225,9 +225,16 @@ def test_from_published_reads_the_family_and_refuses_the_rest():
         with pytest.raises(ValueError, match=what):
             hybrid.HybridConfig.from_published(dict(CONF, **over),
                                                max_len=MAX_LEN)
-    with pytest.raises(ValueError, match="beside Mamba"):
-        hybrid.HybridConfig(layer_types=("mamba", "attention"),
-                            ffn="experts", moe_experts=4, moe_top_k=2)
+    # experts beside Mamba layers are computed since the admission unrolls
+    # such a model's layers (tests/test_qwen3_next.py); a share of experts
+    # past the router's count is refused
+    assert hybrid.HybridConfig(layer_types=("mamba", "attention"),
+                               ffn="experts", moe_experts=4,
+                               moe_top_k=2).moe_count == 4
+    with pytest.raises(ValueError, match="held"):
+        hybrid.HybridConfig(layer_types=("attention",), ffn="experts",
+                            moe_experts=4, moe_top_k=2, moe_first=2,
+                            moe_held=3)
     with pytest.raises(ValueError, match="one entry an attention layer"):
         hybrid.HybridConfig(layer_types=("attention",) * 2, window=(4,))
 
