@@ -78,8 +78,9 @@ the admission attends a whole prompt by blocks of query rows over chunks of
 keys (``attention_full``: no ``[H, T, T]`` scores at 8,192 positions, a
 window layer reads its band; with bf16 products and Pallas on, one kernel
 that writes no score at all, ops/pallas_prefill.py), the tick one query a
-lane over the lane's blocks (serving/paged.chunked_attention, with a lower
-bound a lane in a window layer). And two of the expert layer, by the rows: every expert on
+lane over the lane's own blocks (ops/pallas_paged.decode_attention: a Pallas
+kernel on the chip, serving/paged.chunked_attention elsewhere; a lower bound a
+lane in a window layer). And two of the expert layer, by the rows: every expert on
 every lane in one batched product in the tick, rows sorted by expert through
 grouped products in the admission.
 
@@ -1328,7 +1329,7 @@ def paged_decode_step(params, arena, tok, pos, tables, cfg: HybridConfig):
     1, bt, Hkv * hd]`` (block 0 trash) and, a buffer a Mamba layer, ``ssm``
     ``[S, H, P, N]`` and ``conv`` ``[S, K-1, C]`` indexed by lane. Attention layers scatter
     the token's K and V into (tables[s, pos // bt], pos % bt) and read the
-    lane's blocks through serving/paged.chunked_attention, grouped; Mamba
+    lane's blocks through ops/pallas_paged.decode_attention, grouped; Mamba
     layers advance EVERY lane's state one step (a dead lane's is never
     read: admission overwrites it whole). The layers are unrolled: each
     state buffer is read once and rewritten in place.
@@ -1343,7 +1344,7 @@ def paged_decode_step(params, arena, tok, pos, tables, cfg: HybridConfig):
     how many (layer, live lane, held expert) triples the routing made:
     (arena, logits, hit, pairs). GDN layers advance every lane's ``gdn``
     and ``gdn_conv`` as Mamba layers do theirs."""
-    from deeplearning4j_tpu.serving.paged import chunked_attention
+    from deeplearning4j_tpu.ops.pallas_paged import decode_attention
 
     s = tok.shape[0]
     bt = arena["k"][0].shape[1]
@@ -1389,7 +1390,7 @@ def paged_decode_step(params, arena, tok, pos, tables, cfg: HybridConfig):
                     k1.reshape(s, -1).astype(ak[j].dtype))
                 av[j] = av[j].at[wbs[gi], off].set(
                     v1.reshape(s, -1).astype(av[j].dtype))
-            att = chunked_attention(
+            att = decode_attention(
                 q, ak[j], av[j], by_group[gi], pos,
                 scale=cfg.attention_multiplier,
                 lo=jnp.maximum(pos - (window - 1), 0) if window else None)

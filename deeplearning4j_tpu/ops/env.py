@@ -108,12 +108,6 @@ _register("DL4J_TPU_PALLAS", "", "enum",
 _register("DL4J_TPU_PALLAS_FORCE", "", "flag",
           "1 bypasses the PALLAS_BENCH.json measured-win gate (bench legs "
           "measuring the kernel itself)")
-_register("DL4J_TPU_PALLAS_PAGED", "", "enum",
-          "paged-decode attention kernel gate (ops/pallas_paged.py): '' "
-          "auto (TPU + fit + measured-win 'paged' group), 0 off, force on "
-          "wherever the VMEM budget fits (compiled; tests substitute the "
-          "interpreted kernel themselves)",
-          choices=("", "0", "false", "False", "force"))
 _register("DL4J_TPU_PALLAS_SGNS", "", "enum",
           "fused SGNS gather-dot-scatter kernel gate (ops/pallas_sgns.py): "
           "'' auto (TPU + fit + measured-win 'sgns' group), 0 off, force "

@@ -52,11 +52,11 @@ Sharding scheme — chosen for the BYTE-identity bar, not peak FLOPs:
 
 Gates (the ``_reject_lowprec`` discipline — loud, never a silent dense
 fallback): ``DL4J_TPU_SERVE_KV_DTYPE=bf16`` and ``DL4J_TPU_SERVE_SPEC``
-both raise at decoder build; ``n_heads % devices != 0`` raises; the
-pallas paged-attention kernel is never used under shard_map (its
-PALLAS_BENCH verdicts were measured dense), the sharded tick always
-takes the chunked gather loop (paged.chunked_attention, the single
-device's own function over the local heads).
+both raise at decoder build; ``n_heads % devices != 0`` raises. The
+sharded tick attends through the single device's own entry
+(ops/pallas_paged.decode_attention over the local heads: the kernel on a
+chip, in the chunks of the whole row, and the chunked gather loop
+elsewhere).
 
 Prefill/decode disaggregation rides the PagedDecoder half of this PR:
 ``export_prefix``/``import_prefix`` (serving/paged.py) hand
@@ -83,12 +83,12 @@ from deeplearning4j_tpu.ops import dispatch
 from deeplearning4j_tpu.ops import env as envknob
 from deeplearning4j_tpu.ops import memory as opsmem
 from deeplearning4j_tpu.ops import lowprec
+from deeplearning4j_tpu.ops.pallas_paged import decode_attention
 from deeplearning4j_tpu.parallel.mesh import MODEL_AXIS, device_mesh
 from deeplearning4j_tpu.parallel.tensor_parallel import local_head_columns
 from deeplearning4j_tpu.serving.decode import _sample_step
 from deeplearning4j_tpu.serving.paged import (
     PagedDecoder,
-    chunked_attention,
     refuse_window,
 )
 
@@ -153,11 +153,13 @@ def mesh_paged_decode_step(params, arena, tok, pos, tables,
         ck = ck.at[base + wb, off].set(k1.astype(ck.dtype))
         cv = cv.at[base + wb, off].set(v1.astype(cv.dtype))
         # per-head attention over the LOCAL arena shard — the single
-        # device's own function (paged.chunked_attention), just over H/d
-        # heads: per-head math is device-independent (the einsums
-        # contract hd/T only and the running softmax is per head), and
-        # pos is replicated, so every device loops to the same bound
-        att_l = chunked_attention(q, ck, cv, tables + base, pos)
+        # device's own function (pallas_paged.decode_attention), just over
+        # H/d heads: per-head math is device-independent (the products
+        # contract hd/T only and the running softmax is per head), pos is
+        # replicated, and the kernel's chunk follows the whole row's bytes
+        # (shards), so every device folds the same chunks one device would
+        att_l = decode_attention(q, ck, cv, tables + base, pos,
+                                 shards=n_devices)
         # reassemble the full [S, H, hd] head outputs by CONCATENATION
         # (axis-index order == head order) — not a psum: Megatron's
         # row-parallel Wo would reorder the contraction's float sum and

@@ -29,11 +29,14 @@ Layout and invariants:
     K/V). The tick carries the arena through its layer scan as
     ``[L * (n_blocks+1), block_tokens, H*hd]`` and layer ``l`` reaches
     its blocks at rows ``l * (n_blocks+1) + block``.
-  * the tick reads each lane's blocks through its table a chunk of
-    whole blocks at a time (``arena[tables[:, chunk]]``, in the arena's
-    dtype) and folds each chunk into an online softmax, looping only as
-    far as the longest LIVE lane reaches (chunked_attention; the trip
-    count is data, so there is one program). No ``[S, max_len, H, hd]``
+  * the tick reads each lane's blocks through its table
+    (ops/pallas_paged.decode_attention). On a chip with a bf16 arena a
+    Pallas kernel copies each lane's own live blocks into VMEM and nothing
+    else. Elsewhere (chunked_attention) it gathers a chunk of whole blocks
+    at a time (``arena[tables[:, chunk]]``, in the arena's dtype) and folds
+    each chunk into an online softmax, looping only as far as the longest
+    LIVE lane reaches. Either way the trip count is data, so there is one
+    program. No ``[S, max_len, H, hd]``
     view exists. The heads' two products read the gathered chunk as it
     lies, ``[S, chunk, H*hd]``: a head is a column range of the row, and
     its product takes that range where it is. A product batched over
@@ -162,28 +165,15 @@ from deeplearning4j_tpu.serving.slo import SLOClass, default_classes
 from deeplearning4j_tpu.serving.telemetry import ServingStats
 
 
-def attention_path(cfg: TransformerConfig, block_tokens: int) -> str:
-    """Which attention path the paged tick traces for this config:
-    ``kernel`` = the pallas paged-decode kernel (ops/pallas_paged.py,
-    behind DL4J_TPU_PALLAS_PAGED + the measured-win gate), ``gather`` =
-    the chunked ``ck[tables[:, chunk]]`` loop of chunked_attention.
-    Resolved at trace time; the tick cache keys on it, and the
-    serving_decode bench stamps it."""
-    if hasattr(cfg, "paged_decode_step"):
-        # a model that brings its own tick body (models/hybrid.py) reads
-        # the arena through chunked_attention; the kernel has no grouped
-        # heads
-        return "gather"
-    hd = cfg.d_model // cfg.n_heads
-    if jnp.dtype(lowprec.kv_dtype(cfg)) != jnp.dtype(cfg.compute_dtype):
-        # a down-cast KV arena (DL4J_TPU_SERVE_KV_DTYPE=bf16 on an f32
-        # model) takes the gather path, which reads the blocks as
-        # stored against the query's exact rows; the pallas kernel's
-        # bench verdicts were measured at the compute dtype
-        return "gather"
-    if pallas_paged.paged_kernel_enabled(cfg.n_heads, hd, block_tokens):
-        return "kernel"
-    return "gather"
+def attention_path(cfg, block_tokens: int) -> str:
+    """Which attention the tick's programs run for this config: ``kernel``
+    (ops/pallas_paged.decode_attention's kernel, each lane's own live
+    blocks) or ``gather`` (chunked_attention, every lane to the longest).
+    The program chooses at trace time from the arena it is handed; this is
+    the host's view of the same choice, from the same shapes."""
+    return "kernel" if pallas_paged.fits(
+        lowprec.kv_dtype(cfg), opsmem.cache_needs(cfg).head_dim,
+        block_tokens) else "gather"
 
 
 # table columns one pass of the tick's attention gathers: whole blocks,
@@ -374,16 +364,28 @@ def kv_read_tokens_window(pos: np.ndarray, window: int, block_tokens: int,
     return int((pos // chunk - lo // chunk).max() + 1) * chunk
 
 
+def kv_read_tokens_own(pos: np.ndarray, window: int,
+                       block_tokens: int) -> int:
+    """Tokens the kernel (ops/pallas_paged) reads for lanes at ``pos``,
+    all of them, dead ones too: each lane its own blocks, from the one that
+    holds its first visible position (``pos - window + 1`` in a window
+    layer, 0 without one: ``window`` 0) to the one that holds ``pos``."""
+    lo = np.maximum(pos - (window - 1), 0) if window else np.zeros_like(pos)
+    return int((pos // block_tokens - lo // block_tokens + 1).sum()) \
+        * block_tokens
+
+
 def paged_decode_step(params, arena, tok, pos, tables,
-                      cfg: TransformerConfig, attention: Optional[str] = None):
+                      cfg: TransformerConfig):
     """One decode tick over the block arena: tok [S] int32, pos [S]
     int32, tables [S, max_len//bt] int32 -> (updated arena, logits
     [S, V]).
 
     The paged variant of serving/decode.decode_step_slots: the per-slot
-    cache stripe becomes the lane's blocks, read through its table a
-    chunk at a time and only as far as the longest lane reaches
-    (chunked_attention), and the one-hot cache write becomes a scatter
+    cache stripe becomes the lane's blocks, read through its table
+    (ops/pallas_paged.decode_attention: each lane's own live blocks in
+    the kernel, or chunk by chunk as far as the longest lane reaches in
+    chunked_attention), and the one-hot cache write becomes a scatter
     into (block, offset) = (tables[s, pos//bt], pos % bt). Active lanes
     write distinct blocks by allocation invariant; inactive lanes all
     scatter into trash block 0, whose content is never visible under
@@ -394,20 +396,11 @@ def paged_decode_step(params, arena, tok, pos, tables,
     ``[L * (n_blocks+1), bt, H*hd]`` (a bitcast), and layer ``l`` writes
     and reads at rows ``l * (n_blocks+1) + block``, so the program
     updates the donated buffers where they lie and nothing of the
-    arena's or of a layer's size is sliced, copied or re-laid.
-
-    ``attention`` picks the per-layer attention body ('kernel' streams
-    blocks through the pallas online-softmax kernel; 'gather' is the
-    chunked XLA loop; None resolves via attention_path at trace time).
-    Neither materializes the gathered window, and both honor the same
-    ``t <= pos`` visibility mask, so outputs agree to f32 rounding
-    (tests/test_pallas_paged.py pins 1e-6)."""
+    arena's or of a layer's size is sliced, copied or re-laid."""
     cdt = cfg.compute_dtype
     s = tok.shape[0]
     hd = cfg.d_model // cfg.n_heads
     n_layers, rows, bt, width = arena["k"].shape
-    if attention is None:
-        attention = attention_path(cfg, bt)
     h = (params["embed"][tok] + params["pos"][pos])[:, None, :].astype(cdt)
     wb = jnp.take_along_axis(tables, (pos // bt)[:, None], axis=1)[:, 0]
     off = pos % bt
@@ -426,13 +419,7 @@ def paged_decode_step(params, arena, tok, pos, tables,
         with jax.named_scope("tick.scatter"):
             ck = ck.at[base + wb, off].set(k1.astype(ck.dtype))
             cv = cv.at[base + wb, off].set(v1.astype(cv.dtype))
-        if attention == "kernel":
-            with jax.named_scope("tick.attend"):
-                heads = lambda a: a.reshape(a.shape[:2] + (cfg.n_heads, hd))
-                att = pallas_paged.paged_attention(
-                    q, heads(ck), heads(cv), tables + base, pos)
-        else:
-            att = chunked_attention(q, ck, cv, tables + base, pos)
+        att = pallas_paged.decode_attention(q, ck, cv, tables + base, pos)
         att = att.reshape(s, 1, cfg.d_model)
         h = h + att.astype(cdt) @ c(bp["Wo"])
         x = _ln(h, c(bp["ln2_g"]), c(bp["ln2_b"]))
@@ -450,7 +437,7 @@ def paged_decode_step(params, arena, tok, pos, tables,
             "v": cv.reshape(arena["v"].shape)}, h @ params["embed"].T
 
 
-def decode_body(cfg, attention: Optional[str] = None):
+def decode_body(cfg):
     """The tick's body for a model: ``step(params, arena, tok, pos,
     tables) -> (arena, logits)``. A config that brings its own
     (``cfg.paged_decode_step``, models/hybrid.py: recurrent layers beside
@@ -460,7 +447,7 @@ def decode_body(cfg, attention: Optional[str] = None):
     if own is not None:
         return own
     return lambda params, arena, tok, pos, tables: paged_decode_step(
-        params, arena, tok, pos, tables, cfg, attention=attention)
+        params, arena, tok, pos, tables, cfg)
 
 
 def serving_view(params, cfg):
@@ -497,18 +484,16 @@ _PAGED_ADMIT_CACHE: Dict[tuple, object] = {}
 
 
 def _paged_tick_for(cfg: TransformerConfig, block_tokens: int, k: int = 1):
-    # the attention path is resolved HERE, not inside the trace: a knob
-    # flip after the first tick must rebuild the jitted program, so the
-    # resolved path rides the cache key. k (tokens
-    # per tick, ISSUE 16) rides it the same way: the adaptive worker only
-    # ever asks for k=1 and k=tick_k, so at most two programs per path.
-    path = attention_path(cfg, block_tokens)
-    key = (cfg, block_tokens, path, int(k))
+    # k (tokens per tick) rides the cache key: the adaptive worker only
+    # ever asks for k=1 and k=tick_k, so at most two programs. The
+    # attention path is the program's own choice from the arena's shapes,
+    # one program whatever the lanes' lengths
+    key = (cfg, block_tokens, int(k))
     fn = _PAGED_TICK_CACHE.get(key)
     if fn is not None:
         return fn
 
-    step_body = decode_body(cfg, path)
+    step_body = decode_body(cfg)
 
     if k == 1:
         def tick(params, arena, tok, pos, tables, keys, temps):
@@ -921,6 +906,8 @@ class PagedDecoder:
         # arena dtype (DL4J_TPU_SERVE_KV_DTYPE): bf16 halves block bytes,
         # so the auto-sized arena admits ~2x tokens on the same budget
         self.kv_dtype = jnp.dtype(lowprec.kv_dtype(cfg))
+        # which attention the tick runs, as its span says (attention_path)
+        self.attend = attention_path(cfg, bt)
         if n_blocks is None:
             # per-device accounting: the mesh subclass head-shards the
             # arena, so each device prices only H/d heads per block
@@ -1325,35 +1312,42 @@ class PagedDecoder:
         self._slots[i] = None
         self.stats.set_kv_blocks(self._blocks.in_use, self.n_blocks)
 
-    def _kv_counts(self, active: List[int], k: int) -> Dict[str, int]:
+    def _kv_counts(self, active: List[int], k: int) -> Dict[str, object]:
         """What the next k-step tick's attention reads, for its span:
+        ``attend`` which attention the tick runs (attention_path),
         ``kv_live`` the positions the active lanes can see, ``kv_read``
-        the positions the program loops over for every lane, dead ones
-        too (the bound follows the longest lane, step by step)."""
+        the positions the program reads for every lane, dead ones too:
+        the kernel each lane's own blocks, the gather every lane as far as
+        the longest one (step by step)."""
         pos = self._pos[active].astype(np.int64)
-        top = int(self._pos.max())
+        every = self._pos.astype(np.int64)
+        own = self.attend == "kernel"
+        reads = (lambda at: kv_read_tokens_own(at, 0, self.block_tokens)) \
+            if own else (lambda at: self.lanes * kv_read_tokens(
+                int(at.max()), self.block_tokens, self.table_width))
         counts = {
             "kv_live": int(sum((pos + 1 + j).sum() for j in range(k))),
-            "kv_read": self.lanes * sum(
-                kv_read_tokens(top + j, self.block_tokens, self.table_width)
-                for j in range(k))}
+            "kv_read": sum(reads(every + j) for j in range(k))}
         if self.needs.windowed:
             # over the groups, weighted by their layers and divided by the
             # KV layers: a window layer's lane sees min(pos + 1, window)
-            # positions and loops over its own span of chunks (k is 1)
-            every = self._pos.astype(np.int64)
+            # positions and reads from its own first chunk or block (k is 1)
             live = read = 0
             for g in self.needs.groups:
                 if g.window:
                     live += g.layers * int(
                         np.minimum(pos + 1, g.window).sum())
-                    read += g.layers * self.lanes * kv_read_tokens_window(
-                        every, g.window, self.block_tokens, self.table_width)
+                    read += g.layers * (kv_read_tokens_own(
+                        every, g.window, self.block_tokens) if own
+                        else self.lanes * kv_read_tokens_window(
+                            every, g.window, self.block_tokens,
+                            self.table_width))
                 else:
                     live += g.layers * counts["kv_live"]
                     read += g.layers * counts["kv_read"]
             counts = {"kv_live": live // self.needs.kv_layers,
                       "kv_read": read // self.needs.kv_layers}
+        counts["attend"] = self.attend
         if self._moe_rows:
             counts["moe_rows"] = len(active) * k * self._moe_rows
         for leaf in self.needs.state:

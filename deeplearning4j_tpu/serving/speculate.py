@@ -79,7 +79,6 @@ from deeplearning4j_tpu.ops import memory as opsmem
 from deeplearning4j_tpu.serving import decode
 from deeplearning4j_tpu.serving.paged import (
     PagedDecoder,
-    attention_path,
     paged_decode_step,
     refuse_window,
 )
@@ -97,10 +96,8 @@ def _verify_for(cfg: TransformerConfig, block_tokens: int, k: int):
     scatter/gather/attend the k=1 tick runs, so the emitted argmax at
     step j is byte-equal to what a plain greedy tick would have sampled
     after committing the first j proposals (the acceptance-exactness
-    contract). Keyed like paged._paged_tick_for: the resolved attention
-    path rides the cache key so a knob flip rebuilds the program."""
-    path = attention_path(cfg, block_tokens)
-    key = (cfg, block_tokens, path, int(k))
+    contract). Keyed like paged._paged_tick_for."""
+    key = (cfg, block_tokens, int(k))
     fn = _VERIFY_CACHE.get(key)
     if fn is not None:
         return fn
@@ -109,7 +106,7 @@ def _verify_for(cfg: TransformerConfig, block_tokens: int, k: int):
         def step(carry, tok):
             arena, pos = carry
             arena, logits = paged_decode_step(params, arena, tok, pos,
-                                              tables, cfg, attention=path)
+                                              tables, cfg)
             return (arena, pos + 1), \
                 jnp.argmax(logits, axis=-1).astype(jnp.int32)
 

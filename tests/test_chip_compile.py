@@ -186,7 +186,7 @@ def _compile_serve_program(one_chip, heads, n_layers, program="tick"):
                 arg((lanes,), jnp.int32), arg((lanes, m), jnp.int32),
                 arg((lanes, 2), jnp.uint32), arg((lanes,), jnp.float32)
             ).compile()
-            paged._PAGED_TICK_CACHE.pop((cfg, bt, "gather", 1), None)
+            paged._PAGED_TICK_CACHE.pop((cfg, bt, 1), None)
         else:
             compiled = paged._paged_admit_for(cfg, width, bt).lower(
                 params, arena, arg((1, width), jnp.int32),
@@ -340,7 +340,7 @@ def test_hybrid_tick_rewrites_its_state_in_place_on_v5e(one_chip,
             arg((lanes, cfg.max_len // bt), jnp.int32),
             arg((lanes, 2), jnp.uint32), arg((lanes,), jnp.float32)
         ).compile()
-    paged._PAGED_TICK_CACHE.pop((cfg, bt, "gather", 1), None)
+    paged._PAGED_TICK_CACHE.pop((cfg, bt, 1), None)
     text = compiled.as_text()
     state = f"f32[{lanes},64,64,128]"
     entry = text[text.index("\nENTRY "):]
@@ -429,7 +429,7 @@ def _smallthinker_programs(one_chip, width):
             params, arena, arg((lanes,), jnp.int32), arg((lanes,), jnp.int32),
             arg((2, lanes, cfg.max_len // bt), jnp.int32),
             arg((lanes, 2), jnp.uint32), arg((lanes,), jnp.float32)).compile()
-    paged._PAGED_TICK_CACHE.pop((cfg, bt, "gather", 1), None)
+    paged._PAGED_TICK_CACHE.pop((cfg, bt, 1), None)
     return tick, _smallthinker_admit(one_chip, width), arena
 
 
@@ -544,7 +544,7 @@ def test_qwen3_next_tick_fits_and_rewrites_its_state_in_place_on_v5e(
             params, arena, arg((lanes,), jnp.int32), arg((lanes,), jnp.int32),
             arg((lanes, cfg.max_len // bt), jnp.int32),
             arg((lanes, 2), jnp.uint32), arg((lanes,), jnp.float32)).compile()
-    paged._PAGED_TICK_CACHE.pop((cfg, bt, "gather", 1), None)
+    paged._PAGED_TICK_CACHE.pop((cfg, bt, 1), None)
     mem = tick.memory_analysis()
     assert mem.alias_size_in_bytes >= _tree_bytes(arena) > 1.8e9
     assert mem.temp_size_in_bytes < 0.5e9
@@ -588,6 +588,170 @@ def test_qwen3_next_admission_at_16384_fits_beside_the_arena_on_v5e(
     assert "163840,2048]" in text      # 16,384 rows x 10 pairs, grouped
 
 
+def _serve_tick(one_chip, cell):
+    """A serve cell's tick at its lanes and table width, as shapes on the
+    described chip: (tick, cfg, params, arena, inputs, lanes). The 590M
+    cell whole (18 layers, 40 lanes, 1,280 blocks, tables of 128), the
+    routed-expert cell's period (48 lanes, tables of 512, a global and a
+    window pool), the GDN cell's stage (64 lanes, 16,384 blocks, tables of
+    1,024) and the hybrid cell's layers around one attention layer (64
+    lanes, tables of 128)."""
+    from deeplearning4j_tpu.models import hybrid
+    from deeplearning4j_tpu.models.transformer import (
+        TransformerConfig,
+        init_params,
+    )
+    from deeplearning4j_tpu.ops import memory as opsmem
+    from deeplearning4j_tpu.serving import paged
+
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    bt = 16
+    if cell == "590m":
+        cfg = TransformerConfig(vocab_size=50257, d_model=1536, n_layers=18,
+                                n_heads=12, d_ff=6144, max_len=2048,
+                                dtype_policy="performance")
+        lanes = 40
+        on = lambda a: arg(a.shape, a.dtype)
+        params = jax.tree.map(on, jax.eval_shape(lambda: init_params(cfg)))
+        arena = jax.tree.map(on, _arena(cfg, 1280, bt))
+        tables = (lanes, cfg.max_len // bt)
+    elif cell == "smallthinker":
+        cfg, params, arena, arg = _smallthinker_period(one_chip)
+        lanes = 48
+        tables = (2, lanes, cfg.max_len // bt)
+    elif cell == "qwen3-next":
+        cfg, params, arena, arg = _qwen3_next_stage(one_chip)
+        lanes = 64
+        tables = (lanes, cfg.max_len // bt)
+    else:
+        cfg = hybrid.HybridConfig(
+            vocab_size=4096, d_model=2048, n_heads=32, n_kv_heads=8,
+            d_ff=8192, layer_types=("mamba", "attention", "mamba"),
+            max_len=2048, ssm_heads=64, ssm_head_dim=64, ssm_state=128)
+        lanes = 64
+        params = jax.tree.map(lambda s: arg(s, jnp.bfloat16),
+                              hybrid.param_shapes(cfg),
+                              is_leaf=lambda x: isinstance(x, tuple))
+        needs = opsmem.cache_needs(cfg)
+        kv = arg((513, bt, needs.kv_heads * needs.head_dim), jnp.bfloat16)
+        arena = {"k": (kv,), "v": (kv,)}
+        for leaf in needs.state:
+            arena[leaf.name] = tuple(arg((lanes,) + leaf.shape, leaf.dtype)
+                                     for _ in range(leaf.layers))
+        tables = (lanes, cfg.max_len // bt)
+    inputs = (arg((lanes,), jnp.int32), arg((lanes,), jnp.int32),
+              arg(tables, jnp.int32), arg((lanes, 2), jnp.uint32),
+              arg((lanes,), jnp.float32))
+    return paged._paged_tick_for(cfg, bt), cfg, params, arena, inputs, lanes
+
+
+@pytest.mark.parametrize("cell", ["590m", "smallthinker", "qwen3-next",
+                                  "granite"])
+def test_serve_tick_reads_each_lanes_blocks_in_the_kernel_on_v5e(
+        one_chip, cell, no_compile_cache, monkeypatch):
+    """Each serve cell's tick, its attention chosen as on the chip (a TPU
+    backend): where the heads fill lanes of 128 and the arena is bf16 it
+    compiles for a v5e with the kernel's Mosaic calls under their name,
+    ONE lowered kernel for each distinct arena shape (the unrolled hybrid
+    tick's layers share one jitted call, which traces and lowers once: set-up
+    pays for a kernel once a program), under the default 16 MiB of scoped
+    VMEM (the call asks for no more), and nothing left of the gather: no
+    buffer of K or V rows other than the arena's own, and the arena
+    donated and aliased whole. The hybrid cell's heads of 64 take the
+    gather, and its tick keeps it."""
+    from deeplearning4j_tpu.ops import device
+    from deeplearning4j_tpu.ops import memory as opsmem
+    from deeplearning4j_tpu.serving import paged
+
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    monkeypatch.setattr(paged, "_PAGED_TICK_CACHE", {})
+    tick, cfg, params, arena, inputs, lanes = _serve_tick(one_chip, cell)
+    with jax.enable_x64(False):
+        lowered = tick.lower(params, arena, *inputs)
+        compiled = lowered.compile()
+    low, text = lowered.as_text(), compiled.as_text()
+    mosaic = 'custom_call_target="tpu_custom_call"'
+    calls = [ln for ln in text.splitlines() if mosaic in ln]
+    rows = {a.shape for a in jax.tree.leaves({"k": arena["k"]})}
+    width = next(iter(rows))[-1]
+    if cell == "granite":
+        assert paged.attention_path(cfg, 16) == "gather"
+        assert not calls and "decode_attn" not in text
+        return
+    assert paged.attention_path(cfg, 16) == "kernel"
+    assert calls and all(re.match(r"\s*(?:ROOT )?%decode_attn(?:\.\d+)? = ",
+                                  ln) for ln in calls), calls
+    head_dim = opsmem.cache_needs(cfg).head_dim
+    assert f"f32[{lanes},{cfg.n_heads},{head_dim}]" in calls[0]
+    # one Mosaic kernel in the lowered program for each arena shape
+    bodies = re.findall(r"body\\22: \\22([A-Za-z0-9+/=]+)\\22", low)
+    assert len(bodies) == len(set(bodies)) == len(rows), (len(bodies), rows)
+    assert "vmem_limit" not in low
+    # no rows of K or V but the arena's: the gather's [lanes x 8, 16, W]
+    # chunk is gone
+    made = {int(m.group(1)) for m in re.finditer(
+        rf"bf16\[(\d+),16,{width}\]", text)}
+    assert made <= {int(np.prod(r[:-2])) for r in rows}, made
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= _tree_bytes(arena)
+
+
+
+def test_mesh_tick_takes_the_kernel_in_one_devices_chunks_on_v5e_2x2(
+        topo, no_compile_cache, monkeypatch):
+    """The serving mesh's tick (serving/mesh.py) for the 590M model over
+    the four chips of a described v5e 2x2, its attention chosen as on the
+    chip: it compiles with the kernel's Mosaic calls over each device's 3 of
+    the 12 heads, and folds them in the chunks the single-device tick takes
+    (16 blocks of the whole 48 KB row, not 64 of a device's 12 KB): the
+    same sums in the same order, which is what keeps the sharded tick the
+    single device's bit for bit."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from deeplearning4j_tpu.models.transformer import (
+        TransformerConfig,
+        init_params,
+    )
+    from deeplearning4j_tpu.ops import device, pallas_paged
+    from deeplearning4j_tpu.serving import mesh as smesh
+
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    monkeypatch.setattr(smesh, "_MESH_TICK_CACHE", {})
+    chunks = []
+    call = pallas_paged._decode_call
+    monkeypatch.setattr(pallas_paged, "_decode_call",
+                        lambda *a, **k: chunks.append(k["c"]) or call(*a, **k))
+    cfg = TransformerConfig(vocab_size=50257, d_model=1536, n_layers=18,
+                            n_heads=12, d_ff=6144, max_len=2048,
+                            dtype_policy="performance")
+    mesh = Mesh(np.array(topo.devices), (smesh.MODEL_AXIS,))
+    rep = NamedSharding(mesh, P())
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=rep)
+    params = jax.tree.map(lambda a: arg(a.shape, a.dtype),
+                          jax.eval_shape(lambda: init_params(cfg)))
+    bt, lanes = 16, 40
+    leaf = jax.ShapeDtypeStruct((cfg.n_layers, 1281, bt, cfg.d_model),
+                                jnp.bfloat16,
+                                sharding=NamedSharding(mesh, smesh.ARENA_SPEC))
+    tick = smesh._mesh_tick_for(cfg, bt, mesh)
+    with jax.enable_x64(False):
+        text = tick.lower(
+            params, {"k": leaf, "v": leaf}, arg((lanes,), jnp.int32),
+            arg((lanes,), jnp.int32), arg((lanes, cfg.max_len // bt),
+                                          jnp.int32),
+            arg((lanes, 2), jnp.uint32), arg((lanes,), jnp.float32)
+        ).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and all(re.match(r"\s*(?:ROOT )?%decode_attn(?:\.\d+)? = ",
+                                  ln) for ln in calls), calls
+    assert f"f32[{lanes},3,128]" in calls[0]
+    one = pallas_paged.chunk_blocks(bt * cfg.d_model * 2, cfg.max_len // bt)
+    assert one == 16 and chunks == [one], chunks
+
+
 def test_qwen3_next_tick_and_admit_hold_the_new_scopes():
     """A tiny GDN model: the tick's and the admission's lowered text holds
     the delta rule's and the shared expert's scopes beside the expert
@@ -627,7 +791,7 @@ def test_qwen3_next_tick_and_admit_hold_the_new_scopes():
                   "admit.moe_route", "admit.moe_experts", "admit.gdn_scan",
                   "admit.shared_expert"):
         assert _has_scope(admit, scope), scope
-    paged._PAGED_TICK_CACHE.pop((cfg, bt, "gather", 1), None)
+    paged._PAGED_TICK_CACHE.pop((cfg, bt, 1), None)
     paged._PAGED_ADMIT_CACHE.pop((cfg, 16, bt), None)
 
 
@@ -765,7 +929,7 @@ def test_smallthinker_tick_and_admit_hold_the_five_new_scopes():
     for scope in ("admit.prefill", "admit.scatter", "admit.attend",
                   "admit.moe_route", "admit.moe_experts"):
         assert _has_scope(admit, scope), scope
-    paged._PAGED_TICK_CACHE.pop((cfg, bt, "gather", 1), None)
+    paged._PAGED_TICK_CACHE.pop((cfg, bt, 1), None)
     paged._PAGED_ADMIT_CACHE.pop((cfg, 16, bt), None)
 
 

@@ -557,7 +557,7 @@ class TestKvDtype:
 
         monkeypatch.setenv(ENV_KV, "bf16")
         lm = TransformerLM(tiny_lm_cfg(max_len=32))
-        # a down-cast arena under an f32 model takes the gather path
+        # off a chip every arena takes the gather path
         assert attention_path(lm._run_cfg, 8) == "gather"
         d = PagedDecoder(lm, block_tokens=8, n_blocks=16)
         try:

@@ -153,13 +153,16 @@ def _tick_inputs(cfg, lanes, pos, seed=0):
         jnp.asarray(_dense_tables(lanes, m))
 
 
-def _pr27_chunked_attention(q, ck, cv, tables, pos):
+def _pr27_chunked_attention(q, ck, cv, tables, pos, scale=None, lo=None):
     """paged.chunked_attention as PR 27 left it, kept here letter for
     letter: full multi-head attention only, scores over sqrt(hd), the
     heads a batch axis of both products. The oracle for the logits of a
     GPT-2-shaped model's tick, whatever paged.chunked_attention does to
-    tell the heads apart (ISSUE 28, ISSUE 35)."""
+    tell the heads apart. The tick hands it no scale and no lower
+    bound."""
     from jax import lax
+
+    assert scale is None and lo is None
 
     s, n_heads, hd = q.shape
     bt = ck.shape[1]
@@ -213,7 +216,7 @@ def test_lane_logits_bit_equal_alone_and_beside_a_longer_lane(shape,
     short, long_ = CHUNK // 2, 4 * CHUNK + CHUNK // 2
     params, arena, tok, _, tables = _tick_inputs(cfg, 2, [0, 0])
     tick = lambda a, p: paged.paged_decode_step(
-        params, a, tok, p, tables, cfg, attention="gather")[1]
+        params, a, tok, p, tables, cfg)[1]
     step = jax.jit(tick)
     # lane 1 dead at position 0: the loop runs one chunk; then live four
     # chunks further on: it runs five
@@ -294,7 +297,7 @@ def _hybrid_tick_text(lanes, heads, kv_heads, hd):
         arena[leaf.name] = tuple(arg((lanes,) + leaf.shape, leaf.dtype)
                                  for _ in range(leaf.layers))
     tick = paged._paged_tick_for(cfg, BT)
-    paged._PAGED_TICK_CACHE.pop((cfg, BT, "gather", 1), None)
+    paged._PAGED_TICK_CACHE.pop((cfg, BT, 1), None)
     return tick.lower(
         params, arena, arg((lanes,), jnp.int32), arg((lanes,), jnp.int32),
         arg((lanes, MAX_LEN // BT), jnp.int32), arg((lanes, 2), jnp.uint32),
@@ -306,7 +309,7 @@ def _dense_tick_text(lanes, heads, hd):
                dtype_policy="performance")
     params, arena, tok, pos, tables = _tick_inputs(cfg, lanes, [0] * lanes)
     tick = paged._paged_tick_for(cfg, BT)
-    paged._PAGED_TICK_CACHE.pop((cfg, BT, "gather", 1), None)
+    paged._PAGED_TICK_CACHE.pop((cfg, BT, 1), None)
     return tick.lower(params, arena, tok, pos, tables,
                       jnp.zeros((lanes, 2), jnp.uint32),
                       jnp.zeros((lanes,), jnp.float32)).as_text()
@@ -500,7 +503,7 @@ def test_one_tick_program_for_every_live_length():
         arena, nxt, keys = tick(params, arena, tok, pos, tables, keys, temps)
         np.asarray(nxt)
     added = [k for k in paged._PAGED_TICK_CACHE if k not in before]
-    assert added == [(cfg, bt, "gather", 1)]
+    assert added == [(cfg, bt, 1)]
     assert paged._paged_tick_for(cfg, bt) is tick
     assert tick._cache_size() == 1
 
@@ -559,7 +562,7 @@ def test_a_tick_writes_its_lanes_rows_of_every_layer_and_nothing_else(policy):
     out, logits = jax.jit(
         lambda a: paged.paged_decode_step(
             init_params(cfg), a, tok, jnp.asarray(pos), jnp.asarray(tables),
-            cfg, attention="gather"))(arena)
+            cfg))(arena)
     assert np.isfinite(np.asarray(logits)).all()
     wrote = {(n_blocks, 1), (2, 2), (0, 0)}        # (block, offset) a layer
     want = {(l, b, t) for l in range(cfg.n_layers) for b, t in wrote}
