@@ -1,16 +1,22 @@
 """A serve-only LM block with mixers and feed-forwards of several kinds
-under one residual stream: Mamba-2 layers beside grouped-query attention
+under one residual stream, read from four families' published config.json
+(``FAMILIES``): Mamba-2 layers beside grouped-query attention
 (`granitemoehybrid` without experts, IBM Granite 4.0-H), attention whose
 layers differ in their own data, window and rotary positions, over routed
-experts (`smallthinker`, PowerInfer SmallThinker), and Gated DeltaNet linear
+experts (`smallthinker`, PowerInfer SmallThinker), Gated DeltaNet linear
 attention beside gated full attention over routed experts and a shared one
-(`qwen3_next`, Qwen3-Next). A layer names its mixer (``layer_types``:
-``mamba``, ``linear_attention``, or ``attention`` with ``rope`` and
-``window`` as that layer's data) and the model its feed-forward (``ffn``:
-``gated`` with its activation, or ``experts``); RMS normalisation
-throughout, no biases, four scalar multipliers (embedding, residual,
-attention, logits; 1 where a family has none), the head tied to the
-embedding or a matrix of its own. With ``u`` a layer's normalised input:
+(`qwen3_next`, Qwen3-Next), and the SambaY decoder-hybrid-decoder
+(`phi4flash`, Phi-4-mini-flash-reasoning: Mamba-1 and window differential
+attention, one full layer's keys and values read by cross-attention layers
+beside gated memory units). A layer names its mixer (``layer_types``:
+``mamba``, ``linear_attention``, ``selective`` (Mamba-1),
+``cross_attention``, ``gmu``, or ``attention`` with ``rope`` and ``window``
+as that layer's data) and the model its feed-forward (``ffn``: ``gated``
+with its activation, or ``experts``); RMS normalisation and no biases but
+where the model says otherwise (``layer_norm``, ``attn_bias``), four scalar
+multipliers (embedding, residual, attention, logits; 1 where a family has
+none), the head tied to the embedding or a matrix of its own. With ``u`` a
+layer's normalised input:
 
   * every layer: ``h += r * Mixer(u)``, ``u = RMSNorm(h)``, then the
     feed-forward on ``x = RMSNorm(h)``: gated, ``h += r * W_down(act(g) *
@@ -64,6 +70,34 @@ that defaults to the other families' behaviour:
     - S^T k^_t)``, ``S <- S + k^_t (outer) delta``, ``o_t = S^T q^_t``; ``y =
     RMSNorm_w(o) * silu(z)`` per value head; output ``W_out y``.
 
+`phi4flash` (SambaY) changes these, each a field of the config:
+
+  * LayerNorm ``(x - mean) / sqrt(var + eps) * w + b`` in every norm
+    (``layer_norm``), and ``W x + b`` in every attention projection
+    (``attn_bias``).
+  * differential attention (``diff_attn``): query heads 2i, 2i+1 are pair
+    i, KV heads 2j, 2j+1 pair j, pair i reads pair i // 2; ``a1 =
+    softmax(q1 k1^T s) [v1 | v2]``, ``a2 = softmax(q2 k2^T s) [v1 | v2]``;
+    ``o = RMSNorm(a1 - lambda a2) * (1 - lambda_init)`` over the pair's 2
+    hd, ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init``,
+    ``lambda_init = 0.8 - 0.6 exp(-0.3 l)`` at layer l. Both maps run in
+    the attention kernels that exist: the arena's row read as heads of a
+    KV pair side by side, each query head a row of that width zero where
+    the other map's keys lie (``_pair_rows``).
+  * Mamba-1 (``selective``; inner ``sel_inner``, state ``sel_state``,
+    rank ``sel_dt_rank``): ``[x | z] = W_in u``; ``x = silu(conv(x) +
+    b)``; ``[dt | B | C] = W_x x``; ``delta = softplus(W_dt dt + b_dt)``;
+    ``S_t = exp(delta_t A) * S_{t-1} + B_t (delta_t x_t)`` with ``A =
+    -exp(A_log)`` of its own a (state entry, channel), ``S [N, inner]``;
+    ``y = (C_t S_t + D x) * silu(z)``; output ``W_out y``. Layer
+    ``memory_layer`` keeps y as the memory m.
+  * GMU (``gmu``): ``W_out (silu(W_in u) * m)``, m of the same token.
+  * cross-attention (``cross_attention``): ``q = W_q u + b``, attending
+    the last attention layer's keys and values (one buffer, written by
+    that layer, read by all: ``KVGroup.readers``). The admission stops at
+    that layer's K and V (``admit_layers``): nothing after it feeds a
+    later token's state.
+
 Two computations of the recurrence, one result: the admission prefill runs
 it in chunks (``ssm_chunk``; inside a chunk a masked matrix product, between
 chunks the carried ``S``), the decode tick one step on the lane's stored
@@ -99,7 +133,8 @@ and two state leaves a lane where it has Mamba layers: ``ssm`` ``[H, P, N]``
 float32 and ``conv``
 ``[ssm_conv - 1, conv_dim]`` in the compute dtype (where it has GDN layers
 ``gdn`` ``[Hv, dk, dv]`` float32 and ``gdn_conv`` ``[gdn_conv - 1, 2 Hk dk +
-Hv dv]``), ONE BUFFER A LAYER
+Hv dv]``, where it has Mamba-1 layers ``selective`` ``[N, inner]`` float32
+and ``selective_conv`` ``[sel_conv - 1, inner]``), ONE BUFFER A LAYER
 (``[lanes, ...]`` each) so that the tick rewrites each in place and nothing
 restacks 4 GB of state. They ride in the decoder's arena pytree beside
 ``k`` and ``v`` and are donated with it. K and V are one buffer an
@@ -123,13 +158,16 @@ where it leaves the projection, which is what the stored tail holds.
 Reference anchor: none in the DL4J 0.4 reference, whose recurrent layers
 are LSTM and GRU cells (nn/layers/recurrent/GravesLSTM.java) and which has
 neither attention nor experts; provenance
-is Dao & Gu, "Transformers are SSMs" (Mamba-2 and its chunked dual form),
+is Gu & Dao (Mamba-1), Ren et al. (SambaY), Sun et al. (YOCO), Ye et al.
+(differential attention), Dao & Gu, "Transformers are SSMs" (Mamba-2 and
+its chunked dual form),
 Su et al. (rotary positions), Beltagy et al. (sliding windows), Gale et
 al. (dropless experts), Yang et al., "Gated Delta Networks" (the gated
 delta rule and its chunked form) and the published `granitemoehybrid`,
-`smallthinker` and `qwen3_next` config.json. The plain float32 forms of the
-same equations are perfbench/reference_granite.py,
-perfbench/reference_smallthinker.py and perfbench/reference_qwen3_next.py.
+`smallthinker`, `qwen3_next` and `phi4flash` config.json. The plain float32
+forms of the same equations are perfbench/reference_granite.py,
+perfbench/reference_smallthinker.py, perfbench/reference_qwen3_next.py and
+perfbench/reference_phi4flash.py.
 """
 from __future__ import annotations
 
@@ -147,7 +185,12 @@ from deeplearning4j_tpu.ops.memory import CacheNeeds, KVGroup, StateLeaf
 
 Params = Dict[str, Any]
 MAMBA, ATTENTION, LINEAR = "mamba", "attention", "linear_attention"
+SELECTIVE, CROSS, GMU = "selective", "cross_attention", "gmu"
+KINDS = (MAMBA, ATTENTION, LINEAR, SELECTIVE, CROSS, GMU)
 GATED, EXPERTS = "gated", "experts"
+# the per-lane recurrent state leaves a model may hold (cache_needs)
+STATE_LEAVES = ("ssm", "conv", "gdn", "gdn_conv", "selective",
+                "selective_conv")
 ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
@@ -217,17 +260,48 @@ class HybridConfig:
     gdn_v_dim: int = 128
     gdn_conv: int = 4
     gdn_chunk: int = 64
+    # phi4flash (SambaY): LayerNorm with a bias in every norm, biases on
+    # the attention projections, differential attention (query heads 2i,
+    # 2i+1 a pair over KV heads 2j, 2j+1), Mamba-1 (``selective``) layers
+    # of inner width ``sel_inner``, state ``sel_state``, a rank
+    # ``sel_dt_rank`` step and a conv of ``sel_conv``, the admission's scan
+    # in time chunks of ``sel_chunk``; the Mamba-1 layer ``memory_layer``
+    # (among all layers) keeps its gated output, which GMU layers gate;
+    # cross-attention layers project q alone and read the last attention
+    # layer's keys and values, and the admission stops at that projection
+    layer_norm: bool = False
+    attn_bias: bool = False
+    diff_attn: bool = False
+    sel_inner: int = 0
+    sel_state: int = 16
+    sel_dt_rank: int = 0
+    sel_conv: int = 4
+    sel_chunk: int = 128
+    memory_layer: int = -1
 
     # what the decode pools ask of an expert layer: no row is dropped and a
     # row's output is its own, whoever shares its batch
     moe_dropless = True
 
     def __post_init__(self):
-        kinds = (MAMBA, ATTENTION, LINEAR)
-        bad = [t for t in self.layer_types if t not in kinds]
+        bad = [t for t in self.layer_types if t not in KINDS]
         if bad or not self.layer_types:
-            raise ValueError(f"layer_types must name one of {kinds} for "
+            raise ValueError(f"layer_types must name one of {KINDS} for "
                              f"every layer, got {bad}")
+        kinds = self.layer_types
+        last_attention = max((i for i, t in enumerate(kinds)
+                              if t == ATTENTION), default=len(kinds))
+        if self.n_cross and (
+                kinds.index(CROSS) < last_attention
+                or self.window_of(self.n_attention - 1)
+                in [self.window_of(j) for j in range(self.n_attention - 1)]):
+            raise ValueError("cross-attention layers come after the last "
+                             "attention layer, which pages alone")
+        if self.n_gmu and not (
+                0 <= self.memory_layer < kinds.index(GMU)
+                and kinds[self.memory_layer] == SELECTIVE):
+            raise ValueError("GMU layers come after the Mamba-1 layer "
+                             "memory_layer")
         if (not self.attn_head_dim and self.d_model % self.n_heads) \
                 or self.n_heads % self.n_kv_heads:
             raise ValueError(
@@ -261,153 +335,23 @@ class HybridConfig:
         if self.rope_dims % 2 or self.rope_dims > self.head_dim:
             raise ValueError(f"rotary over {self.rope_dims} of a head of "
                              f"{self.head_dim}")
+        if self.n_selective and not (self.sel_inner and self.sel_dt_rank):
+            raise ValueError("Mamba-1 layers need sel_inner and sel_dt_rank")
+        if self.diff_attn and (self.n_heads % 2 or self.n_kv_heads % 2):
+            raise ValueError("differential attention pairs its heads")
 
     @classmethod
     def from_published(cls, conf: Dict[str, Any], *, max_len: int,
                        dtype_policy: str = "performance") -> "HybridConfig":
-        """From a published config.json's own keys: `granitemoehybrid`
-        (the default), `smallthinker` or `qwen3_next` (``model_type``).
-        Whatever of a family this module does not compute is refused
-        here."""
-        if conf.get("model_type") == "smallthinker":
-            return cls._from_smallthinker(conf, int(max_len), dtype_policy)
-        if conf.get("model_type") == "qwen3_next":
-            return cls._from_qwen3_next(conf, int(max_len), dtype_policy)
-        refused = [
-            (conf.get("num_local_experts", 0) != 0, "experts"),
-            (conf.get("mamba_n_groups", 1) != 1, "mamba_n_groups != 1"),
-            (conf.get("position_embedding_type", "nope") != "nope",
-             "positional encoding"),
-            (conf.get("normalization_function", "rmsnorm") != "rmsnorm",
-             "a norm other than rmsnorm"),
-            (conf.get("hidden_act", "silu") != "silu", "hidden_act"),
-            (bool(conf.get("attention_bias")), "attention_bias"),
-            (bool(conf.get("mamba_proj_bias")), "mamba_proj_bias"),
-            (not conf.get("mamba_conv_bias", True), "no mamba_conv_bias"),
-            (not conf.get("tie_word_embeddings", True), "an untied head"),
-            (conf["mamba_expand"] * conf["hidden_size"]
-             != conf["mamba_n_heads"] * conf["mamba_d_head"],
-             "mamba_expand x hidden_size != mamba_n_heads x mamba_d_head"),
-        ]
-        bad = [what for is_bad, what in refused if is_bad]
-        if bad:
-            raise ValueError("HybridLM does not compute: " + ", ".join(bad))
-        return cls(
-            vocab_size=conf["vocab_size"], d_model=conf["hidden_size"],
-            layer_types=tuple(conf["layer_types"]),
-            n_heads=conf["num_attention_heads"],
-            n_kv_heads=conf["num_key_value_heads"],
-            d_ff=conf["intermediate_size"], max_len=int(max_len),
-            ssm_heads=conf["mamba_n_heads"],
-            ssm_head_dim=conf["mamba_d_head"],
-            ssm_state=conf["mamba_d_state"], ssm_conv=conf["mamba_d_conv"],
-            ssm_chunk=conf["mamba_chunk_size"],
-            embedding_multiplier=float(conf["embedding_multiplier"]),
-            residual_multiplier=float(conf["residual_multiplier"]),
-            attention_multiplier=float(conf["attention_multiplier"]),
-            logits_scaling=float(conf["logits_scaling"]),
-            rms_eps=float(conf["rms_norm_eps"]), dtype_policy=dtype_policy)
-
-    @classmethod
-    def _from_smallthinker(cls, conf, max_len, dtype_policy):
-        """`smallthinker`: every layer attention over grouped heads and
-        routed ReGLU experts; ``rope_layout`` and ``sliding_window_layout``
-        say layer by layer which have rotary positions and the window.
-        ``n_layer`` (where the file has it) is how many of the
-        ``num_hidden_layers`` are held here, from layer 0 on."""
-        n = int(conf.get("n_layer", conf["num_hidden_layers"]))
-        ropes = conf["rope_layout"][:n]
-        windows = conf["sliding_window_layout"][:n]
-        refused = [
-            (conf.get("rope_scaling") is not None, "rope_scaling"),
-            (not conf.get("moe_primary_router_apply_softmax", True),
-             "a router without the softmax over the chosen"),
-            (len(ropes) != n or len(windows) != n,
-             "layouts shorter than the layers held"),
-            (max_len > conf["max_position_embeddings"],
-             "a served context past max_position_embeddings"),
-        ]
-        bad = [what for is_bad, what in refused if is_bad]
-        if bad:
-            raise ValueError("HybridLM does not compute: " + ", ".join(bad))
-        return cls(
-            vocab_size=conf["vocab_size"], d_model=conf["hidden_size"],
-            layer_types=(ATTENTION,) * n,
-            n_heads=conf["num_attention_heads"],
-            n_kv_heads=conf["num_key_value_heads"],
-            attn_head_dim=conf["head_dim"],
-            d_ff=conf["moe_ffn_hidden_size"], max_len=max_len,
-            rope=tuple(bool(r) for r in ropes),
-            rope_theta=float(conf["rope_theta"]),
-            window=tuple(int(conf["sliding_window_size"]) if w else 0
-                         for w in windows),
-            attn_exact=False, ffn=EXPERTS, ffn_act="relu",
-            moe_experts=conf["moe_num_primary_experts"],
-            moe_top_k=conf["moe_num_active_primary_experts"],
-            tie_head=bool(conf["tie_word_embeddings"]),
-            embedding_multiplier=1.0, residual_multiplier=1.0,
-            attention_multiplier=float(conf["head_dim"]) ** -0.5,
-            logits_scaling=1.0, rms_eps=float(conf["rms_norm_eps"]),
-            dtype_policy=dtype_policy)
-
-    @classmethod
-    def _from_qwen3_next(cls, conf, max_len, dtype_policy):
-        """`qwen3_next`: layer i full attention where ``(i + 1) %
-        full_attention_interval == 0``, else Gated DeltaNet; every layer's
-        feed-forward routed experts beside a shared one. ``n_layer`` (where
-        the file has it) is how many of the ``num_hidden_layers`` are held
-        here, from layer 0 on; ``num_experts`` how many experts are held,
-        from ``expert_first`` on, of the ``router_num_experts`` the router
-        scores (both ``num_experts`` where the file names no share)."""
-        n = int(conf.get("n_layer", conf["num_hidden_layers"]))
-        every = int(conf["full_attention_interval"])
-        kinds = tuple(ATTENTION if (i + 1) % every == 0 else LINEAR
-                      for i in range(n))
-        routed = int(conf.get("router_num_experts", conf["num_experts"]))
-        rope_dims = conf["head_dim"] * float(conf["partial_rotary_factor"])
-        refused = [
-            (conf.get("decoder_sparse_step", 1) != 1
-             or bool(conf.get("mlp_only_layers")), "dense feed-forward layers"),
-            (conf.get("rope_scaling") is not None, "rope_scaling"),
-            (bool(conf.get("use_sliding_window")), "a sliding window"),
-            (bool(conf.get("attention_bias")), "attention_bias"),
-            (conf.get("hidden_act", "silu") != "silu", "hidden_act"),
-            (not conf.get("norm_topk_prob", True),
-             "a router without the softmax over the chosen"),
-            (n > conf["num_hidden_layers"], "more layers than published"),
-            (rope_dims != int(rope_dims) or int(rope_dims) % 2,
-             "a rotary share that is no even number of dims"),
-            (max_len > conf["max_position_embeddings"],
-             "a served context past max_position_embeddings"),
-        ]
-        bad = [what for is_bad, what in refused if is_bad]
-        if bad:
-            raise ValueError("HybridLM does not compute: " + ", ".join(bad))
-        return cls(
-            vocab_size=conf["vocab_size"], d_model=conf["hidden_size"],
-            layer_types=kinds, n_heads=conf["num_attention_heads"],
-            n_kv_heads=conf["num_key_value_heads"],
-            attn_head_dim=conf["head_dim"],
-            d_ff=conf["moe_intermediate_size"], max_len=max_len,
-            rope=(True,) * kinds.count(ATTENTION),
-            rope_theta=float(conf["rope_theta"]), rope_dims=int(rope_dims),
-            attn_exact=False, attn_gate=True, qk_norm=True,
-            norm_offset=True, ffn=EXPERTS, ffn_act="silu",
-            moe_experts=routed, moe_top_k=conf["num_experts_per_tok"],
-            moe_first=int(conf.get("expert_first", 0)),
-            moe_held=int(conf["num_experts"]),
-            shared_expert=int(conf["shared_expert_intermediate_size"]),
-            router_after_mixer=True,
-            tie_head=bool(conf["tie_word_embeddings"]),
-            gdn_k_heads=conf["linear_num_key_heads"],
-            gdn_v_heads=conf["linear_num_value_heads"],
-            gdn_k_dim=conf["linear_key_head_dim"],
-            gdn_v_dim=conf["linear_value_head_dim"],
-            gdn_conv=conf["linear_conv_kernel_dim"],
-            embedding_multiplier=1.0, residual_multiplier=1.0,
-            attention_multiplier=float(conf["head_dim"]) ** -0.5,
-            logits_scaling=1.0, rms_eps=float(conf["rms_norm_eps"]),
-            dtype_policy=dtype_policy)
+        """From a published config.json's own keys, by the family its
+        ``model_type`` names (``FAMILIES``; `granitemoehybrid` where it
+        names none). Whatever of a family this module does not compute is
+        refused by the family's reader."""
+        kind = conf.get("model_type", "granitemoehybrid")
+        if kind not in FAMILIES:
+            raise ValueError(f"HybridLM reads no model_type {kind!r} (have "
+                             f"{sorted(FAMILIES)})")
+        return FAMILIES[kind](cls, conf, int(max_len), dtype_policy)
 
     # -- sizes ------------------------------------------------------------
     @property
@@ -430,6 +374,42 @@ class HybridConfig:
     @property
     def n_attention(self) -> int:
         return sum(t == ATTENTION for t in self.layer_types)
+
+    @property
+    def n_selective(self) -> int:
+        return sum(t == SELECTIVE for t in self.layer_types)
+
+    @property
+    def n_cross(self) -> int:
+        return sum(t == CROSS for t in self.layer_types)
+
+    @property
+    def n_gmu(self) -> int:
+        return sum(t == GMU for t in self.layer_types)
+
+    @property
+    def attend_shape(self) -> Tuple[int, int, int]:
+        """(query rows, KV heads, head width) as the attention kernels see
+        them: with differential attention the arena's row read as heads of
+        a KV pair side by side, and each query head a row of that width,
+        zero where the other map's keys lie (``_pair_rows``)."""
+        if self.diff_attn:
+            return self.n_heads, self.n_kv_heads // 2, 2 * self.head_dim
+        return self.n_heads, self.n_kv_heads, self.head_dim
+
+    def lambda_init(self, g: int) -> float:
+        """Differential attention's lambda_init at layer g (among all)."""
+        return 0.8 - 0.6 * math.exp(-0.3 * g)
+
+    @property
+    def admit_layers(self) -> int:
+        """Layers the admission runs whole: with cross-attention layers it
+        stops before the last attention layer, of which it projects K and
+        V alone (and before everything after it)."""
+        if self.n_cross:
+            return max(i for i, t in enumerate(self.layer_types)
+                       if t == ATTENTION)
+        return self.n_layers
 
     @property
     def moe_count(self) -> int:
@@ -484,7 +464,7 @@ class HybridConfig:
         """Maximal runs of one layer kind: (kind, first layer, first index
         among the layers of that kind, length)."""
         out: List[Tuple[str, int, int, int]] = []
-        seen = {MAMBA: 0, ATTENTION: 0, LINEAR: 0}
+        seen = dict.fromkeys(KINDS, 0)
         for g, kind in enumerate(self.layer_types):
             if out and out[-1][0] == kind:
                 out[-1] = out[-1][:3] + (out[-1][3] + 1,)
@@ -497,16 +477,23 @@ class HybridConfig:
     def kv_groups(self) -> Tuple[KVGroup, ...]:
         """The attention layers by how they page: one group a distinct
         window (0, every position, first), each with its layers' indices
-        among the attention layers."""
+        among the attention layers. The group of the last attention layer
+        is read by the cross-attention layers too (``readers``)."""
         sizes = sorted({self.window_of(j) for j in range(self.n_attention)})
+        shared = self.window_of(self.n_attention - 1) if self.n_cross \
+            else None
         return tuple(
             KVGroup(tuple(j for j in range(self.n_attention)
-                          if self.window_of(j) == w), w) for w in sizes)
+                          if self.window_of(j) == w), w,
+                    **({"readers": 1 + self.n_cross} if w == shared else {}))
+            for w in sizes)
 
     def cache_needs(self) -> CacheNeeds:
+        dt = jnp.dtype(self.compute_dtype).name
+        _, kv_heads, head_dim = self.attend_shape
         return CacheNeeds(
-            kv_layers=self.n_attention, kv_heads=self.n_kv_heads,
-            head_dim=self.head_dim, groups=self.kv_groups(),
+            kv_layers=self.n_attention, kv_heads=kv_heads,
+            head_dim=head_dim, groups=self.kv_groups(),
             state=((StateLeaf("ssm", self.n_mamba,
                               (self.ssm_heads, self.ssm_head_dim,
                                self.ssm_state), "float32"),
@@ -520,7 +507,14 @@ class HybridConfig:
                 StateLeaf("gdn_conv", self.n_linear,
                           (self.gdn_conv - 1, self.gdn_conv_dim),
                           jnp.dtype(self.compute_dtype).name))
-               if self.n_linear else ()),
+               if self.n_linear else ())
+            # Mamba-1's S laid [N, inner] (the state on the sublanes, the
+            # channels on the lanes: [inner, 16] would pad 16 to 128)
+            + ((StateLeaf("selective", self.n_selective,
+                          (self.sel_state, self.sel_inner), "float32"),
+                StateLeaf("selective_conv", self.n_selective,
+                          (self.sel_conv - 1, self.sel_inner), dt))
+               if self.n_selective else ()),
             kv_per_layer=True)
 
     def paged_decode_step(self, params, arena, tok, pos, tables):
@@ -536,7 +530,10 @@ class HybridConfig:
 
     def scan_chunks(self, width: int) -> int:
         """Chunks the admission prefill's scan walks at a bucket width (the
-        delta rule's where the model has no Mamba layer)."""
+        Mamba-1 scan's time chunks, else the delta rule's where the model
+        has no Mamba layer)."""
+        if self.n_selective:
+            return -(-width // self.sel_chunk)
         if not self.n_mamba:
             return -(-width // self.gdn_chunk)
         return width // _chunk_len(self, width)
@@ -549,11 +546,212 @@ class HybridConfig:
         from deeplearning4j_tpu.ops import pallas_prefill
         from deeplearning4j_tpu.ops.pallas_kernels import pallas_enabled
 
-        fits = pallas_prefill.fits(width, self.n_heads // self.n_kv_heads,
-                                   self.head_dim)
+        rows, kv_heads, head_dim = self.attend_shape
+        fits = pallas_prefill.fits(width, rows // kv_heads, head_dim)
         return "kernel" if not self.attn_exact and fits and \
             pallas_enabled() else "xla"
 
+
+# ---------------------------------------------------------------------------
+# the families a published config.json can name
+# ---------------------------------------------------------------------------
+
+
+def _from_granite(cls, conf, max_len, dtype_policy):
+    """`granitemoehybrid` without experts: Mamba-2 layers beside grouped-
+    query attention, as ``layer_types`` lays them."""
+    refused = [
+        (conf.get("num_local_experts", 0) != 0, "experts"),
+        (conf.get("mamba_n_groups", 1) != 1, "mamba_n_groups != 1"),
+        (conf.get("position_embedding_type", "nope") != "nope",
+         "positional encoding"),
+        (conf.get("normalization_function", "rmsnorm") != "rmsnorm",
+         "a norm other than rmsnorm"),
+        (conf.get("hidden_act", "silu") != "silu", "hidden_act"),
+        (bool(conf.get("attention_bias")), "attention_bias"),
+        (bool(conf.get("mamba_proj_bias")), "mamba_proj_bias"),
+        (not conf.get("mamba_conv_bias", True), "no mamba_conv_bias"),
+        (not conf.get("tie_word_embeddings", True), "an untied head"),
+        (conf["mamba_expand"] * conf["hidden_size"]
+         != conf["mamba_n_heads"] * conf["mamba_d_head"],
+         "mamba_expand x hidden_size != mamba_n_heads x mamba_d_head"),
+    ]
+    bad = [what for is_bad, what in refused if is_bad]
+    if bad:
+        raise ValueError("HybridLM does not compute: " + ", ".join(bad))
+    return cls(
+        vocab_size=conf["vocab_size"], d_model=conf["hidden_size"],
+        layer_types=tuple(conf["layer_types"]),
+        n_heads=conf["num_attention_heads"],
+        n_kv_heads=conf["num_key_value_heads"],
+        d_ff=conf["intermediate_size"], max_len=max_len,
+        ssm_heads=conf["mamba_n_heads"],
+        ssm_head_dim=conf["mamba_d_head"],
+        ssm_state=conf["mamba_d_state"], ssm_conv=conf["mamba_d_conv"],
+        ssm_chunk=conf["mamba_chunk_size"],
+        embedding_multiplier=float(conf["embedding_multiplier"]),
+        residual_multiplier=float(conf["residual_multiplier"]),
+        attention_multiplier=float(conf["attention_multiplier"]),
+        logits_scaling=float(conf["logits_scaling"]),
+        rms_eps=float(conf["rms_norm_eps"]), dtype_policy=dtype_policy)
+
+
+def _from_smallthinker(cls, conf, max_len, dtype_policy):
+    """`smallthinker`: every layer attention over grouped heads and
+    routed ReGLU experts; ``rope_layout`` and ``sliding_window_layout``
+    say layer by layer which have rotary positions and the window.
+    ``n_layer`` (where the file has it) is how many of the
+    ``num_hidden_layers`` are held here, from layer 0 on."""
+    n = int(conf.get("n_layer", conf["num_hidden_layers"]))
+    ropes = conf["rope_layout"][:n]
+    windows = conf["sliding_window_layout"][:n]
+    refused = [
+        (conf.get("rope_scaling") is not None, "rope_scaling"),
+        (not conf.get("moe_primary_router_apply_softmax", True),
+         "a router without the softmax over the chosen"),
+        (len(ropes) != n or len(windows) != n,
+         "layouts shorter than the layers held"),
+        (max_len > conf["max_position_embeddings"],
+         "a served context past max_position_embeddings"),
+    ]
+    bad = [what for is_bad, what in refused if is_bad]
+    if bad:
+        raise ValueError("HybridLM does not compute: " + ", ".join(bad))
+    return cls(
+        vocab_size=conf["vocab_size"], d_model=conf["hidden_size"],
+        layer_types=(ATTENTION,) * n,
+        n_heads=conf["num_attention_heads"],
+        n_kv_heads=conf["num_key_value_heads"],
+        attn_head_dim=conf["head_dim"],
+        d_ff=conf["moe_ffn_hidden_size"], max_len=max_len,
+        rope=tuple(bool(r) for r in ropes),
+        rope_theta=float(conf["rope_theta"]),
+        window=tuple(int(conf["sliding_window_size"]) if w else 0
+                     for w in windows),
+        attn_exact=False, ffn=EXPERTS, ffn_act="relu",
+        moe_experts=conf["moe_num_primary_experts"],
+        moe_top_k=conf["moe_num_active_primary_experts"],
+        tie_head=bool(conf["tie_word_embeddings"]),
+        embedding_multiplier=1.0, residual_multiplier=1.0,
+        attention_multiplier=float(conf["head_dim"]) ** -0.5,
+        logits_scaling=1.0, rms_eps=float(conf["rms_norm_eps"]),
+        dtype_policy=dtype_policy)
+
+
+def _from_qwen3_next(cls, conf, max_len, dtype_policy):
+    """`qwen3_next`: layer i full attention where ``(i + 1) %
+    full_attention_interval == 0``, else Gated DeltaNet; every layer's
+    feed-forward routed experts beside a shared one. ``n_layer`` (where
+    the file has it) is how many of the ``num_hidden_layers`` are held
+    here, from layer 0 on; ``num_experts`` how many experts are held,
+    from ``expert_first`` on, of the ``router_num_experts`` the router
+    scores (both ``num_experts`` where the file names no share)."""
+    n = int(conf.get("n_layer", conf["num_hidden_layers"]))
+    every = int(conf["full_attention_interval"])
+    kinds = tuple(ATTENTION if (i + 1) % every == 0 else LINEAR
+                  for i in range(n))
+    routed = int(conf.get("router_num_experts", conf["num_experts"]))
+    rope_dims = conf["head_dim"] * float(conf["partial_rotary_factor"])
+    refused = [
+        (conf.get("decoder_sparse_step", 1) != 1
+         or bool(conf.get("mlp_only_layers")), "dense feed-forward layers"),
+        (conf.get("rope_scaling") is not None, "rope_scaling"),
+        (bool(conf.get("use_sliding_window")), "a sliding window"),
+        (bool(conf.get("attention_bias")), "attention_bias"),
+        (conf.get("hidden_act", "silu") != "silu", "hidden_act"),
+        (not conf.get("norm_topk_prob", True),
+         "a router without the softmax over the chosen"),
+        (n > conf["num_hidden_layers"], "more layers than published"),
+        (rope_dims != int(rope_dims) or int(rope_dims) % 2,
+         "a rotary share that is no even number of dims"),
+        (max_len > conf["max_position_embeddings"],
+         "a served context past max_position_embeddings"),
+    ]
+    bad = [what for is_bad, what in refused if is_bad]
+    if bad:
+        raise ValueError("HybridLM does not compute: " + ", ".join(bad))
+    return cls(
+        vocab_size=conf["vocab_size"], d_model=conf["hidden_size"],
+        layer_types=kinds, n_heads=conf["num_attention_heads"],
+        n_kv_heads=conf["num_key_value_heads"],
+        attn_head_dim=conf["head_dim"],
+        d_ff=conf["moe_intermediate_size"], max_len=max_len,
+        rope=(True,) * kinds.count(ATTENTION),
+        rope_theta=float(conf["rope_theta"]), rope_dims=int(rope_dims),
+        attn_exact=False, attn_gate=True, qk_norm=True,
+        norm_offset=True, ffn=EXPERTS, ffn_act="silu",
+        moe_experts=routed, moe_top_k=conf["num_experts_per_tok"],
+        moe_first=int(conf.get("expert_first", 0)),
+        moe_held=int(conf["num_experts"]),
+        shared_expert=int(conf["shared_expert_intermediate_size"]),
+        router_after_mixer=True,
+        tie_head=bool(conf["tie_word_embeddings"]),
+        gdn_k_heads=conf["linear_num_key_heads"],
+        gdn_v_heads=conf["linear_num_value_heads"],
+        gdn_k_dim=conf["linear_key_head_dim"],
+        gdn_v_dim=conf["linear_value_head_dim"],
+        gdn_conv=conf["linear_conv_kernel_dim"],
+        embedding_multiplier=1.0, residual_multiplier=1.0,
+        attention_multiplier=float(conf["head_dim"]) ** -0.5,
+        logits_scaling=1.0, rms_eps=float(conf["rms_norm_eps"]),
+        dtype_policy=dtype_policy)
+
+
+def _from_phi4flash(cls, conf, max_len, dtype_policy):
+    """`phi4flash` (SambaY): with n layers, below n // 2 Mamba-1 at even
+    indices and differential attention over a window of ``sliding_window``
+    at odd ones; layer n // 2 Mamba-1 that keeps its gated output as the
+    memory; n // 2 + 1 differential attention over every position, whose
+    keys and values every later odd layer reads (cross-attention, q alone);
+    the later even layers GMU. LayerNorm with bias throughout, biases on
+    the attention projections, no positions, the head tied. The Mamba-1
+    sizes the config lacks are read where the file states them (the
+    family's: state 16, conv 4, expand 2, a step of rank ceil(d / 16))."""
+    n, d = int(conf["num_hidden_layers"]), int(conf["hidden_size"])
+    half = n // 2
+    kinds = tuple(
+        (SELECTIVE if i % 2 == 0 else ATTENTION) if i < half
+        else SELECTIVE if i == half else ATTENTION if i == half + 1
+        else (GMU if i % 2 == 0 else CROSS) for i in range(n))
+    rope = [k for k in conf if k.startswith("rope")
+            or k in ("partial_rotary_factor", "position_embedding_type")]
+    refused = [
+        (bool(rope), "positions (" + ", ".join(sorted(rope)) + ")"),
+        (conf.get("mb_per_layer", 2) != 2, "mb_per_layer other than 2"),
+        (bool(conf.get("mlp_bias")), "mlp_bias"),
+        (bool(conf.get("lm_head_bias")), "lm_head_bias"),
+        (conf.get("hidden_act", "silu") != "silu", "hidden_act"),
+        (not conf.get("tie_word_embeddings", True), "an untied head"),
+        (n % 2 or n < 4, "an odd number of layers, or fewer than 4"),
+        (max_len > conf["max_position_embeddings"],
+         "a served context past max_position_embeddings"),
+    ]
+    bad = [what for is_bad, what in refused if is_bad]
+    if bad:
+        raise ValueError("HybridLM does not compute: " + ", ".join(bad))
+    hd = d // conf["num_attention_heads"]
+    n_window = sum(k == ATTENTION for k in kinds[:half])
+    return cls(
+        vocab_size=conf["vocab_size"], d_model=d, layer_types=kinds,
+        n_heads=conf["num_attention_heads"],
+        n_kv_heads=conf["num_key_value_heads"],
+        d_ff=conf["intermediate_size"], max_len=max_len,
+        window=(int(conf["sliding_window"]),) * n_window + (0,),
+        attn_exact=False, layer_norm=True, attn_bias=True, diff_attn=True,
+        sel_inner=int(conf.get("mamba_expand", 2)) * d,
+        sel_state=int(conf.get("mamba_d_state", 16)),
+        sel_dt_rank=int(conf.get("mamba_dt_rank", math.ceil(d / 16))),
+        sel_conv=int(conf.get("mamba_d_conv", 4)), memory_layer=half,
+        tie_head=True, embedding_multiplier=1.0, residual_multiplier=1.0,
+        attention_multiplier=float(hd) ** -0.5, logits_scaling=1.0,
+        rms_eps=float(conf["layer_norm_eps"]), dtype_policy=dtype_policy)
+
+
+# the families ``HybridConfig.from_published`` reads, by ``model_type``
+FAMILIES = {"granitemoehybrid": _from_granite,
+            "smallthinker": _from_smallthinker,
+            "qwen3_next": _from_qwen3_next,
+            "phi4flash": _from_phi4flash}
 
 # ---------------------------------------------------------------------------
 # weights
@@ -597,13 +795,58 @@ def param_shapes(cfg: HybridConfig) -> Params:
         "A_log": (nm, cfg.ssm_heads), "D": (nm, cfg.ssm_heads),
         "norm_y": (nm, cfg.d_inner), "W_out": (nm, cfg.d_inner, d)}} \
         if nm else {}
-    return {
+    out = {
         "embed": (cfg.vocab_size, d), "norm_f": (d,), **head, **ffn,
         **mamba, **gdn,
         "attn": {
             "norm1": (na, d), "Wq": (na, d, qd), "Wk": (na, d, kv),
             "Wv": (na, d, kv), "Wo": (na, qd, d), **gated, **qk},
     }
+    _sambay_shapes(cfg, out)
+    return out
+
+
+def _sambay_shapes(cfg: HybridConfig, out: Params) -> None:
+    """The phi4flash leaves, into ``out``: Mamba-1 (``selective``; A_log
+    laid [N, inner] as its state is), the cross-attention layers' (q and o
+    alone) and the GMU layers'; a bias beside every norm's scale with
+    ``layer_norm``, beside every attention projection with ``attn_bias``,
+    and differential attention's lambda vectors and pair norm."""
+    d, hd, qd = cfg.d_model, cfg.head_dim, cfg.q_dim
+    di, n, r = cfg.sel_inner, cfg.sel_state, cfg.sel_dt_rank
+    ns, nc, ng = cfg.n_selective, cfg.n_cross, cfg.n_gmu
+    if ns:
+        out["selective"] = {
+            "norm1": (ns, d), "W_in": (ns, d, 2 * di),
+            "conv_w": (ns, cfg.sel_conv, di), "conv_b": (ns, di),
+            "W_x": (ns, di, r + 2 * n), "W_dt": (ns, r, di),
+            "dt_bias": (ns, di), "A_log": (ns, n, di), "D": (ns, di),
+            "W_out": (ns, di, d)}
+    if nc:
+        out["cross"] = {"norm1": (nc, d), "Wq": (nc, d, qd),
+                        "Wo": (nc, qd, d)}
+    if ng:
+        out["gmu"] = {"norm1": (ng, d), "W_in": (ng, d, di),
+                      "W_out": (ng, di, d)}
+    kv = cfg.n_kv_heads * hd
+    for kind, stack in (("attn", out["attn"]), ("cross", out.get("cross"))):
+        if stack is None:
+            continue
+        layers = stack["norm1"][0]
+        if cfg.attn_bias:
+            stack["bq"], stack["bo"] = (layers, qd), (layers, d)
+            if kind == "attn":
+                stack["bk"] = stack["bv"] = (layers, kv)
+        if cfg.diff_attn:
+            for name in ("lq1", "lk1", "lq2", "lk2"):
+                stack[name] = (layers, hd)
+            stack["sub"] = (layers, 2 * hd)
+    if cfg.layer_norm:
+        out["norm_f_b"] = (d,)
+        out["mlp"]["norm2_b"] = out["mlp"]["norm2"]
+        for kind in ("attn", "selective", "cross", "gmu"):
+            if kind in out:
+                out[kind]["norm1_b"] = out[kind]["norm1"]
 
 
 def init_params(cfg: HybridConfig, key) -> Params:
@@ -691,7 +934,52 @@ def init_params(cfg: HybridConfig, key) -> Params:
                                                 1.0, 16.0)),
             "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
             "norm_o": ones(g["norm_o"]), "W_out": xavier(g["W_out"])}
+    if "selective" in shapes or cfg.layer_norm or cfg.attn_bias \
+            or cfg.diff_attn:
+        _sambay_init(cfg, shapes, out, jax.random.fold_in(key, 3))
     return jax.tree_util.tree_map(lambda x: x.astype(cfg.compute_dtype), out)
+
+
+def _sambay_init(cfg: HybridConfig, shapes: Params, out: Params,
+                 key) -> None:
+    """The phi4flash leaves from their own stream: matrices Xavier-normal,
+    norm and bias vectors and lambda vectors normal(0, 0.1) about 1 (a
+    scale) or 0, ``A_log = log(1 .. N)`` a channel, ``dt_bias`` the inverse
+    softplus of a step log-uniform in [1e-3, 1e-1], ``D = 1``, the conv
+    uniform in +-1/sqrt(width)."""
+    ks = iter(jax.random.split(key, 64))
+    f32 = jnp.float32
+    near = lambda shape, mean: mean + 0.1 * jax.random.normal(
+        next(ks), shape, f32)
+
+    def leaf(name, shape):
+        if name.startswith("W"):
+            std = np.sqrt(2.0 / (shape[-2] + shape[-1]))
+            return jax.random.normal(next(ks), shape, f32) * np.float32(std)
+        if name in ("norm1", "norm2", "sub"):
+            return near(shape, 1.0)
+        if name == "A_log":
+            return jnp.broadcast_to(jnp.log(jnp.arange(
+                1, shape[1] + 1, dtype=f32))[None, :, None], shape)
+        if name == "D":
+            return jnp.ones(shape, f32)
+        if name == "dt_bias":
+            dt = jnp.exp(jax.random.uniform(next(ks), shape, f32,
+                                            np.log(1e-3), np.log(1e-1)))
+            return dt + jnp.log(-jnp.expm1(-dt))
+        if name == "conv_w":
+            bound = 1.0 / np.sqrt(shape[1])
+            return jax.random.uniform(next(ks), shape, f32, -bound, bound)
+        return near(shape, 0.0)
+
+    for kind in ("attn", "mlp", "selective", "cross", "gmu"):
+        if kind in shapes:
+            stack = out.setdefault(kind, {})
+            for name, shape in shapes[kind].items():
+                if name not in stack:
+                    stack[name] = leaf(name, shape)
+    if "norm_f_b" in shapes:
+        out["norm_f_b"] = near(shapes["norm_f_b"], 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -708,8 +996,14 @@ def _rms(x, g, eps):
         * _f32(g)
 
 
-def _norm(x, g, cfg: HybridConfig):
-    """The model's RMSNorm: scale ``w``, or ``1 + w`` (``norm_offset``)."""
+def _norm(x, g, cfg: HybridConfig, b=None):
+    """The model's norm: RMSNorm of scale ``w``, or ``1 + w``
+    (``norm_offset``); with ``layer_norm`` LayerNorm, ``(x - mean) /
+    sqrt(var + eps) * w + b``."""
+    if cfg.layer_norm:
+        c = x - jnp.mean(x, axis=-1, keepdims=True)
+        return c * lax.rsqrt(jnp.mean(c * c, axis=-1, keepdims=True)
+                             + cfg.rms_eps) * _f32(g) + _f32(b)
     if cfg.norm_offset:
         return x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
                              + cfg.rms_eps) * (1.0 + _f32(g))
@@ -732,7 +1026,7 @@ def _layer(tree, i):
 
 
 def _mlp(h, fp, cfg: HybridConfig):
-    gv = _mm(_norm(h, fp["norm2"], cfg), fp["W_up"])
+    gv = _mm(_norm(h, fp["norm2"], cfg, fp.get("norm2_b")), fp["W_up"])
     g, v = gv[..., :cfg.d_ff], gv[..., cfg.d_ff:]
     return h + cfg.residual_multiplier * _mm(ACTS[cfg.ffn_act](g) * v,
                                              fp["W_down"])
@@ -1045,9 +1339,8 @@ def _qkv(u, ap, cfg: HybridConfig, j: int = 0, pos=None):
         q, gate = qg[..., :cfg.head_dim], \
             qg[..., cfg.head_dim:].reshape(n, cfg.q_dim)
     else:
-        q = _mm(u, ap["Wq"]).reshape(n, cfg.n_heads, cfg.head_dim)
-    k = _mm(u, ap["Wk"]).reshape(n, cfg.n_kv_heads, cfg.head_dim)
-    v = _mm(u, ap["Wv"]).reshape(n, cfg.n_kv_heads, cfg.head_dim)
+        q = _q(u, ap, cfg)
+    k, v = _kv(u, ap, cfg)
     if cfg.qk_norm:
         q, k = _norm(q, ap["q_norm"], cfg), _norm(k, ap["k_norm"], cfg)
     if cfg.rope_of(j):
@@ -1056,12 +1349,138 @@ def _qkv(u, ap, cfg: HybridConfig, j: int = 0, pos=None):
     return q, k, v, gate
 
 
+def _proj(u, w, b):
+    """u W (+ b where the model has attention biases)."""
+    out = _mm(u, w)
+    return out if b is None else out + _f32(b)
+
+
+def _q(u, ap, cfg: HybridConfig):
+    return _proj(u, ap["Wq"], ap.get("bq")).reshape(
+        u.shape[0], cfg.n_heads, cfg.head_dim)
+
+
+def _kv(u, ap, cfg: HybridConfig):
+    n = u.shape[0]
+    k = _proj(u, ap["Wk"], ap.get("bk")).reshape(n, cfg.n_kv_heads,
+                                                 cfg.head_dim)
+    v = _proj(u, ap["Wv"], ap.get("bv")).reshape(n, cfg.n_kv_heads,
+                                                 cfg.head_dim)
+    return k, v
+
+
 def _attn_out(att, gate, ap):
     """W_o over the heads' output [n, H * hd], under the gate's sigmoid
-    where the layer has one."""
+    where the layer has one (and its bias where it has one)."""
     if gate is not None:
         att = _f32(att) * jax.nn.sigmoid(gate)
-    return _mm(att, ap["Wo"])
+    return _proj(att, ap["Wo"], ap.get("bo"))
+
+
+def _pair_rows(q):
+    """Differential attention's queries as the kernels read them: q [n, H,
+    hd], heads 2i and 2i+1 a pair, -> [n, H, 2 hd], row 2i ``[q_2i | 0]``
+    and row 2i+1 ``[0 | q_2i+1]``: against a KV pair's row ``[k_2j |
+    k_2j+1]`` each scores its own map, and each reads both values."""
+    n, h, hd = q.shape
+    q = q.reshape(n, h // 2, 2, hd)
+    zero = jnp.zeros_like(q[:, :, 0])
+    return jnp.stack([jnp.concatenate([q[:, :, 0], zero], -1),
+                      jnp.concatenate([zero, q[:, :, 1]], -1)],
+                     axis=2).reshape(n, h, 2 * hd)
+
+
+def _diff_out(att, ap, cfg: HybridConfig, g: int):
+    """The pairs' two maps' outputs att [n, H, 2 hd] float32 -> [n, H * hd]:
+    ``RMSNorm(a1 - lambda a2) * (1 - lambda_init)`` a pair, with ``lambda =
+    exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init`` of layer g."""
+    n, h, w = att.shape
+    a = att.reshape(n, h // 2, 2, w)
+    init = np.float32(cfg.lambda_init(g))
+    dot = lambda x, y: jnp.sum(_f32(ap[x]) * _f32(ap[y]))
+    lam = jnp.exp(dot("lq1", "lk1")) - jnp.exp(dot("lq2", "lk2")) + init
+    o = _rms(a[:, :, 0] - lam * a[:, :, 1], ap["sub"], cfg.rms_eps)
+    return (o * (1.0 - init)).reshape(n, h // 2 * w)
+
+
+def _selective_in(u, sp, cfg: HybridConfig):
+    """Mamba-1's in-projection of rows u [n, d]: (x [n, inner] in the
+    compute dtype, what the conv and its stored tail read; z [n, inner])."""
+    xz = _mm(u, sp["W_in"])
+    di = cfg.sel_inner
+    return xz[..., :di].astype(cfg.compute_dtype), xz[..., di:]
+
+
+def _selective_proj(xc, sp, cfg: HybridConfig):
+    """After the conv and silu, xc [n, inner] float32: (delta [n, inner] =
+    softplus(W_dt dt + dt_bias), B [n, N], C [n, N]) from ``[dt | B | C] =
+    W_x xc``."""
+    r, n = cfg.sel_dt_rank, cfg.sel_state
+    dbc = _mm(xc, sp["W_x"])
+    delta = jax.nn.softplus(_mm(dbc[:, :r], sp["W_dt"])
+                            + _f32(sp["dt_bias"]))
+    return delta, dbc[:, r:r + n], dbc[:, r + n:]
+
+
+def _selective_out(y, xc, z, sp):
+    """y + D x, gated by silu(z): the memory; and W_out of it."""
+    y = (y + _f32(sp["D"]) * xc) * jax.nn.silu(z)
+    return _mm(y, sp["W_out"]), y
+
+
+def selective_step(u, state, tail, sp, cfg: HybridConfig):
+    """One step of Mamba-1 for every lane: u [S, d] (normalised), state
+    [S, N, inner] float32, tail [S, K-1, inner] -> (out [S, d], state, tail,
+    the gated y [S, inner]). ``S_t = exp(delta_t A) S + (delta_t x_t) B_t``
+    a channel and state entry, ``y_t = C_t S_t``: elementwise float32."""
+    x, z = _selective_in(u, sp, cfg)
+    with jax.named_scope("tick.selective_conv"):
+        win = jnp.concatenate([tail, x[:, None, :]], axis=1)   # [S, K, di]
+        xc = jax.nn.silu(jnp.sum(_f32(win) * _f32(sp["conv_w"])[None],
+                                 axis=1) + _f32(sp["conv_b"]))
+        tail = win[:, 1:]
+    delta, b, c = _selective_proj(xc, sp, cfg)
+    with jax.named_scope("tick.selective_step"):
+        a = -jnp.exp(_f32(sp["A_log"]))                         # [N, di]
+        state = jnp.exp(delta[:, None, :] * a[None]) * state \
+            + b[:, :, None] * (delta * xc)[:, None, :]
+        y = jnp.sum(state * c[:, :, None], axis=1)
+    out, y = _selective_out(y, xc, z, sp)
+    return out, state, tail, y
+
+
+def selective_chunked(u, n_state, sp, cfg: HybridConfig):
+    """Mamba-1 over one sequence: u [T, d] (normalised), ``n_state``
+    (traced) the positions that feed the state. Returns (out [T, d], state
+    [N, inner] and tail [K-1, inner] as of position n_state - 1, the gated
+    y [T, inner]). A position at or past ``n_state`` carries delta = 0: no
+    decay, no input. The scan walks time in chunks of ``sel_chunk`` with
+    the state carried between them (ops/pallas_selective.selective_scan:
+    a Pallas kernel over channel tiles on the chip)."""
+    from deeplearning4j_tpu.ops.pallas_selective import selective_scan
+
+    t, k1 = u.shape[0], cfg.sel_conv - 1
+    x, z = _selective_in(u, sp, cfg)
+    padded = jnp.concatenate([jnp.zeros((k1, cfg.sel_inner), x.dtype), x],
+                             axis=0)
+    w = _f32(sp["conv_w"])
+    xc = jax.nn.silu(sum(_f32(padded[i:i + t]) * w[i]
+                         for i in range(cfg.sel_conv)) + _f32(sp["conv_b"]))
+    # padded[i] is x[i - k1]: rows n_state - k1 .. n_state - 1
+    tail = lax.dynamic_slice_in_dim(padded, n_state, k1, axis=0)
+    delta, b, c = _selective_proj(xc, sp, cfg)
+    delta = jnp.where((jnp.arange(t) < n_state)[:, None], delta, 0.0)
+    with jax.named_scope("admit.selective_scan"):
+        y, state = selective_scan(delta, delta * xc, b, c,
+                                  -jnp.exp(_f32(sp["A_log"])),
+                                  chunk=cfg.sel_chunk)
+    out, y = _selective_out(y, xc, z, sp)
+    return out, state, tail, y
+
+
+def _gmu(u, m, gp):
+    """The gated memory unit: ``W_out (silu(W_in u) * m)``."""
+    return _mm(jax.nn.silu(_mm(u, gp["W_in"])) * m, gp["W_out"])
 
 
 # one pass's float32 scores [H, rows, keys] at the most, and the keys a pass
@@ -1085,7 +1504,8 @@ def _attend_tiles(n_heads: int, t: int) -> Tuple[int, int]:
     return rows, keys
 
 
-def attention_full(u, ap, cfg: HybridConfig, kv_dtype, j: int = 0):
+def attention_full(u, ap, cfg: HybridConfig, kv_dtype, j: int = 0,
+                   g: int = 0):
     """Causal grouped-query attention of attention layer j over one
     sequence u [T, d]; K and V are rounded to the arena's dtype first,
     which is what a later decode step will read. Returns (out [T, d], k, v
@@ -1104,20 +1524,38 @@ def attention_full(u, ap, cfg: HybridConfig, kv_dtype, j: int = 0):
     ``kernel`` the same arithmetic runs in one Pallas call
     (ops/pallas_prefill.py) that writes no score to HBM."""
     t = u.shape[0]
-    hk, grp, hd = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim
-    hi = lax.Precision.HIGHEST
     window = cfg.window_of(j)
     q, k, v, gate = _qkv(u, ap, cfg, j, jnp.arange(t))
     k, v = k.astype(kv_dtype), v.astype(kv_dtype)
+    att = attend_sequence(q, k, v, cfg, kv_dtype, window)
+    if cfg.diff_attn:
+        return _attn_out(_diff_out(_f32(att).reshape(t, cfg.n_heads, -1),
+                                   ap, cfg, g), gate, ap), k, v
+    return _attn_out(att, gate, ap), k, v
+
+
+def attend_sequence(q, k, v, cfg: HybridConfig, kv_dtype, window: int = 0):
+    """Causal grouped attention of one sequence: q [T, H, hd], k and v [T,
+    Hkv, hd] as stored (``kv_dtype``), scale ``attention_multiplier``, a
+    window where one is given -> att [T, H * hd'] (hd' the width the
+    kernels see: ``cfg.attend_shape``, a pair's with differential
+    attention, whose rows ``_pair_rows`` lays). The kernel where
+    ``cfg.admit_attend`` says so, else by blocks (``attention_full``)."""
+    t = q.shape[0]
+    hq, hk, hd = cfg.attend_shape
+    grp = hq // hk
+    hi = lax.Precision.HIGHEST
+    if cfg.diff_attn:
+        q = _pair_rows(q)
+        k, v = k.reshape(t, hk, hd), v.reshape(t, hk, hd)
     if cfg.admit_attend(t) == "kernel":
         from deeplearning4j_tpu.ops.pallas_prefill import prefill_attention
 
         with jax.named_scope("admit.attend"):
-            att = prefill_attention(
-                q.reshape(t, cfg.q_dim).astype(kv_dtype), k.reshape(t, -1),
+            return prefill_attention(
+                q.reshape(t, hq * hd).astype(kv_dtype), k.reshape(t, -1),
                 v.reshape(t, -1), head_dim=hd,
                 scale=cfg.attention_multiplier, window=window)
-        return _attn_out(att, gate, ap), k, v
     # a KV head's products: its g query heads' rows side by side against
     # its keys, [Hkv, g * rows, keys], the keys innermost (with the scores
     # laid "kgts" out of one einsum the chip's compiler put the rows
@@ -1173,7 +1611,7 @@ def attention_full(u, ap, cfg: HybridConfig, kv_dtype, j: int = 0):
     with jax.named_scope("admit.attend"):
         att = block(0) if rows == t else \
             lax.map(block, jnp.arange(0, t, rows))
-    return _attn_out(att.reshape(t, cfg.q_dim), gate, ap), k, v
+    return att.reshape(t, hq * hd)
 
 
 def _embed(params, tok, cfg: HybridConfig):
@@ -1181,7 +1619,7 @@ def _embed(params, tok, cfg: HybridConfig):
 
 
 def _head(params, h, cfg: HybridConfig):
-    x = _norm(h, params["norm_f"], cfg)
+    x = _norm(h, params["norm_f"], cfg, params.get("norm_f_b"))
     e = params["embed"] if cfg.tie_head else params["head"]
     return jnp.einsum("...d,vd->...v", x.astype(e.dtype), e,
                       precision=lax.Precision.HIGHEST,
@@ -1194,7 +1632,8 @@ def _head(params, h, cfg: HybridConfig):
 # ---------------------------------------------------------------------------
 
 
-def prefill(params, tokens, n_state, cfg: HybridConfig, kv_dtype=None):
+def prefill(params, tokens, n_state, cfg: HybridConfig, kv_dtype=None,
+            whole: bool = True):
     """One sequence tokens [T] through every layer. Returns the residual
     stream h [T, d], K and V [n_attention, T, Hkv, hd] in ``kv_dtype``, and
     by name the recurrent state as of position ``n_state - 1``: ``ssm``
@@ -1203,16 +1642,22 @@ def prefill(params, tokens, n_state, cfg: HybridConfig, kv_dtype=None):
     where there are such layers; with a share of the experts held also
     ``moe_pairs``, the (row, held expert) pairs of the prompt's rows.
 
-    A model of gated feed-forwards without GDN layers runs its Mamba
-    layers under a loop that reads each layer's weights out of the stacked
-    leaves by index; any other unrolls its layers, as the tick does (an
-    expert layer's matrices are buffers of their own)."""
+    Mamba-1 layers give ``selective`` [n_selective, N, inner] float32 and
+    ``selective_conv`` [n_selective, K-1, inner]. With cross-attention
+    layers and ``whole`` False the prefill stops at the last attention
+    layer's K and V (``cfg.admit_layers``): what the layers after it would
+    compute feeds no state, and h is then the stream before that layer.
+
+    A model of gated feed-forwards without GDN or Mamba-1 layers runs its
+    Mamba layers under a loop that reads each layer's weights out of the
+    stacked leaves by index; any other unrolls its layers, as the tick
+    does (an expert layer's matrices are buffers of their own)."""
     if kv_dtype is None:
         kv_dtype = cfg.compute_dtype
     t = tokens.shape[0]
-    if cfg.ffn == EXPERTS or cfg.n_linear:
+    if cfg.ffn == EXPERTS or cfg.n_linear or cfg.n_selective:
         h, ks, vs, state = _prefill_unrolled(params, tokens, n_state, cfg,
-                                             kv_dtype)
+                                             kv_dtype, whole)
     else:
         h, ks, vs, state = _prefill_runs(params, tokens, n_state, cfg,
                                          kv_dtype)
@@ -1261,16 +1706,19 @@ def _before_mixer(u, params, g, cfg: HybridConfig):
     return None if cfg.router_after_mixer else _router(u, params, g, cfg)
 
 
-def _prefill_unrolled(params, tokens, n_state, cfg: HybridConfig, kv_dtype):
+def _prefill_unrolled(params, tokens, n_state, cfg: HybridConfig, kv_dtype,
+                      whole: bool = True):
     """`prefill` a layer at a time: (h, K list, V list, state by name)."""
     t = tokens.shape[0]
     r = cfg.residual_multiplier
     h = _embed(params, tokens, cfg)
     live = jnp.arange(t) <= n_state if cfg.moe_share else None
-    seen = {MAMBA: 0, ATTENTION: 0, LINEAR: 0}
+    seen = dict.fromkeys(KINDS, 0)
     ks, vs, kept = [], [], {"ssm": [], "conv": [], "gdn": [],
-                            "gdn_conv": []}
+                            "gdn_conv": [], "selective": [],
+                            "selective_conv": []}
     pairs = jnp.zeros((), jnp.int32)
+    memory = None
     for g, kind in enumerate(cfg.layer_types):
         j = seen[kind]
         seen[kind] += 1
@@ -1289,11 +1737,41 @@ def _prefill_unrolled(params, tokens, n_state, cfg: HybridConfig, kv_dtype):
             out, s, tail = gdn_chunked(u, n_state, lp, cfg)
             kept["gdn"].append(s)
             kept["gdn_conv"].append(tail)
+        elif kind == SELECTIVE:
+            sp = _layer(params["selective"], j)
+            u = _norm(h, sp["norm1"], cfg, sp.get("norm1_b"))
+            logits = None
+            out, s, tail, y = selective_chunked(u, n_state, sp, cfg)
+            if g == cfg.memory_layer:
+                memory = y
+            kept["selective"].append(s)
+            kept["selective_conv"].append(tail)
+        elif kind == GMU:
+            gp = _layer(params["gmu"], j)
+            logits = None
+            out = _gmu(_norm(h, gp["norm1"], cfg, gp.get("norm1_b")),
+                       memory, gp)
+        elif kind == CROSS:
+            cp = _layer(params["cross"], j)
+            u = _norm(h, cp["norm1"], cfg, cp.get("norm1_b"))
+            logits = None
+            att = attend_sequence(_q(u, cp, cfg), ks[-1], vs[-1], cfg,
+                                  kv_dtype)
+            out = _attn_out(_diff_out(_f32(att).reshape(t, cfg.n_heads, -1),
+                                      cp, cfg, g), None, cp)
         else:
             ap = _layer(params["attn"], j)
-            u = _norm(h, ap["norm1"], cfg)
+            u = _norm(h, ap["norm1"], cfg, ap.get("norm1_b"))
             logits = _before_mixer(u, params, g, cfg)
-            out, k, v = attention_full(u, ap, cfg, kv_dtype, j)
+            if g == cfg.admit_layers and not whole:
+                # the admission of a model whose later layers read this
+                # one's keys and values: they are all it needs of it
+                with jax.named_scope("admit.yoco_kv"):
+                    k, v = _kv(u, ap, cfg)
+                ks.append(k.astype(kv_dtype))
+                vs.append(v.astype(kv_dtype))
+                break
+            out, k, v = attention_full(u, ap, cfg, kv_dtype, j, g)
             ks.append(k)
             vs.append(v)
         h, _, held = _ffn(h + r * out, logits, params, g, cfg, "admit.moe",
@@ -1343,7 +1821,11 @@ def paged_decode_step(params, arena, tok, pos, tables, cfg: HybridConfig):
     (arena, logits, hit); where only a share of the experts is held, also
     how many (layer, live lane, held expert) triples the routing made:
     (arena, logits, hit, pairs). GDN layers advance every lane's ``gdn``
-    and ``gdn_conv`` as Mamba layers do theirs."""
+    and ``gdn_conv`` as Mamba layers do theirs, Mamba-1 layers their
+    ``selective`` and ``selective_conv``. A cross-attention layer reads the
+    last attention layer's buffers (written by it earlier in the same
+    tick) through that layer's table; a GMU layer gates the memory layer's
+    output of the same token."""
     from deeplearning4j_tpu.ops.pallas_paged import decode_attention
 
     s = tok.shape[0]
@@ -1361,13 +1843,39 @@ def paged_decode_step(params, arena, tok, pos, tables, cfg: HybridConfig):
     hits = jnp.zeros((), jnp.int32)
     pairs = jnp.zeros((), jnp.int32)
     ak, av = list(arena["k"]), list(arena["v"])
-    state = {name: list(arena.get(name, ()))
-             for name in ("ssm", "conv", "gdn", "gdn_conv")}
-    seen = {MAMBA: 0, ATTENTION: 0, LINEAR: 0}
+    state = {name: list(arena.get(name, ())) for name in STATE_LEAVES}
+    seen = dict.fromkeys(KINDS, 0)
+    memory = None
     for g, kind in enumerate(cfg.layer_types):
         j = seen[kind]
         seen[kind] += 1
-        if kind == MAMBA:
+        if kind == SELECTIVE:
+            sp = _layer(params["selective"], j)
+            u = _norm(h, sp["norm1"], cfg, sp.get("norm1_b"))
+            logits = None
+            out, state["selective"][j], state["selective_conv"][j], y = \
+                selective_step(u, state["selective"][j],
+                               state["selective_conv"][j], sp, cfg)
+            if g == cfg.memory_layer:
+                memory = y
+        elif kind == GMU:
+            gp = _layer(params["gmu"], j)
+            logits = None
+            with jax.named_scope("tick.gmu"):
+                out = _gmu(_norm(h, gp["norm1"], cfg, gp.get("norm1_b")),
+                           memory, gp)
+        elif kind == CROSS:
+            cp = _layer(params["cross"], j)
+            src = cfg.n_attention - 1
+            gi = group_of[src]
+            u = _norm(h, cp["norm1"], cfg, cp.get("norm1_b"))
+            logits = None
+            with jax.named_scope("tick.cross_attn"):
+                att = decode_attention(
+                    _pair_rows(_q(u, cp, cfg)), ak[src], av[src],
+                    by_group[gi], pos, scale=cfg.attention_multiplier)
+                out = _attn_out(_diff_out(att, cp, cfg, g), None, cp)
+        elif kind == MAMBA:
             mp = _layer(params["mamba"], j)
             u = _norm(h, mp["norm1"], cfg)
             logits = _before_mixer(u, params, g, cfg)
@@ -1381,7 +1889,7 @@ def paged_decode_step(params, arena, tok, pos, tables, cfg: HybridConfig):
                 u, state["gdn"][j], state["gdn_conv"][j], lp, cfg)
         else:
             ap = _layer(params["attn"], j)
-            u = _norm(h, ap["norm1"], cfg)
+            u = _norm(h, ap["norm1"], cfg, ap.get("norm1_b"))
             logits = _before_mixer(u, params, g, cfg)
             q, k1, v1, gate = _qkv(u, ap, cfg, j, pos)
             gi, window = group_of[j], cfg.window_of(j)
@@ -1390,11 +1898,18 @@ def paged_decode_step(params, arena, tok, pos, tables, cfg: HybridConfig):
                     k1.reshape(s, -1).astype(ak[j].dtype))
                 av[j] = av[j].at[wbs[gi], off].set(
                     v1.reshape(s, -1).astype(av[j].dtype))
-            att = decode_attention(
-                q, ak[j], av[j], by_group[gi], pos,
-                scale=cfg.attention_multiplier,
-                lo=jnp.maximum(pos - (window - 1), 0) if window else None)
-            out = _attn_out(att.reshape(s, cfg.q_dim), gate, ap)
+            lo = jnp.maximum(pos - (window - 1), 0) if window else None
+            if cfg.diff_attn:
+                with jax.named_scope("tick.diff_attn"):
+                    att = decode_attention(
+                        _pair_rows(q), ak[j], av[j], by_group[gi], pos,
+                        scale=cfg.attention_multiplier, lo=lo)
+                    out = _attn_out(_diff_out(att, ap, cfg, g), gate, ap)
+            else:
+                att = decode_attention(
+                    q, ak[j], av[j], by_group[gi], pos,
+                    scale=cfg.attention_multiplier, lo=lo)
+                out = _attn_out(att.reshape(s, cfg.q_dim), gate, ap)
         h, hit, held = _ffn(h + r * out, logits, params, g, cfg, "tick.moe",
                             live)
         if hit is not None:
@@ -1413,7 +1928,7 @@ def paged_decode_step(params, arena, tok, pos, tables, cfg: HybridConfig):
 
 
 def paged_admit(params, arena, window, write_table, lane,
-                cfg: HybridConfig):
+                cfg: HybridConfig, whole: bool = False):
     """The batch-1 admission prefill at a bucket width: window [1, T],
     write_table [m] (shared and beyond-prompt entries point at trash block
     0), lane int32 [2] = (the lane's index, n_state). Scatters the
@@ -1426,6 +1941,10 @@ def paged_admit(params, arena, window, write_table, lane,
     first tick can see: the host gave the group blocks for those alone);
     every other block of the bucket is left where it was computed.
 
+    A model with cross-attention layers stops at the last attention
+    layer's K and V (``cfg.admit_layers``; ``whole`` runs every layer, as a
+    check that the result is the same).
+
     Where only a share of the experts is held it returns (arena, the
     prompt's (row, held expert) pairs: one int32), else the arena."""
     t = window.shape[1]
@@ -1433,7 +1952,7 @@ def paged_admit(params, arena, window, write_table, lane,
     nb = -(-t // bt)
     with jax.named_scope("admit.prefill"):
         _, ks, vs, state = prefill(params, window[0], lane[1], cfg,
-                                   arena["k"][0].dtype)
+                                   arena["k"][0].dtype, whole)
     groups = cfg.kv_groups()
     tables = write_table[None] if write_table.ndim == 1 else write_table
     with jax.named_scope("admit.scatter"):
@@ -1454,7 +1973,7 @@ def paged_admit(params, arena, window, write_table, lane,
                 ak[j] = ak[j].at[cols].set(cut(kb[j], 0))
                 av[j] = av[j].at[cols].set(cut(vb[j], 0))
         out = {"k": tuple(ak), "v": tuple(av)}
-        for name in ("ssm", "conv", "gdn", "gdn_conv"):
+        for name in STATE_LEAVES:
             if name in arena:
                 out[name] = tuple(buf.at[lane[0]].set(state[name][j])
                                   for j, buf in enumerate(arena[name]))

@@ -358,9 +358,13 @@ class KVGroup:
     ``window``, how many of the newest positions a layer of the group sees,
     the token's own among them (0: every earlier position). A group has a
     block pool and a per-lane table of its own in the paged decoder; a lane
-    of a window group holds only the blocks its window reaches."""
+    of a window group holds only the blocks its window reaches.
+    ``readers`` is how many layers read each of the group's buffers: the
+    layer that writes it, and layers that only read another layer's keys
+    and values (cross-attention: one buffer, written once, read by all)."""
     layer_ids: Tuple[int, ...]
     window: int = 0
+    readers: int = 1
 
     @property
     def layers(self) -> int:

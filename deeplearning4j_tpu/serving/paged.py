@@ -1329,24 +1329,29 @@ class PagedDecoder:
             "kv_live": int(sum((pos + 1 + j).sum() for j in range(k))),
             "kv_read": sum(reads(every + j) for j in range(k))}
         if self.needs.windowed:
-            # over the groups, weighted by their layers and divided by the
-            # KV layers: a window layer's lane sees min(pos + 1, window)
-            # positions and reads from its own first chunk or block (k is 1)
+            # over the groups, weighted by the layers that read them and
+            # divided by the KV layers: a window layer's lane sees
+            # min(pos + 1, window) positions and reads from its own first
+            # chunk or block (k is 1); a buffer that several layers read
+            # (``readers``) is read that many times
             live = read = 0
             for g in self.needs.groups:
+                reads = g.layers * g.readers
                 if g.window:
-                    live += g.layers * int(
+                    live += reads * int(
                         np.minimum(pos + 1, g.window).sum())
-                    read += g.layers * (kv_read_tokens_own(
+                    read += reads * (kv_read_tokens_own(
                         every, g.window, self.block_tokens) if own
                         else self.lanes * kv_read_tokens_window(
                             every, g.window, self.block_tokens,
                             self.table_width))
                 else:
-                    live += g.layers * counts["kv_live"]
-                    read += g.layers * counts["kv_read"]
-            counts = {"kv_live": live // self.needs.kv_layers,
-                      "kv_read": read // self.needs.kv_layers}
+                    live += reads * counts["kv_live"]
+                    read += reads * counts["kv_read"]
+                if g.readers > 1:
+                    counts["shared_kv_live"] = reads * counts["kv_live"]
+            counts.update(kv_live=live // self.needs.kv_layers,
+                          kv_read=read // self.needs.kv_layers)
         counts["attend"] = self.attend
         if self._moe_rows:
             counts["moe_rows"] = len(active) * k * self._moe_rows
@@ -1357,7 +1362,7 @@ class PagedDecoder:
             # (the leaf of that name alone: the conv tail beside it is a
             # hundredth of it, and the reader of these bytes times the
             # events of that leaf's shape)
-            if leaf.name in ("ssm", "gdn"):
+            if leaf.name in ("ssm", "gdn", "selective"):
                 lanes = len(active) * k
                 counts[leaf.name + "_lanes"] = lanes
                 counts[leaf.name + "_state_bytes"] = \
@@ -1879,6 +1884,11 @@ class PagedDecoder:
                     sp.set_attr(key, value)
                 if self.needs.state:
                     sp.set_attr("scan_chunks", self.cfg.scan_chunks(width))
+                if getattr(self.cfg, "admit_layers",
+                           self.cfg.n_layers) < self.cfg.n_layers:
+                    # the layers an admission that stops early runs whole
+                    # (models/hybrid.HybridConfig.admit_layers)
+                    sp.set_attr("admit_layers", self.cfg.admit_layers)
                 if hasattr(self.cfg, "admit_attend"):
                     sp.set_attr("attend", self.cfg.admit_attend(width))
                 if self._moe_rows:

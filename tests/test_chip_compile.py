@@ -837,6 +837,262 @@ def test_granite_admission_lowers_as_before(monkeypatch):
     assert hashlib.sha256(text.encode()).hexdigest() == GRANITE_ADMIT_512
 
 
+# the other hybrid cells' tick and admission at their published widths,
+# lanes and arenas, lowered as at the parent of the PR that added the
+# SambaY model (its mixers, LayerNorm and biases are the block's data, and
+# none of them may reach another configuration's programs): on the CPU
+# backend (the XLA paths), and for a described v5e with Pallas on (the
+# kernels) with each kernel's serialized body left out, which carries the
+# checkout's file paths. A later change to one of these programs updates
+# its digest, saying why.
+LOWERED = {
+    ("granite", "tick"):
+        "cf34a4d890634ef3c5aa2c8f8ee85f5d90dbe07650f8a59f29dbfaf670f5f82e",
+    ("smallthinker", "tick"):
+        "1793517ec027d5f820f2f0989d7c2c8c54a56bf42a705ee3ad1f714be057a4f3",
+    ("smallthinker", "admit"):
+        "a2dee232e2444a436dd67b64847cbf72f24624ff81269c245c1a8cc731a674db",
+    ("qwen3-next", "tick"):
+        "2c3634428681b31659780147ffc3b262d230d80cfa5c07bbba6649d0a4155c88",
+    ("qwen3-next", "admit"):
+        "6a90dba58f244c25757ac70450917da006b1d4a6628d4c54ed2f84530c4d7838",
+}
+LOWERED_V5E = {
+    ("smallthinker", "tick"):
+        "dfbd339432f793ed5bb67e5649e5bd8978116ce78f0116022662b3adcb81b484",
+    ("smallthinker", "admit"):
+        "3644dd443858ebafc3896730c21f8094925f77d70f7e3338282c7dd295a325fd",
+    ("qwen3-next", "tick"):
+        "956dd1d2883cfe53d9ba2e175385170d3f83479bb8b44e5ed67016d4fff88db5",
+    ("qwen3-next", "admit"):
+        "421a661185fe5a7cc38beb4151165743d3e727eaa67ef988f7fa3b3352c366a9",
+}
+# (configuration file, served context, lanes, blocks, admission width)
+HYBRID_CELLS = {
+    "granite": ("granite-4.0-h-micro.json", 2048, 64, 4096, 512),
+    "smallthinker": ("smallthinker-21b-a3b-stage12.json", 8192, 48, 6144,
+                     1024),
+    "qwen3-next": ("qwen3-next-80b-a3b-stage8-ep4.json", 16384, 64, 16384,
+                   1024),
+}
+
+
+def _lowered(cell, program, sharding=None):
+    """The cell's tick or admission lowered at its published widths: its
+    text, each Pallas kernel's body replaced by a mark."""
+    from deeplearning4j_tpu.models import hybrid
+    from deeplearning4j_tpu.ops import memory as opsmem
+    from deeplearning4j_tpu.serving import paged
+    from perfbench import harness
+
+    f, max_len, lanes, blocks, width = HYBRID_CELLS[cell]
+    conf = harness.load_json(os.path.join(harness.HERE, "configs", f))
+    cfg = hybrid.HybridConfig.from_published(conf, max_len=max_len)
+    bt = 16
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=sharding)
+    params = jax.tree.map(
+        lambda s: sd(s, jnp.bfloat16), hybrid.param_shapes(cfg),
+        is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[0], int))
+    needs = opsmem.cache_needs(cfg)
+    kv = [None] * needs.kv_layers
+    for g, n in zip(needs.groups,
+                    opsmem.kv_group_blocks(needs, blocks, bt, lanes)):
+        for j in g.layer_ids:
+            kv[j] = sd((n + 1, bt, needs.kv_heads * needs.head_dim),
+                       jnp.bfloat16)
+    arena = {"k": tuple(kv), "v": tuple(kv)}
+    for leaf in needs.state:
+        arena[leaf.name] = tuple(sd((lanes,) + leaf.shape, leaf.dtype)
+                                 for _ in range(leaf.layers))
+    i32 = lambda *shape: sd(shape, jnp.int32)
+    groups = (len(needs.groups),) if len(needs.groups) > 1 else ()
+    with jax.enable_x64(False):
+        if program == "tick":
+            text = paged._paged_tick_for(cfg, bt).lower(
+                params, arena, i32(lanes), i32(lanes),
+                i32(*groups, lanes, max_len // bt),
+                sd((lanes, 2), jnp.uint32),
+                sd((lanes,), jnp.float32)).as_text()
+            paged._PAGED_TICK_CACHE.pop((cfg, bt, 1), None)
+        else:
+            text = paged._paged_admit_for(cfg, width, bt).lower(
+                params, arena, i32(1, width), i32(*groups, max_len // bt),
+                i32(2)).as_text()
+            paged._PAGED_ADMIT_CACHE.pop((cfg, width, bt), None)
+    return re.sub(r'\\22body\\22: \\22[A-Za-z0-9+/=]+\\22', "BODY",
+                  text)
+
+
+@pytest.mark.parametrize("cell, program", sorted(LOWERED))
+def test_the_other_hybrid_cells_lower_as_before(cell, program):
+    import hashlib
+
+    text = _lowered(cell, program)
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == LOWERED[(cell, program)]
+
+
+@pytest.mark.parametrize("cell, program", sorted(LOWERED_V5E))
+def test_the_other_hybrid_cells_lower_as_before_on_v5e(
+        cell, program, one_chip, monkeypatch):
+    import hashlib
+
+    from deeplearning4j_tpu.ops import device
+
+    monkeypatch.setenv("DL4J_TPU_PALLAS", "force")
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    text = _lowered(cell, program, one_chip)
+    assert "tpu_custom_call" in text
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == LOWERED_V5E[(cell, program)]
+
+
+def _phi4flash(one_chip, lanes=64, n_blocks=32768, bt=16):
+    """The SambaY cell's whole model at its published widths (32 layers,
+    d 2560, 40 heads over 20 KV heads of 64, Mamba-1 inner 5,120 state 16,
+    vocabulary 200,064) with its arena (the shared pool of 32,768 blocks,
+    the window pool the decoder derives for 64 lanes, 64 lanes of Mamba-1
+    state): (cfg, params, arena, arg) as shapes on the described chip."""
+    from deeplearning4j_tpu.models import hybrid
+    from deeplearning4j_tpu.ops import memory as opsmem
+    from perfbench import harness
+
+    conf = harness.load_json(os.path.join(
+        harness.HERE, "configs", "phi-4-mini-flash-reasoning.json"))
+    cfg = hybrid.HybridConfig.from_published(conf, max_len=16384)
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    params = jax.tree.map(
+        lambda s: arg(s, jnp.bfloat16), hybrid.param_shapes(cfg),
+        is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[0], int))
+    needs = opsmem.cache_needs(cfg)
+    kv = [None] * needs.kv_layers
+    for g, n in zip(needs.groups,
+                    opsmem.kv_group_blocks(needs, n_blocks, bt, lanes)):
+        for j in g.layer_ids:
+            kv[j] = arg((n + 1, bt, needs.kv_heads * needs.head_dim),
+                        jnp.bfloat16)
+    arena = {"k": tuple(kv), "v": tuple(kv)}
+    for leaf in needs.state:
+        arena[leaf.name] = tuple(arg((lanes,) + leaf.shape, leaf.dtype)
+                                 for _ in range(leaf.layers))
+    return cfg, params, arena, arg
+
+
+def test_phi4flash_tick_reads_every_kv_buffer_in_the_kernel_on_v5e(
+        one_chip, no_compile_cache, monkeypatch):
+    """The SambaY tick at 64 lanes compiles for a v5e beside its 7.71 GB of
+    weights and its 4.3 GB arena, donated and aliased whole: the window
+    layers, the full layer and the cross-decoder's layers all read their
+    keys and values in the attention kernel (16 calls: 8 window buffers, and
+    the shared buffer 8 times), at 40 query rows of 128 over 10 KV pairs,
+    and its temporaries stay under 0.1 GB."""
+    from deeplearning4j_tpu.ops import device
+    from deeplearning4j_tpu.serving import paged
+
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    monkeypatch.setattr(paged, "_PAGED_TICK_CACHE", {})
+    cfg, params, arena, arg = _phi4flash(one_chip)
+    lanes, bt = 64, 16
+    assert _tree_bytes(params) == 2 * 3_852_562_944
+    with jax.enable_x64(False):
+        tick = paged._paged_tick_for(cfg, bt).lower(
+            params, arena, arg((lanes,), jnp.int32), arg((lanes,), jnp.int32),
+            arg((2, lanes, cfg.max_len // bt), jnp.int32),
+            arg((lanes, 2), jnp.uint32), arg((lanes,), jnp.float32)).compile()
+    mem = tick.memory_analysis()
+    assert mem.alias_size_in_bytes >= _tree_bytes(arena) > 4.0e9
+    assert mem.temp_size_in_bytes < 0.1e9
+    calls = [ln for ln in tick.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 16 and all(
+        re.match(r"\s*(?:ROOT )?%decode_attn(?:\.\d+)? = ", ln)
+        for ln in calls), calls
+    assert all(f"f32[{lanes},40,128]" in ln for ln in calls)
+    shared = [ln for ln in calls if "bf16[32769,16,1280]" in ln]
+    assert len(shared) == 8
+
+
+def test_phi4flash_admission_at_16384_fits_beside_the_arena_on_v5e(
+        one_chip, no_compile_cache, monkeypatch):
+    """The widest admission (16,384 positions) compiles for a v5e: its
+    window layers attend in the Pallas kernel at 4 query rows of 128 a KV
+    pair, its 9 Mamba-1 layers scan in the selective-scan kernel, it stops
+    at layer 17's K and V (no kernel call of a full layer, no GMU), and the
+    weights, the arena and the program's temporaries together stay under
+    the chip's 16.9 GB with a gigabyte to spare."""
+    from deeplearning4j_tpu.ops import device
+    from deeplearning4j_tpu.serving import paged
+
+    monkeypatch.setenv("DL4J_TPU_PALLAS", "force")
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    monkeypatch.setattr(paged, "_PAGED_ADMIT_CACHE", {})
+    cfg, params, arena, arg = _phi4flash(one_chip)
+    bt, width = 16, 16384
+    assert cfg.admit_attend(width) == "kernel"
+    with jax.enable_x64(False):
+        admit = paged._paged_admit_for(cfg, width, bt).lower(
+            params, arena, arg((1, width), jnp.int32),
+            arg((2, cfg.max_len // bt), jnp.int32),
+            arg((2,), jnp.int32)).compile()
+    mem = admit.memory_analysis()
+    assert mem.alias_size_in_bytes >= _tree_bytes(arena)
+    assert _tree_bytes(params) + _tree_bytes(arena) \
+        + mem.temp_size_in_bytes < 15.9e9, mem.temp_size_in_bytes
+    names = [m.group(1) for m in (
+        re.match(r"\s*(?:ROOT )?%(\w+?)(?:\.\d+)? = ", ln)
+        for ln in admit.as_text().splitlines()
+        if 'custom_call_target="tpu_custom_call"' in ln) if m]
+    assert sorted(set(names)) == ["prefill_attn_w512", "selective_scan"]
+    assert names.count("prefill_attn_w512") == 8
+    assert names.count("selective_scan") == 9
+
+
+def test_phi4flash_tick_and_admit_hold_the_new_scopes():
+    """A tiny SambaY model: the tick's and the admission's lowered text
+    holds the Mamba-1, differential, cross-attention and GMU scopes beside
+    every tick's, and the admission's early stop its own."""
+    from deeplearning4j_tpu.models import hybrid
+    from deeplearning4j_tpu.ops import memory as opsmem
+    from deeplearning4j_tpu.serving import paged
+    from perfbench import harness
+
+    conf = harness.load_json(os.path.join(
+        harness.HERE, "tests", "data_sambay", "configs", "tiny-sambay.json"))
+    cfg = hybrid.HybridConfig.from_published(conf, max_len=32,
+                                             dtype_policy="strict")
+    bt, lanes, m = 4, 3, 8
+    params = jax.eval_shape(
+        lambda: hybrid.init_params(cfg, jax.random.PRNGKey(0)))
+    needs = opsmem.cache_needs(cfg)
+    layer = lambda n: jax.ShapeDtypeStruct((n + 1, bt, 32), jnp.float32)
+    kv = (layer(9), layer(9), layer(16))
+    arena = {"k": kv, "v": kv}
+    for leaf in needs.state:
+        arena[leaf.name] = tuple(
+            jax.ShapeDtypeStruct((lanes,) + leaf.shape, jnp.float32)
+            for _ in range(leaf.layers))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    tick = paged._paged_tick_for(cfg, bt).lower(
+        params, arena, i32(lanes), i32(lanes), i32(2, lanes, m),
+        jax.ShapeDtypeStruct((lanes, 2), jnp.uint32),
+        jax.ShapeDtypeStruct((lanes,), jnp.float32)).as_text(debug_info=True)
+    for scope in TICK_SCOPES + ("tick.selective_conv", "tick.selective_step",
+                                "tick.diff_attn", "tick.cross_attn",
+                                "tick.gmu"):
+        assert _has_scope(tick, scope), scope
+    admit = paged._paged_admit_for(cfg, 16, bt).lower(
+        params, arena, i32(1, 16), i32(2, m), i32(2)).as_text(
+        debug_info=True)
+    for scope in ("admit.prefill", "admit.scatter", "admit.attend",
+                  "admit.selective_scan", "admit.yoco_kv"):
+        assert _has_scope(admit, scope), scope
+    assert not _has_scope(admit, "tick.gmu")
+    paged._PAGED_TICK_CACHE.pop((cfg, bt, 1), None)
+    paged._PAGED_ADMIT_CACHE.pop((cfg, 16, bt), None)
+
+
 # ---------------------------------------------------------------------------
 # 2. the scopes, in the lowered programs
 # ---------------------------------------------------------------------------

@@ -23,7 +23,8 @@ from perfbench import harness, host_gap, run, trace_reduce
 BASE = os.path.join(harness.HERE, "tests", "data")
 SERVE_CELLS = ("serve-590m-chat", "serve-granite-h-micro-chat",
                "serve-smallthinker-stage12-mixed",
-               "serve-qwen3-next-stage8-longdoc")
+               "serve-qwen3-next-stage8-longdoc",
+               "serve-phi4-mini-flash-agent16k")
 IDLE = {"idle_after_dispatch.serve": host_gap.AFTER_DISPATCH,
         "idle_in_tick_upload.serve": "upload",
         "idle_in_tick_dispatch.serve": "dispatch",

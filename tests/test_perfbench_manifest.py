@@ -25,8 +25,8 @@ def manifest():
 
 def test_the_repos_manifest_is_sound(manifest):
     assert check_manifest.check(manifest) == []
-    assert len(manifest["workloads"]) == 6
-    assert [w["chips"] for w in manifest["workloads"]] == [1] * 6
+    assert len(manifest["workloads"]) == 7
+    assert [w["chips"] for w in manifest["workloads"]] == [1] * 7
 
 
 def test_every_per_layer_metric_has_a_reader(manifest):
@@ -132,8 +132,13 @@ GDN_CELL = "serve-qwen3-next-stage8-longdoc"
 GDN_CONFIG = "qwen3-next-80b-a3b-stage8-ep4"
 
 
+def _entry(manifest, group, name):
+    return next(e for e in manifest[group] if e["name"] == name)
+
+
 def test_the_gdn_cell_is_entered_as_its_configuration_states_it(manifest):
-    config, cell = manifest["configs"][-1], manifest["workloads"][-1]
+    config = _entry(manifest, "configs", GDN_CONFIG)
+    cell = _entry(manifest, "workloads", GDN_CELL)
     assert config["name"] == GDN_CONFIG
     assert config["reduced"] == ["n_layer", "num_experts", "vocab_size"]
     assert config["source"] == ("https://huggingface.co/Qwen/"
@@ -199,3 +204,78 @@ def test_the_gdn_configuration_holds_every_published_key_and_the_cut():
         == (32, 0, 0.5)
     assert (mix["user_tokens"]["median"], mix["user_tokens"]["max"],
             mix["output_tokens"]["max"]) == (4096, 15360, 1024)
+
+
+SAMBAY_CELL = "serve-phi4-mini-flash-agent16k"
+SAMBAY_CONFIG = "phi-4-mini-flash-reasoning"
+
+
+def test_the_sambay_cell_is_entered_as_the_issue_names_it(manifest):
+    """One configuration and one cell, appended last; the four readers of
+    its own; the serve cells' span and idle readers and the attention
+    kernel's roofline; no tail among its end-to-end metrics."""
+    config, cell = manifest["configs"][-1], manifest["workloads"][-1]
+    assert config["name"] == SAMBAY_CONFIG and config["reduced"] == []
+    assert config["source"] == ("https://huggingface.co/microsoft/"
+                                "Phi-4-mini-flash-reasoning/blob/main/"
+                                "config.json")
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
+        == (SAMBAY_CELL, SAMBAY_CONFIG, "agent-turns-16k", 1)
+    e2e = {m["name"] for m in harness.cell_metrics(manifest, SAMBAY_CELL,
+                                                   "end_to_end")}
+    assert e2e == {"serve_tokens_per_s", "setup_s"}
+    mine = {m["name"] for m in harness.cell_metrics(
+        manifest, SAMBAY_CELL, "per_layer", sorted(e2e))}
+    only_here = {m["name"] for m in manifest["per_layer"]
+                 if m.get("workloads") == [SAMBAY_CELL]}
+    assert only_here == {"step_mfu.serve_sambay",
+                         "shared_kv_roofline.serve_sambay",
+                         "selective_step_ms_per_tick.serve_sambay",
+                         "selective_scan_roofline.serve_sambay"}
+    gdn = {m["name"] for m in harness.cell_metrics(
+        manifest, GDN_CELL, "per_layer", ["serve_tokens_per_s", "setup_s"])}
+    assert {m for m in gdn if m.endswith(".serve")} <= mine
+
+
+def test_the_sambay_configuration_holds_every_published_key_whole():
+    """Every number of the catalog row's config under its own key, as
+    published, nothing cut; the sizes the config lacks stated beside them;
+    the whole model's count by hand."""
+    conf = harness.load_cell(SAMBAY_CELL)["conf"]
+    published = {
+        "embd_pdrop": 0, "hidden_act": "silu", "hidden_size": 2560,
+        "intermediate_size": 10240, "layer_norm_eps": 1e-05,
+        "max_position_embeddings": 262144, "mb_per_layer": 2,
+        "model_type": "phi4flash", "num_attention_heads": 40,
+        "num_hidden_layers": 32, "num_key_value_heads": 20,
+        "resid_pdrop": 0, "sliding_window": 512, "tie_word_embeddings": True,
+        "mlp_bias": False, "lm_head_bias": False, "vocab_size": 200064}
+    assert {k: conf[k] for k in published} == published
+    assert conf["published"] == published and conf["reduced"] == []
+    assert (conf["mamba_d_state"], conf["mamba_d_conv"],
+            conf["mamba_expand"], conf["mamba_dt_rank"]) == (16, 4, 2, 160)
+    # by hand, this repo's bytes: 9 Mamba-1, 9 attention layers that store
+    # KV, 7 cross-attention and 7 GMU layers, each with its MLP and two
+    # LayerNorms; the tied embedding; the final LayerNorm
+    around = 3 * 2560 * 10240 + 4 * 2560
+    mamba = 2560 * 10240 + 4 * 5120 + 5120 + 5120 * 192 + 160 * 5120 \
+        + 5120 + 16 * 5120 + 5120 + 5120 * 2560
+    attn = 2560 * 5120 + 5120 + 2560 * 2560 + 2560 + 4 * 64 + 128
+    cross = 2 * 2560 * 2560 + 2 * 2560 + 4 * 64 + 128
+    gmu = 2 * 2560 * 5120
+    assert (mamba, attn, cross, gmu) \
+        == (41_241_600, 19_668_864, 13_112_704, 26_214_400)
+    total = 9 * (mamba + around) + 9 * (attn + around) \
+        + 7 * (cross + around) + 7 * (gmu + around) + 200064 * 2560 \
+        + 2 * 2560
+    assert total == 3_852_562_944 == conf["parameters"]["total"]
+    assert conf["parameters"]["bytes_bfloat16"] == 7_705_125_888
+    mix = harness.load_cell(SAMBAY_CELL)["mix"]
+    assert (mix["clients"], mix["system_tokens"], mix["greedy_share"]) \
+        == (48, 0, 0.5)
+    assert mix["user_tokens"] == {"distribution": "lognormal",
+                                  "median": 6144, "sigma": 0.7,
+                                  "min": 1024, "max": 15360}
+    assert mix["output_tokens"] == {"distribution": "lognormal",
+                                    "median": 512, "sigma": 0.5,
+                                    "min": 128, "max": 1024}
